@@ -72,7 +72,6 @@ def _spans(intervals_path):
 def main() -> None:
     import jax
 
-    benchlib.honor_env_platforms()
     print(json.dumps({'platform': jax.devices()[0].platform.lower(),
                       'smoke': SMOKE, 'steps_per_window': STEPS}),
           flush=True)

@@ -84,7 +84,6 @@ def naive_numpy_search(vectors_normed: np.ndarray, queries: np.ndarray,
 
 
 def main() -> None:
-    benchlib.honor_env_platforms()
     smoke = benchlib.smoke_requested()
     parser = argparse.ArgumentParser()
     parser.add_argument('--vectors', type=int,

@@ -373,7 +373,14 @@ def run_stepped_arm(model, lines, capacity: float, max_lines: int,
     (scale-down latency = load step to the drained slot retired); p99
     over requests submitted DURING each transition window is reported
     next to steady-state p99 — the cost of an elastic transition is a
-    latency bulge, never a lost or misrouted request."""
+    latency bulge, never a lost or misrouted request.
+
+    CPU-only today: the parent here has loaded the model, so on a TPU
+    it holds the chip and a locally spawned process-mode worker cannot
+    initialise the backend — ``ServingMesh`` refuses the combination at
+    construction with ``LocalWorkerNeedsHeldChip`` (PERF.md "Bring-up",
+    PR 21) and this arm raises it. ROADMAP C5 decides whether 'process'
+    mode survives."""
     import random as random_lib
     import threading
     from code2vec_tpu.serving.errors import ServingError
@@ -640,7 +647,6 @@ def measure_capacity(model, index, profile, reps: int = 2) -> float:
 
 
 def main() -> None:
-    benchlib.honor_env_platforms()
     smoke = benchlib.smoke_requested()
     parser = argparse.ArgumentParser()
     parser.add_argument('--replica-counts', default='1,2,4',

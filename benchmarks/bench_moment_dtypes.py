@@ -52,7 +52,6 @@ def measure(label: str, **overrides) -> None:
 def main() -> None:
     import jax
 
-    benchlib.honor_env_platforms()
     print(json.dumps({'platform': jax.devices()[0].platform.lower()}),
           flush=True)
     # Arms pin every knob the A/B touches — INCLUDING GRADS_DTYPE in the

@@ -150,7 +150,6 @@ def run_engine_closed_loop(model, requests, tier: str, clients: int,
 
 
 def main() -> None:
-    benchlib.honor_env_platforms()
     smoke = benchlib.smoke_requested()
     parser = argparse.ArgumentParser()
     parser.add_argument('--requests', type=int,

@@ -62,7 +62,7 @@ class Config:
     # checkpointed key stays threefry either way; the rbg key is derived
     # inside the step, so checkpoints are unaffected by this knob.
     # DEFAULT 'rbg' per the ≥2% rule: the on-chip A/B measured 43.36 vs
-    # 47.32 ms/step (-8.4%, capture_2026-07-31T0344Z_r5.jsonl), and the
+    # 47.32 ms/step (-8.4%; 2026-07-31, earlier installation, PERF.md), and the
     # full-dims learning curve under rbg matches the threefry/fp32 twin
     # (accuracy_cpu_full_bf16.json: F1 0.7487 vs 0.7470). 'threefry2x32'
     # remains the portable reference behavior.
@@ -93,8 +93,8 @@ class Config:
     # params stay fp32 (the second moment has its own knob below).
     # DEFAULT 'bfloat16' per the
     # ≥2% rule: the on-chip A/B measured 44.89 vs 47.32 ms/step (-5.1%
-    # alone; -13.4% combined with rbg dropout,
-    # capture_2026-07-31T0344Z_r5.jsonl); the equivalence twins
+    # alone; -13.4% combined with rbg dropout; 2026-07-31, earlier
+    # installation, PERF.md); the equivalence twins
     # (accuracy_*bf16mu*.json) pair its F1 curve against the fp32-moment
     # runs. Changing it changes the optimizer-state dtype; resuming a
     # checkpoint written under the OTHER setting adapts automatically
@@ -141,8 +141,8 @@ class Config:
     # each table row is written at most once. Numerically equivalent up to
     # fp summation order. The on-chip A/B decided for 'dense' on both
     # uniform and zipf index streams (48.69 vs 54.45 sorted / 65.42 dedup
-    # ms/step zipf, capture_2026-07-31T0344Z_r5.jsonl): XLA's native
-    # scatter-add beats both pre-combine strategies, which break its
+    # ms/step zipf; 2026-07-31, earlier installation, PERF.md): XLA's
+    # native scatter-add beats both pre-combine strategies, which break its
     # fusion the same way lazy Adam does (PERF.md).
     EMBED_GRAD_IMPL: str = 'dense'
     # Route the TRAINING cross-entropy through the flash-style fused Pallas
@@ -152,9 +152,9 @@ class Config:
     # Multi-device meshes use the shard_mapped variant (table row-sharded
     # over 'model', batch over 'data', online stats merged over ICI).
     # The on-chip A/B measured it NEUTRAL at java14m shapes (47.18 vs
-    # 47.23 ms/step alone; +1.4% on top of the rbg+bf16-mu winner,
-    # capture_2026-07-31T0344Z_r5.jsonl) — below the ≥2% flip rule, so it
-    # stays opt-in: XLA's own CE fusion already avoids most of the logits
+    # 47.23 ms/step alone; +1.4% on top of the rbg+bf16-mu winner;
+    # 2026-07-31, earlier installation, PERF.md) — below the ≥2% flip
+    # rule, so it stays opt-in: XLA's own CE fusion already avoids most of the logits
     # round-trip. Eval/predict always materialize logits (top-k needs
     # them).
     USE_PALLAS_FUSED_CE: bool = False
@@ -221,11 +221,10 @@ class Config:
     TRAIN_DATA_CACHE: bool = True
     # Use the fused Pallas encode kernel (split-TRANSFORM matmul + tanh +
     # attention scores in one VMEM pass) for the deterministic forward
-    # (eval/predict). Measured on-chip at the java14m config: 0.99x vs
-    # XLA (PERF.md) — the encode block is small next to the 261K-vocab
-    # logits matmul + top-k — so this stays off by default; it is worth
-    # re-measuring for long-context configs (MAX_CONTEXTS >> 200) where
-    # the encode block dominates.
+    # (eval/predict) on the PLANE wire. Off by default: the 2026-07 A/B
+    # on an earlier installation measured 0.99x vs XLA at the java14m
+    # config (PERF.md); not measured on this chip. A TPU kernel: forced
+    # on off a TPU it raises KernelRequiresTPU (ops/_pallas_common.py).
     USE_PALLAS_FUSED_ENCODE: bool = False
     # Run encode + attention straight off the packed wire
     # (ops/pallas_ragged.py): the (D, cap, 3) triples + counts feed a
@@ -233,30 +232,32 @@ class Config:
     # and a FuseMax-style single-pass per-example softmax + weighted sum
     # — so the (B, max_contexts) segment-scatter unpack and every dense
     # (B, C, .) intermediate disappear from the packed train/eval/
-    # predict/serving programs. On a real TPU backend the deterministic
-    # forward runs the Pallas kernel; training (dropout, backward) and
-    # non-TPU backends run the differentiable jnp twin on the same
-    # packed layout. Outputs match the unpack-then-dense path to fp32
-    # rounding (tests/test_pallas_ragged.py); dropout draws its mask
-    # over the packed layout (a different seed-keyed stream, the
-    # DROPOUT_PRNG_IMPL precedent). ON by default: the deterministic
-    # paths run the kernel only on a real TPU backend (jnp twin
-    # everywhere else — never the interpreter), and the train path runs
-    # the custom-VJP twin whose recompute backward saves no (B, C, .)/
-    # (D, cap, .) residuals (structural wins on every backend; CPU
-    # harness smoke 1.59x train / 1.91x predict, PERF.md "Ragged
-    # fusion"). --no-ragged-fusion restores the unpack-then-dense
-    # (bit-exact vs planes) path.
+    # predict/serving programs. The deterministic forward runs the
+    # Pallas kernel when the trainer's mesh is on TPUs and the jnp twin
+    # on any other platform — decided once per trainer from the mesh's
+    # devices and logged (training/trainer.py); training (dropout,
+    # backward) runs the differentiable custom-VJP twin on the same
+    # packed layout everywhere, whose recompute backward saves no
+    # (B, C, .)/(D, cap, .) residuals. Outputs match the
+    # unpack-then-dense path to fp32 rounding
+    # (tests/test_pallas_ragged.py); dropout draws its mask over the
+    # packed layout (a different seed-keyed stream, the
+    # DROPOUT_PRNG_IMPL precedent). ON by default. On the v5e the kernel
+    # compiles under Mosaic at every default serving-ladder shape and
+    # agrees with the twin to bf16 rounding (PERF.md "Bring-up", PR 21);
+    # its speed against the twin and the unfused path is not measured on
+    # the chip (ROADMAP A1). --no-ragged-fusion restores the
+    # unpack-then-dense (bit-exact vs planes) path.
     USE_PALLAS_RAGGED_FUSION: bool = True
     # Route the packed TRAIN step's forward AND recompute-backward
-    # through the Pallas kernel pair on a real TPU backend
+    # through the Pallas kernel pair
     # (ops/pallas_ragged.py::_ragged_kernel/_bwd_kernel). This is the
     # on-chip train flip the >=2% rule still gates: OFF until
-    # scripts/flip_verdict.py reads a healthy capture round
-    # (benchmarks/bench_pallas_ragged.py train arms) clearing 1.02x —
-    # the verdicts have been queued since the 2026-07-31 TPU wedge.
-    # Inert off-TPU (the custom-VJP jnp twin runs regardless) and
-    # without USE_PALLAS_RAGGED_FUSION (the train step then unpacks).
+    # scripts/flip_verdict.py reads a capture round
+    # (benchmarks/bench_pallas_ragged.py train arms) clearing 1.02x;
+    # not measured on the chip (ROADMAP A1). TPU only: forced on off a
+    # TPU it raises KernelRequiresTPU. Inert without
+    # USE_PALLAS_RAGGED_FUSION (the train step then unpacks).
     RAGGED_TRAIN_KERNEL: bool = False
     # When set, capture a jax.profiler trace of a few training steps into
     # this directory (viewable with TensorBoard/Perfetto) — the step-level
@@ -324,9 +325,10 @@ class Config:
     # achieved model FLOP/s over peak x device count). -1 = UNSET: the
     # DEVICE_PEAK_FLOPS environment variable fills in (the
     # TELEMETRY_TRACE_AT_STEP convention), else the device-kind table
-    # in telemetry/goodput.py (known TPU generations, a CPU floor),
-    # else a conservative default. Set it explicitly for hardware the
-    # table doesn't know — MFU is only as honest as this denominator.
+    # in telemetry/goodput.py (known TPU generations, a nominal CPU
+    # row). A device the table doesn't know is an error at telemetry
+    # set-up until this is set — MFU is only as honest as its
+    # denominator.
     DEVICE_PEAK_FLOPS: float = -1.0
     # Step-time anomaly watchdog threshold, in robust standard
     # deviations (MAD-scaled) above the per-shape rolling median. A

@@ -126,9 +126,10 @@ class JaxBackend:
         planes — and its custom VJP recomputes the backward off the same
         segments instead of storing per-slot residuals
         (ops/pallas_ragged.py). RAGGED_TRAIN_KERNEL additionally routes
-        both train passes through the Pallas kernel pair on a real TPU
-        backend (None = auto there; False pins the jnp twin pair — the
-        default pending the >=2% flip verdict, scripts/flip_verdict.py)."""
+        both train passes through the Pallas kernel pair (TPU only: off
+        a TPU the forced kernel raises ``KernelRequiresTPU``); off, the
+        jnp twin pair runs — the default pending the >=2% flip verdict
+        (scripts/flip_verdict.py)."""
         ctx, count, label, weight = packed_arrays
         return functional.loss_and_aux_packed(
             params, ctx, count, label, weight,
@@ -143,20 +144,22 @@ class JaxBackend:
             use_fused_ce=self.config.USE_PALLAS_FUSED_CE,
             fused_ce_mesh=mesh,
             remat_encode=self.config.REMAT_ENCODE,
-            use_ragged_kernel=(None if self.config.RAGGED_TRAIN_KERNEL
-                               else False),
+            use_ragged_kernel=self.config.RAGGED_TRAIN_KERNEL,
             ragged_mesh=mesh)
 
-    def forward_packed(self, params, packed_arrays, mesh=None):
-        """Deterministic forward off the packed wire: on a real TPU
-        backend the fused Pallas kernel runs (shard_mapped over ``mesh``
-        when multi-device); elsewhere the jnp twin."""
+    def forward_packed(self, params, packed_arrays, mesh=None,
+                       use_kernel: bool = False):
+        """Deterministic forward off the packed wire: the fused Pallas
+        kernel when ``use_kernel`` (the trainer's once-per-mesh platform
+        decision; shard_mapped over ``mesh`` when multi-device), the jnp
+        twin otherwise."""
         ctx, count = packed_arrays[0], packed_arrays[1]
         code_vectors, attention = functional.encode_packed(
             params, ctx, count, max_contexts=self.config.MAX_CONTEXTS,
             token_pad=self.token_pad_index,
             path_pad=self.path_pad_index, dtype=self.dtype,
-            embed_grad_impl=self.config.EMBED_GRAD_IMPL, mesh=mesh)
+            embed_grad_impl=self.config.EMBED_GRAD_IMPL,
+            use_kernel=use_kernel, mesh=mesh)
         logits = functional.compute_logits(
             params, code_vectors, dtype=self.dtype,
             num_valid_targets=self.num_valid_targets)
@@ -226,9 +229,11 @@ class FlaxBackend:
             self.named_params(params), packed_arrays, dropout_rng,
             mesh=mesh)
 
-    def forward_packed(self, params, packed_arrays, mesh=None):
+    def forward_packed(self, params, packed_arrays, mesh=None,
+                       use_kernel: bool = False):
         return self._jax_twin.forward_packed(
-            self.named_params(params), packed_arrays, mesh=mesh)
+            self.named_params(params), packed_arrays, mesh=mesh,
+            use_kernel=use_kernel)
 
     def named_params(self, params) -> functional.Code2VecParams:
         inner = params['params']

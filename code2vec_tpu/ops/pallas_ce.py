@@ -48,14 +48,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from code2vec_tpu.ops._pallas_common import (PALLAS_AVAILABLE,
-                                             tpu_backend_active)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-if PALLAS_AVAILABLE:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-from code2vec_tpu.ops._shard_map import shard_map
+from code2vec_tpu.ops._pallas_common import resolve_interpret
 from code2vec_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 # vocab columns per grid step. VMEM at java14m shapes (B=1024, D=384,
@@ -300,16 +296,17 @@ def fused_weighted_ce_sums(params_target: jax.Array, code_vectors: jax.Array,
                            label: jax.Array, weight: jax.Array,
                            num_valid_targets: int,
                            dtype: jnp.dtype = jnp.float32,
-                           interpret: bool = None
+                           interpret: bool = False
                            ) -> Tuple[jax.Array, jax.Array]:
     """Drop-in for compute_logits + weighted_ce_sums in the TRAIN path:
     (weighted CE sum, weight sum) with no (B, V) HBM intermediate.
 
     ``dtype`` is the MXU compute dtype, mirroring compute_logits: the
     matmuls run in ``dtype`` with fp32 accumulation, reductions stay fp32.
+    Compiled on a TPU; elsewhere ``KernelRequiresTPU`` unless a test
+    passes ``interpret=True`` (ops/_pallas_common.py).
     """
-    if interpret is None:
-        interpret = not tpu_backend_active()
+    interpret = resolve_interpret(interpret, 'fused CE')
     lse, picked = fused_lse_and_pick(
         code_vectors.astype(dtype), params_target.astype(dtype),
         label, num_valid_targets, interpret)
@@ -343,7 +340,7 @@ def _sharded_forward(code, w, label, num_valid, mesh, interpret):
     # check_vma=False: outputs ARE replicated along 'model' after the
     # psum/pmax merge, but the static checker can't prove it (same as
     # ops/topk.py::sharded_top_k)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(MODEL_AXIS, None), P(DATA_AXIS)),
         out_specs=(P(DATA_AXIS), P(DATA_AXIS)),
@@ -390,7 +387,7 @@ def _sharded_vjp_bwd(num_valid, mesh, interpret, residuals, cotangents):
         return (jax.lax.psum(dcode_p, MODEL_AXIS),
                 jax.lax.psum(dw_l, DATA_AXIS))
 
-    dcode, dw = shard_map(
+    dcode, dw = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(MODEL_AXIS, None), P(DATA_AXIS),
                   P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
@@ -409,7 +406,7 @@ def sharded_fused_weighted_ce_sums(params_target: jax.Array,
                                    label: jax.Array, weight: jax.Array,
                                    num_valid_targets: int, mesh: Mesh,
                                    dtype: jnp.dtype = jnp.float32,
-                                   interpret: bool = None
+                                   interpret: bool = False
                                    ) -> Tuple[jax.Array, jax.Array]:
     """Multi-device drop-in for fused_weighted_ce_sums. Requires the
     padded target vocab divisible by the model axis (the trainer's
@@ -417,8 +414,7 @@ def sharded_fused_weighted_ce_sums(params_target: jax.Array,
     a VOCAB_TILE multiple still work via the kernel's own pad, at the cost
     of a per-step copy of the local shard (backends align the allocation
     to avoid this)."""
-    if interpret is None:
-        interpret = not tpu_backend_active()
+    interpret = resolve_interpret(interpret, 'sharded fused CE', mesh)
     lse, picked = sharded_fused_lse_and_pick(
         code_vectors.astype(dtype), params_target.astype(dtype),
         label, num_valid_targets, mesh, interpret)

@@ -33,15 +33,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-# shared soft import + TPU predicate (ops/_pallas_common.py); the names
-# are re-exported here because model code and the benches historically
-# import them from this module
-from code2vec_tpu.ops._pallas_common import (PALLAS_AVAILABLE,  # noqa: F401
-                                             tpu_backend_active)
+from jax.experimental import pallas as pl
 
-if PALLAS_AVAILABLE:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from code2vec_tpu.ops._pallas_common import resolve_interpret
 
 ROW_TILE = 512  # context rows per grid step; N is padded to a multiple
 
@@ -64,17 +58,16 @@ def _kernel(src_ref, path_ref, tgt_ref, w_src_ref, w_path_ref, w_tgt_ref,
 def fused_context_transform(src_e: jax.Array, path_e: jax.Array,
                             tgt_e: jax.Array, transform: jax.Array,
                             attention: jax.Array,
-                            interpret: bool = None
+                            interpret: bool = False
                             ) -> Tuple[jax.Array, jax.Array]:
     """(N, d)-shaped gathered embeddings → (x (N, D), scores (N, 1)).
 
     ``transform`` is the full (2·d_tok + d_path, D) TRANSFORM matrix; it is
     row-split here to skip the concat. ``attention`` is (D, 1).
-    ``interpret`` defaults to True off-TPU so the kernel runs (slowly but
-    correctly) everywhere.
+    Runs compiled on a TPU; elsewhere it raises ``KernelRequiresTPU``
+    unless a test passes ``interpret=True``.
     """
-    if interpret is None:
-        interpret = not tpu_backend_active()
+    interpret = resolve_interpret(interpret, 'fused context transform')
     n, token_dim = src_e.shape
     path_dim = path_e.shape[1]
     code_dim = transform.shape[1]

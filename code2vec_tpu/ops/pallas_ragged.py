@@ -83,9 +83,11 @@ deterministic, seed-keyed) stream, the ``DROPOUT_PRNG_IMPL='rbg'``
 precedent.
 
 Gated by ``Config.USE_PALLAS_RAGGED_FUSION`` (threaded through
-models/backends.py and training/trainer.py) with the same
-``tpu_backend_active()`` fallback discipline as the other kernels: off
-TPU the jnp twin runs — never the interpreter.
+models/backends.py and training/trainer.py). Kernel-or-twin is the
+trainer's decision, made once from its mesh's platform and passed down
+as ``use_kernel``; a kernel asked for off a TPU raises
+``KernelRequiresTPU`` (ops/_pallas_common.py) — it never falls back to
+the twin or the interpreter.
 """
 from __future__ import annotations
 
@@ -96,14 +98,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from code2vec_tpu.ops._pallas_common import (PALLAS_AVAILABLE,
-                                             tpu_backend_active)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-if PALLAS_AVAILABLE:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-from code2vec_tpu.ops._shard_map import shard_map
+from code2vec_tpu.ops._pallas_common import resolve_interpret
 from code2vec_tpu.parallel.mesh import DATA_AXIS
 
 SLOT_TILE = 512     # packed slots per grid step; capacity pads to a multiple
@@ -194,7 +192,7 @@ def _ragged_kernel(precision, src_ref, pth_ref, tgt_ref, seg_ref, valid_ref,
     # acc lives (D, n_seg) so the rescale broadcasts along rows and the
     # tile contraction is a single dot_general over the slot axis
     acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-        x, pz, (((0,), (0,)), ((), ())),
+        x, pz, (((0,), (0,)), ((), ())), precision=precision,
         preferred_element_type=jnp.float32)                  # (D, n_seg)
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -276,7 +274,7 @@ def _stats_kernel_path(src_e, pth_e, tgt_e, seg, slot_valid, w_src, w_path,
         # check_vma=False: outputs follow the data axis exactly like the
         # inputs, but the static checker can't see through the kernel
         # (same as ops/pallas_ce.py::_sharded_forward)
-        return shard_map(
+        return jax.shard_map(
             one_shard, mesh=mesh,
             in_specs=(P(DATA_AXIS, None, None), P(DATA_AXIS, None, None),
                       P(DATA_AXIS, None, None), P(DATA_AXIS, None),
@@ -408,15 +406,17 @@ def ragged_encode(token_embedding: jax.Array, path_embedding: jax.Array,
                   dropout_keep_rate: float = 1.0,
                   dropout_prng_impl: str = 'threefry2x32',
                   embed_grad_impl: str = 'dense',
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
+                  use_kernel: bool = False,
+                  interpret: bool = False,
                   mesh=None) -> Tuple[jax.Array, jax.Array]:
     """Packed wire arrays -> (code_vectors (B, D) fp32, attention planes
     (B, C) fp32), with no ``(B, C, .)`` intermediate anywhere.
 
-    ``use_kernel`` None routes the Pallas kernel iff a real TPU backend
-    is active; False forces the jnp twin; True forces the kernel (tests
-    run it with ``interpret=True`` on CPU). Dropout (the fused TRAIN
+    ``use_kernel`` False runs the jnp twin; True runs the Pallas kernel,
+    compiled — off a TPU that raises ``KernelRequiresTPU`` unless a test
+    passes ``interpret=True``. The caller decides (the trainer, from its
+    mesh's platform); nothing here looks for a device to choose a path.
+    Dropout (the fused TRAIN
     draw) rides either implementation: the packed-layout keep mask is
     applied to the gathered embeddings BEFORE the stats pass, so the
     kernel and the twin consume bit-identical inputs. NB the kernel
@@ -435,10 +435,9 @@ def ragged_encode(token_embedding: jax.Array, path_embedding: jax.Array,
         ctx, count, token_pad, path_pad)
 
     apply_dropout = dropout_rng is not None and dropout_keep_rate < 1.0
-    if use_kernel is None:
-        use_kernel = PALLAS_AVAILABLE and tpu_backend_active()
-    if interpret is None:
-        interpret = not tpu_backend_active()
+    if use_kernel:
+        interpret = resolve_interpret(interpret, 'ragged fused encode',
+                                      mesh)
 
     from code2vec_tpu.ops.embed_grad import take_rows
     src_e = take_rows(token_embedding, src,
@@ -644,7 +643,7 @@ def _grads_kernel_path(src_e, pth_e, tgt_e, seg, slot_valid, w_src, w_path,
 
     if mesh is not None and mesh.size > 1:
         # check_vma=False: same reasoning as the forward kernel route
-        outs = shard_map(
+        outs = jax.shard_map(
             one_shard, mesh=mesh,
             in_specs=(P(DATA_AXIS, None, None), P(DATA_AXIS, None, None),
                       P(DATA_AXIS, None, None), P(DATA_AXIS, None),
@@ -729,8 +728,8 @@ def ragged_encode_code(token_embedding: jax.Array,
                        dropout_keep_rate: float = 1.0,
                        dropout_prng_impl: str = 'threefry2x32',
                        embed_grad_impl: str = 'dense',
-                       use_kernel: Optional[bool] = None,
-                       interpret: Optional[bool] = None,
+                       use_kernel: bool = False,
+                       interpret: bool = False,
                        mesh=None, custom_vjp: bool = True) -> jax.Array:
     """The TRAIN-path encode: packed wire arrays -> code vectors
     ``(B, D) fp32`` under a ``jax.custom_vjp`` whose backward RECOMPUTES
@@ -739,18 +738,16 @@ def ragged_encode_code(token_embedding: jax.Array,
     PRNG key get ``None`` cotangents (the embed_grad.take_rows
     precedent).
 
-    ``use_kernel`` routes BOTH passes: None engages the Pallas pair iff
-    a real TPU backend is active (callers gate train-side engagement via
-    ``Config.RAGGED_TRAIN_KERNEL`` pending the >=2% flip verdict), False
-    pins the jnp twin pair, True forces the kernels (tests:
+    ``use_kernel`` routes BOTH passes: False runs the jnp twin pair,
+    True the Pallas pair (``Config.RAGGED_TRAIN_KERNEL``; off a TPU that
+    raises ``KernelRequiresTPU`` unless a test passes
     ``interpret=True``). ``custom_vjp=False`` is the autodiff reference
     — the twin differentiated by jax, storing its residuals — kept for
     the parity/residual tests."""
     apply_dropout = dropout_rng is not None and dropout_keep_rate < 1.0
-    if use_kernel is None:
-        use_kernel = PALLAS_AVAILABLE and tpu_backend_active()
-    if interpret is None:
-        interpret = not tpu_backend_active()
+    if use_kernel and custom_vjp:
+        interpret = resolve_interpret(interpret, 'ragged train kernel pair',
+                                      mesh)
     if not custom_vjp:
         if use_kernel:
             raise ValueError(
@@ -765,7 +762,7 @@ def ragged_encode_code(token_embedding: jax.Array,
             dropout_keep_rate=dropout_keep_rate,
             dropout_prng_impl=dropout_prng_impl,
             embed_grad_impl=embed_grad_impl, use_kernel=False,
-            interpret=interpret, mesh=mesh)[0]
+            mesh=mesh)[0]
 
     precision = _precision(dtype)
 

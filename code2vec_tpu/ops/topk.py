@@ -32,7 +32,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from code2vec_tpu.ops._shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from code2vec_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
@@ -131,10 +130,10 @@ def sharded_top_k(logits: jax.Array, k: int, mesh: Mesh,
     # check_vma=False: outputs ARE replicated along the shard axis (post
     # all_gather + identical merge on every shard) but the static checker
     # can't prove it
-    return shard_map(local_merge, mesh=mesh,
-                     in_specs=(P(batch_axis, shard_axis),),
-                     out_specs=(P(batch_axis), P(batch_axis)),
-                     check_vma=False)(logits)
+    return jax.shard_map(local_merge, mesh=mesh,
+                         in_specs=(P(batch_axis, shard_axis),),
+                         out_specs=(P(batch_axis), P(batch_axis)),
+                         check_vma=False)(logits)
 
 
 def padded_local_topk(x: jax.Array, k: int

@@ -34,6 +34,15 @@ DATA_AXIS = 'data'
 MODEL_AXIS = 'model'
 
 
+def mesh_platform(mesh: Optional[Mesh] = None) -> str:
+    """Platform of the devices a program will run on: the mesh's when one
+    is given, the default backend's otherwise. Backend-init errors
+    propagate — nothing may pick a code path by catching them."""
+    device = (mesh.devices.flat[0] if mesh is not None
+              else jax.devices()[0])
+    return device.platform.lower()
+
+
 def create_mesh(config: Optional[Config] = None,
                 devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """Build the (data, model) mesh. ``MESH_DATA_AXIS_SIZE == -1`` means

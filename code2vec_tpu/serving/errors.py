@@ -63,6 +63,18 @@ class AdoptionRejected(ServingError):
     the mesh's job — SERVING.md "Elastic fleet")."""
 
 
+class LocalWorkerNeedsHeldChip(ServingError):
+    """A worker-mode mesh (``MESH_REPLICA_MODE`` 'process' or 'socket')
+    was asked to spawn a worker on this machine while the parent process
+    holds its TPU.  A TPU belongs to one process: the child's backend
+    init fails with "The TPU is already in use by process with pid N"
+    (established on a v5e, PERF.md "Bring-up"), and nothing in the tree
+    restricts a child's visible chips.  Refused at mesh construction,
+    before any spawn, instead of waiting out the worker start timeout.
+    Thread mode, and socket workers started on another machine
+    (scripts/mesh_worker.py), are unaffected."""
+
+
 class WireError(ServingError):
     """A mesh transport frame failed validation — bad magic, truncated
     body, or CRC mismatch (the on-wire shape of a worker dying mid-

@@ -53,7 +53,14 @@ mesh opens a listener, each worker dials in with a rid/proto handshake
 and reports its restored params step, so replicas can live on other
 machines.  Worker replicas restore params from the model's checkpoint
 path (pytrees don't cross processes; checkpoint refs do — which is
-also why worker-mode rollover takes step/path sources only).
+also why worker-mode rollover takes step/path sources only).  Both
+worker modes spawn their n workers on THIS machine from a parent that
+already holds the model's devices, and a TPU belongs to one process:
+on a TPU-holding parent the mesh refuses 'process'/'socket' at
+construction with ``LocalWorkerNeedsHeldChip`` (the child's backend
+init fails with "The TPU is already in use by process with pid N" —
+PERF.md "Bring-up").  Thread mode, CPU hosts, and socket workers
+started on another machine (scripts/mesh_worker.py) are unaffected.
 
 **Self-healing (SERVING.md "Multi-host mesh").**  Replica death is a
 non-event, not an operator page:
@@ -102,6 +109,7 @@ trace trees).
 from __future__ import annotations
 
 import collections
+import os
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -122,8 +130,9 @@ from code2vec_tpu.serving.engine import (ServingEngine, _Request,
                                          _resolve)
 from code2vec_tpu.serving.errors import (AdoptionRejected,
                                          DeadlineExceeded, EngineClosed,
-                                         EngineOverloaded, ReplicaDead,
-                                         WireError)
+                                         EngineOverloaded,
+                                         LocalWorkerNeedsHeldChip,
+                                         ReplicaDead, WireError)
 from code2vec_tpu.serving.frontqueue import FrontQueue
 from code2vec_tpu.telemetry import core as tele_core
 from code2vec_tpu.telemetry import tracing as tracing_lib
@@ -744,8 +753,8 @@ def _replica_worker_main(rid: str, config_overrides: Dict[str, object],
     framed wire — a pipe connection (``conn``) in process mode, or a
     TCP dial to the mesh listener (``address``) in socket mode.  The
     protocol is identical either way."""
-    import os
     import signal
+    from code2vec_tpu import compile_cache
     from code2vec_tpu.config import Config
     from code2vec_tpu.model_api import Code2VecModel
     if conn is not None:
@@ -760,6 +769,9 @@ def _replica_worker_main(rid: str, config_overrides: Dict[str, object],
             channel.send(message)
 
     try:
+        # before the worker's first compile: its 23+ warm-ladder programs
+        # come from the shared persistent cache when a sibling built them
+        compile_cache.configure()
         config = Config(**config_overrides)
         if config.MESH_TELEMETRY_BACKHAUL == 1:
             # the parent resolved the backhaul decision at spawn: with
@@ -979,6 +991,19 @@ class ServingMesh:
             raise ValueError("MESH_REPLICA_MODE must be 'thread', "
                              "'process' or 'socket', got %r"
                              % (self.mode,))
+        if self.mode != 'thread':
+            # every worker-mode build spawns its n workers on THIS
+            # machine, from this process — which holds the model's
+            # devices
+            if mesh_lib.mesh_platform(model.mesh) == 'tpu':
+                raise LocalWorkerNeedsHeldChip(
+                    "MESH_REPLICA_MODE=%r spawns its workers locally, and "
+                    'this process holds the TPU they would need (a chip '
+                    'belongs to one process; the child fails with "The '
+                    'TPU is already in use by process with pid %d"). Use '
+                    "MESH_REPLICA_MODE='thread' on this host, or run "
+                    'scripts/mesh_worker.py on another machine.'
+                    % (self.mode, os.getpid()))
         # ---- self-healing knobs (SERVING.md "Multi-host mesh") ----
         self.heartbeat_secs = float(
             heartbeat_secs if heartbeat_secs is not None

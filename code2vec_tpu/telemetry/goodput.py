@@ -106,9 +106,6 @@ KNOWN_DEVICE_PEAK_FLOPS: Dict[str, float] = {
     'cpu': 50e9,
 }
 
-#: fallback when the device kind is unknown and no knob is set
-DEFAULT_PEAK_FLOPS = 50e9
-
 ENV_DEVICE_PEAK_FLOPS = 'DEVICE_PEAK_FLOPS'
 
 
@@ -117,8 +114,9 @@ def resolve_peak_flops(configured: float = -1.0,
     """Per-device peak FLOP/s: ``Config.DEVICE_PEAK_FLOPS`` when set
     (> 0), else the ``DEVICE_PEAK_FLOPS`` environment variable (the
     TELEMETRY_TRACE_AT_STEP unset-field convention), else the
-    known-device table by ``device_kind`` prefix match, else
-    :data:`DEFAULT_PEAK_FLOPS`."""
+    known-device table by ``device_kind`` prefix match. A device the
+    table does not know, with neither knob set, is a ``ValueError`` at
+    telemetry set-up: an MFU over a made-up peak is worse than none."""
     if configured and configured > 0:
         return float(configured)
     env = os.environ.get(ENV_DEVICE_PEAK_FLOPS)
@@ -134,7 +132,11 @@ def resolve_peak_flops(configured: float = -1.0,
         for known, peak in KNOWN_DEVICE_PEAK_FLOPS.items():
             if kind.startswith(known.lower()):
                 return peak
-    return DEFAULT_PEAK_FLOPS
+    raise ValueError(
+        'no peak FLOP/s for device_kind %r: set DEVICE_PEAK_FLOPS '
+        '(--device-peak-flops or the environment variable) or add the '
+        'device to telemetry/goodput.py::KNOWN_DEVICE_PEAK_FLOPS (known: '
+        '%s)' % (device_kind, ', '.join(KNOWN_DEVICE_PEAK_FLOPS)))
 
 
 def mfu(window_flops: float, window_seconds: float,

@@ -14,10 +14,15 @@ Two complementary signals:
   bucket — the "silent jit re-specialization" PR 1 made possible and
   this PR makes visible.
 
-The monitoring listener is installed once per process and kept — jax has
-no unregister API stable across versions — but it forwards through
-``core.enabled()``, so with telemetry off its cost is one bool read per
-compile (compiles are seconds-scale; this is nothing).
+The monitoring listener is installed once per process and kept, but it
+forwards through ``core.enabled()``, so with telemetry off its cost is
+one bool read per compile (compiles are seconds-scale; this is nothing).
+
+A persistent-cache HIT still fires the event (jax times
+``compile_or_get_cached``, and a hit's duration is its retrieval time),
+so ``jit/compiles_total`` and the goodput ``compile`` bucket count
+programs that were built OR loaded for this process — "zero after
+warm-up" means neither happened.
 """
 from __future__ import annotations
 
@@ -26,16 +31,13 @@ from code2vec_tpu.telemetry import goodput
 
 _LISTENER_INSTALLED = False
 
-# Event-name suffixes across jax versions (0.4.x uses *_duration; older
-# releases used *_time_sec).
-_COMPILE_EVENT_SUFFIXES = ('backend_compile_duration',
-                           'backend_compile_time_sec')
+_COMPILE_EVENT = '/jax/core/compile/backend_compile_duration'
 
 
 def _on_event_duration(name: str, secs: float, **_kwargs) -> None:
     if not core.enabled():
         return
-    if name.endswith(_COMPILE_EVENT_SUFFIXES):
+    if name == _COMPILE_EVENT:
         reg = core.registry()
         reg.counter('jit/compiles_total').inc()
         reg.timer('jit/compile_ms').record(secs)
@@ -45,17 +47,13 @@ def _on_event_duration(name: str, secs: float, **_kwargs) -> None:
 
 
 def install_compile_listener() -> bool:
-    """Idempotently register the jax.monitoring compile listener.
-    Returns False when jax (or its monitoring API) is unavailable."""
+    """Idempotently register the jax.monitoring compile listener; True
+    once it is in place."""
     global _LISTENER_INSTALLED
-    if _LISTENER_INSTALLED:
-        return True
-    try:
+    if not _LISTENER_INSTALLED:
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_on_event_duration)
-    except Exception:
-        return False
-    _LISTENER_INSTALLED = True
+        _LISTENER_INSTALLED = True
     return True
 
 

@@ -74,13 +74,9 @@ class StepTelemetry:
         self.goodput = goodput_lib.GoodputLedger(
             os.path.join(self.dir, 'intervals%s.jsonl' % suffix),
             log=self.log)
-        try:
-            import jax
-            device_kind = jax.local_devices()[0].device_kind
-            self._num_devices = jax.device_count()
-        except Exception:  # jax-less construction (unit tests)
-            device_kind = None
-            self._num_devices = 1
+        import jax
+        device_kind = jax.local_devices()[0].device_kind
+        self._num_devices = jax.device_count()
         self.peak_flops = goodput_lib.resolve_peak_flops(
             getattr(config, 'DEVICE_PEAK_FLOPS', -1.0), device_kind)
         sigma = getattr(config, 'GOODPUT_ANOMALY_SIGMA', 6.0)

@@ -68,7 +68,6 @@ from code2vec_tpu import benchlib  # noqa: E402
 
 
 def main() -> int:
-    benchlib.honor_env_platforms()
     smoke = benchlib.smoke_requested()
     parser = argparse.ArgumentParser()
     parser.add_argument('--secs', type=float,

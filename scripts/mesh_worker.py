@@ -7,7 +7,8 @@ sub-mesh when ``--device-indices`` places it), warms its ladder, dials
 the mesh listener at ``--address``, and serves the framed dispatch
 wire exactly like a mesh-spawned worker (scripts/../serving/mesh.py
 ``_replica_worker_main`` IS the serve loop — this script only
-assembles its config).
+assembles its config; the serve loop places the persistent compile cache,
+``code2vec_tpu/compile_cache.py``, before the worker's first compile).
 
 Because the rid is one the mesh never registered, the dial-in lands on
 ``SocketListener``'s unclaimed path and the mesh ADOPTS it: validates

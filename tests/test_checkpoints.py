@@ -200,6 +200,7 @@ def test_resume_across_opt_state_sharding_modes(tmp_path):
 
 
 @pytest.mark.slow  # three full trains (~11s); tier-1 budget headroom
+@pytest.mark.usefixtures('pallas_interpret')
 def test_resume_across_fused_ce_and_mesh_reshape(tmp_path):
     """ADVICE r3: the fused-CE target-table allocation folds in the vocab
     tile and mesh model-axis size, so its row count is topology-dependent —
@@ -261,6 +262,7 @@ def test_resume_across_fused_ce_and_mesh_reshape(tmp_path):
 
 
 @pytest.mark.slow  # train + release + resume (~10s); budget headroom
+@pytest.mark.usefixtures('pallas_interpret')
 def test_release_rows_rewrite_does_not_poison_older_checkpoints(tmp_path):
     """ADVICE r4: one meta.json serves the whole history, and its
     target_vocab_rows tracks only the NEWEST writer — after a --release

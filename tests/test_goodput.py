@@ -201,9 +201,11 @@ def test_resolve_peak_flops_precedence(monkeypatch):
         == goodput.KNOWN_DEVICE_PEAK_FLOPS['TPU v4']
     assert goodput.resolve_peak_flops(-1.0, 'TPU v5 lite podslice') \
         == goodput.KNOWN_DEVICE_PEAK_FLOPS['TPU v5 lite']
-    # unknown kind -> conservative default
-    assert goodput.resolve_peak_flops(-1.0, 'FPGA x1') \
-        == goodput.DEFAULT_PEAK_FLOPS
+    # the string the v5e reports (PERF.md "Bring-up", PR 21 chip run)
+    assert goodput.resolve_peak_flops(-1.0, 'TPU v5 lite') == 197e12
+    # an unknown kind with no knob set is an error, never a default
+    with pytest.raises(ValueError, match='FPGA x1'):
+        goodput.resolve_peak_flops(-1.0, 'FPGA x1')
 
 
 def test_program_cost_matches_hand_computed_flops():

@@ -158,13 +158,6 @@ def test_lazy_backend_parity_jax_vs_flax():
                                    err_msg=k)
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith('0.4.'),
-    reason='environment-limited: GSPMD scatter semantics gap breaks the '
-           'opt-in lazy-Adam sparse-row update on multi-device meshes '
-           'under jax 0.4.x (known-xfail, CHANGES.md PR 1); the dense '
-           'default path is unaffected (test_lazy_vs_dense_*)',
-    strict=False)
 def test_lazy_mesh_parity():
     """A 4x2 mesh lazy step equals the single-device result."""
     devices = jax.devices()

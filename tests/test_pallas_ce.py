@@ -9,8 +9,9 @@ import pytest
 from code2vec_tpu.models import functional
 from code2vec_tpu.ops import pallas_ce
 
-pytestmark = pytest.mark.skipif(not pallas_ce.PALLAS_AVAILABLE,
-                                reason='pallas unavailable')
+# forced kernels reached through functional/Trainer carry no per-call
+# interpret flag: the fixture turns the interpreter on for this module
+pytestmark = pytest.mark.usefixtures('pallas_interpret')
 
 
 def _case(rng, batch=16, dim=8, vocab=40, num_valid=None):

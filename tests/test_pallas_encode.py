@@ -7,8 +7,9 @@ import pytest
 
 from code2vec_tpu.ops import pallas_encode
 
-pytestmark = pytest.mark.skipif(not pallas_encode.PALLAS_AVAILABLE,
-                                reason='pallas unavailable')
+# forced kernels reached through functional/Trainer carry no per-call
+# interpret flag: the fixture turns the interpreter on for this module
+pytestmark = pytest.mark.usefixtures('pallas_interpret')
 
 
 @pytest.mark.parametrize('n', [512, 1024, 700])  # incl. non-multiple of tile
@@ -34,9 +35,9 @@ def test_fused_matches_reference_math(n):
 
 
 def test_encode_with_pallas_flag_matches_plain_path():
-    """On CPU the flag falls back to the jnp path (the kernel only routes
-    on a real TPU backend) — this asserts the flag is safe everywhere; the
-    kernel itself is covered by the interpret-mode tests above."""
+    """``functional.encode(use_pallas=True)`` routes the kernel (here in
+    the interpreter, via the module's ``pallas_interpret`` fixture) and
+    matches the plain jnp path."""
     from code2vec_tpu.models import functional
     params = functional.init_params(
         jax.random.PRNGKey(0), token_vocab_size=20, path_vocab_size=10,

@@ -24,8 +24,9 @@ from code2vec_tpu.ops import pallas_ragged
 from tests.test_packed import random_plane_batch
 from tests.test_stage_batches import make_trainer
 
-pytestmark = pytest.mark.skipif(not pallas_ragged.PALLAS_AVAILABLE,
-                                reason='pallas unavailable')
+# forced kernels reached through functional/Trainer carry no per-call
+# interpret flag: the fixture turns the interpreter on for this module
+pytestmark = pytest.mark.usefixtures('pallas_interpret')
 
 
 def small_params(rng_seed=0, token_vocab=32, path_vocab=16,

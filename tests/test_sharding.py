@@ -256,6 +256,7 @@ def test_zero_opt_state_requires_whole_mesh_alignment():
 
 
 @pytest.mark.parametrize('fused', [False, True])
+@pytest.mark.usefixtures('pallas_interpret')
 def test_bf16_grads_on_mixed_mesh_tracks_fp32_twin(fused):
     """The combined pod recipe: GRADS_DTYPE='bfloat16' (bf16 compute, as
     verify() requires) on a (4,2) DP+TP mesh, with and without the
