@@ -2,9 +2,10 @@
 
 The tracing analogue of ``metrics-schema`` / ``fault-points``: every
 span emission site (``.begin('x.y')`` / ``.span('x.y')`` /
-``.span_at('x.y')`` / ``.event('x.y')`` / ``.single('x.y')``) must name
-a span cataloged in ``telemetry/tracing.py::SPAN_CATALOG``, every
-cataloged span must be documented in OBSERVABILITY.md, and — like the
+``.span_at('x.y')`` / ``.event('x.y')`` / ``.single('x.y')`` /
+``.phase('x.y')``, whose profiler event is named ``x/y`` after the same
+entry) must name a span cataloged in
+``telemetry/tracing.py::SPAN_CATALOG``, every cataloged span must be documented in OBSERVABILITY.md, and — like the
 fault-point rule — every cataloged span must be WIRED at some call
 site: a stale catalog entry would document a phase the span log can
 never contain, the drift this lint exists to close.
@@ -31,7 +32,7 @@ from code2vec_tpu.analysis.walker import SourceTree
 # forwarding calls (trace._add(name, ...)) are invisible by design, and
 # the dot requirement keeps unrelated .begin()/.event() calls out
 SPAN_RE = re.compile(
-    r"""\.(?:begin|span|span_at|event|single)\(\s*"""
+    r"""\.(?:begin|span|span_at|event|single|phase)\(\s*"""
     r"""['"]([a-z0-9_]+\.[a-z0-9_.]+)['"]""")
 
 DOC_NAME = 'OBSERVABILITY.md'

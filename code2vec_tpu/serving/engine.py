@@ -72,6 +72,8 @@ runbook.
 from __future__ import annotations
 
 import collections
+import gc
+import itertools
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -405,10 +407,12 @@ def tokenize_and_chunk(reader: PathContextReader,
     or under the top bucket stays whole; larger ones split into
     ``_Request`` chunks re-joined in order through an ``_Aggregate``
     (chunk spans nest each chunk's phases under the shared trace)."""
-    batch = reader.process_input_rows(lines)
+    with tracing_lib.phase('serving.tokenize',
+                           rows=len(lines)) as tokenize:
+        batch = reader.process_input_rows(lines)
     if trace is not None:
-        trace.span_at('serving.tokenize', t_tokenize0,
-                      time.perf_counter())
+        # from the admission span's end: the two tile
+        tokenize.span(trace, t0=t_tokenize0)
     n = int(batch.label.shape[0])
     if n <= max_bucket:
         return [_Request(batch, tier, future=future,
@@ -430,6 +434,27 @@ def tokenize_and_chunk(reader: PathContextReader,
             deadline_s=deadline_s, trace=trace,
             span_parent=chunk_span))
     return requests
+
+
+class _GcPauseHook:
+    """``gc.callbacks`` hook: every generation-2 collection becomes a
+    ``process/gc_pause`` profiler event (entered on ``start``, left on
+    ``stop``, both on the collecting thread).  The interpreter calls the
+    hook for every collection; younger generations return at once."""
+
+    def __init__(self):
+        self._open: Optional[tracing_lib.Phase] = None
+
+    def __call__(self, when: str, info: dict) -> None:
+        if info['generation'] != 2:
+            return
+        if when == 'start':
+            self._open = tracing_lib.phase('process.gc_pause',
+                                           generation=2)
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
 
 
 class _Rollover:
@@ -682,6 +707,14 @@ class ServingEngine:
             trainer.backend.param_shapes())
         self._follow_thread: Optional[threading.Thread] = None
         self._follow_stop = threading.Event()
+        # joins a batch's profiler events across the dispatcher and the
+        # decode worker (the `batch` stat of serving/pack, /fetch,
+        # /deliver)
+        self._batch_seq = itertools.count(1)
+        # whole-process stalls in a profiler capture: generation-2
+        # collections as process/gc_pause events, until close()
+        self._gc_hook = _GcPauseHook()
+        gc.callbacks.append(self._gc_hook)
         self._decode_pool = ThreadPoolExecutor(
             max_workers=max(1, workers),
             thread_name_prefix='serving-decode'
@@ -1013,7 +1046,8 @@ class ServingEngine:
 
             def lookup():
                 try:
-                    values, indices = index.search(vectors, k)
+                    values, indices = self._search_index(index, vectors,
+                                                         k)
                     _resolve(outer, neighbors_from_search(
                         values, indices, index.labels))
                 except BaseException as exc:
@@ -1032,7 +1066,7 @@ class ServingEngine:
                     _resolve(outer, [])
                     return
                 vectors = np.stack([r.code_vector for r in results])
-                values, indices = index.search(vectors, k)
+                values, indices = self._search_index(index, vectors, k)
                 _resolve(outer, neighbors_from_search(
                     values, indices, index.labels))
             except BaseException as exc:
@@ -1040,6 +1074,18 @@ class ServingEngine:
                     outer.set_exception(exc)
         inner.add_done_callback(chain)
         return outer
+
+    def _search_index(self, index, vectors: np.ndarray, k: int):
+        """``index.search`` on the calling decode worker, as a phase: the
+        callback holds no request trace, so the span is a trace of its
+        own (head-sampled: it fires once a neighbour query)."""
+        attrs = {'rows': len(vectors), 'k': k}
+        with tracing_lib.phase('serving.index_search', **attrs) as search:
+            found = index.search(vectors, k)
+        if self._tracer is not None:
+            self._tracer.single(search.name, attrs=attrs, t0=search.t0,
+                                t1=search.t1, always=False)
+        return found
 
     def predict_neighbors(self, context_or_vectors,
                           k: Optional[int] = None,
@@ -1329,6 +1375,9 @@ class ServingEngine:
                 self.log('serving: follow-checkpoints poll failed: %s'
                          % exc)
 
+    def _queued_locked(self) -> bool:
+        return any(self._queues[t] for t in PREDICT_TIERS)
+
     def _set_queue_depth_locked(self) -> None:
         depth = sum(len(q) for q in self._queues.values())
         self.queue_depth.set(depth)
@@ -1340,9 +1389,11 @@ class ServingEngine:
         while True:
             abandoned: List[_Request] = []
             with self._cond:
-                while not self._closed and \
-                        not any(self._queues[t] for t in PREDICT_TIERS):
-                    self._cond.wait()
+                if not self._closed and not self._queued_locked():
+                    with tracing_lib.phase('serving.no_work'):
+                        while not self._closed and \
+                                not self._queued_locked():
+                            self._cond.wait()
                 if self._closed and not self._drain:
                     # fail-fast close: queued work is going nowhere —
                     # every undispatched future fails typed below (the
@@ -1353,11 +1404,7 @@ class ServingEngine:
                         self._queues[t].clear()
                         self._pending_rows[t] = 0
                     self._set_queue_depth_locked()
-                if self._closed and \
-                        not any(self._queues[t] for t in PREDICT_TIERS):
-                    done = True
-                else:
-                    done = False
+                done = self._closed and not self._queued_locked()
             if abandoned or done:
                 for request in abandoned:
                     request.fail(EngineClosed(
@@ -1368,7 +1415,7 @@ class ServingEngine:
                     return
                 continue
             with self._cond:
-                if not any(self._queues[t] for t in PREDICT_TIERS):
+                if not self._queued_locked():
                     continue  # raced a drain-close or expiry
                 # serve the tier whose head request has waited longest
                 tier = min(
@@ -1377,12 +1424,13 @@ class ServingEngine:
                 deadline = (self._queues[tier][0].t_enqueue
                             + self.max_delay_s)
                 max_bucket = self.buckets[-1]
-                while not self._closed:
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0 or \
-                            self._pending_rows[tier] >= max_bucket:
-                        break
-                    self._cond.wait(remaining)
+                with tracing_lib.phase('serving.coalesce'):
+                    while not self._closed:
+                        remaining = deadline - time.perf_counter()
+                        if remaining <= 0 or \
+                                self._pending_rows[tier] >= max_bucket:
+                            break
+                        self._cond.wait(remaining)
                 if self._closed and not self._drain:
                     # a fail-fast close() landed during coalescing:
                     # the requests being gathered must fail typed at
@@ -1467,25 +1515,28 @@ class ServingEngine:
             if request.queue_span is not None:
                 request.trace.end(request.queue_span, t0)
                 request.queue_span = None
-        stalled = faults.maybe_fire('slow_dispatch')
-        if stalled:
+        stall = None
+        if faults.maybe_fire('slow_dispatch'):
             # deterministic overload: the queue keeps filling while the
             # dispatcher stalls here, driving shed/expiry/degrade drills
-            time.sleep(faults.SLOW_DISPATCH_SECONDS)
-        t_stall = time.perf_counter()
-        merged = (taken[0].batch if len(taken) == 1 else
-                  PathContextReader._concat([r.batch for r in taken]))
+            with tracing_lib.phase('serving.stall') as stall:
+                time.sleep(faults.SLOW_DISPATCH_SECONDS)
+        seq = next(self._batch_seq)
         bucket = pick_bucket(rows, self.buckets)
-        padded = self.reader.pad_batch_to(merged, bucket)
-        if self.wire == 'packed':
-            host_arrays, capacity = self._pack_padded(padded, bucket)
-        else:
-            host_arrays, capacity = padded.device_arrays(), 0
-        t_pack = time.perf_counter()
-        arrays = mesh_lib.shard_batch(host_arrays, self.mesh,
-                                      self.config.SHARD_CONTEXTS,
-                                      direct=True)
-        t_h2d = time.perf_counter()
+        with tracing_lib.phase('serving.pack', batch=seq, rows=rows,
+                               bucket=bucket, requests=len(taken),
+                               tier=tier) as pack:
+            merged = (taken[0].batch if len(taken) == 1 else
+                      PathContextReader._concat([r.batch for r in taken]))
+            padded = self.reader.pad_batch_to(merged, bucket)
+            if self.wire == 'packed':
+                host_arrays, capacity = self._pack_padded(padded, bucket)
+            else:
+                host_arrays, capacity = padded.device_arrays(), 0
+        with tracing_lib.phase('serving.h2d', batch=seq) as h2d:
+            arrays = mesh_lib.shard_batch(host_arrays, self.mesh,
+                                          self.config.SHARD_CONTEXTS,
+                                          direct=True)
         stale = None
         with self._lock:
             params = self.params
@@ -1513,19 +1564,14 @@ class ServingEngine:
         # async dispatch: returns with device futures; the decode pool
         # blocks on them, the dispatcher goes back to coalescing.  The
         # enqueue itself is serialized across engines (mesh replicas):
-        # see _DISPATCH_ENQUEUE_LOCK
-        with _DISPATCH_ENQUEUE_LOCK:
-            if self._tracer is not None:
-                # bridge into the profiler timeline (OBSERVABILITY.md):
-                # the dispatch shows up as a named host lane next to the
-                # trainer's StepTraceAnnotation scopes in captured traces
-                import jax
-                with jax.profiler.TraceAnnotation('serving/dispatch'):
-                    out = self.trainer.predict_step_placed(params, arrays,
-                                                           tier=tier)
-            else:
-                out = self.trainer.predict_step_placed(params, arrays,
-                                                       tier=tier)
+        # see _DISPATCH_ENQUEUE_LOCK.  The phase is the named host lane
+        # of a profiler capture (OBSERVABILITY.md), next to the
+        # trainer's StepTraceAnnotation scopes
+        with tracing_lib.phase('serving.dispatch',
+                               batch=seq) as dispatch, \
+                _DISPATCH_ENQUEUE_LOCK:
+            out = self.trainer.predict_step_placed(params, arrays,
+                                                   tier=tier)
             shadow_out = None
             if rollover is not None and tier != 'vectors':
                 # canary shadow: same arrays, same shapes/shardings —
@@ -1534,7 +1580,7 @@ class ServingEngine:
                 # re-feeding `arrays` is safe)
                 shadow_out = self.trainer.predict_step_placed(
                     rollover.params, arrays, tier=tier)
-        t_disp = time.perf_counter()
+        t_disp = dispatch.t1
         if traced:
             t_head = min(request.t_enqueue for request in taken)
             # the pack span carries the dispatch attribution the latency
@@ -1550,16 +1596,16 @@ class ServingEngine:
                 tr.span_at('serving.coalesce', t_head, t0, parent=parent,
                            attrs={'requests': len(taken),
                                   'overlaps': 'queue_wait'})
-                if stalled:
-                    tr.span_at('serving.stall', t0, t_stall,
-                               parent=parent,
-                               attrs={'fault': 'slow_dispatch'})
-                tr.span_at('serving.pack', t_stall, t_pack, parent=parent,
-                           attrs=pack_attrs)
-                tr.span_at('serving.h2d', t_pack, t_h2d, parent=parent)
-                tr.span_at('serving.dispatch', t_h2d, t_disp,
-                           parent=parent,
-                           attrs={'shadow': shadow_out is not None})
+                if stall is not None:
+                    stall.span(tr, parent, {'fault': 'slow_dispatch'},
+                               t0=t0)
+                pack.span(tr, parent, pack_attrs)
+                # from the previous phase's end: the chain tiles (the
+                # params read between h2d and dispatch is microseconds)
+                h2d.span(tr, parent, t0=pack.t1)
+                dispatch.span(tr, parent,
+                              {'shadow': shadow_out is not None},
+                              t0=h2d.t1)
         dispatch_s = t_disp - t0
         self.dispatch_timer.record(dispatch_s)
         self.batches_total.inc()
@@ -1573,57 +1619,75 @@ class ServingEngine:
             reg.counter('serving/batches_total').inc()
             reg.gauge('serving/batch_fill_rate').set(rows / bucket)
         self._decode_pool.submit(self._decode, out, shadow_out, rollover,
-                                 padded, taken, t_disp)
+                                 padded, taken, t_disp, t0, seq)
 
     # ----------------------------------------------------------- decode
     def _decode(self, out: dict, shadow_out: Optional[dict],
                 rollover: Optional[_Rollover], padded: Batch,
                 taken: List[_Request],
-                t_dispatched: Optional[float] = None) -> None:
+                t_dispatched: Optional[float] = None,
+                t_popped: Optional[float] = None,
+                seq: int = 0) -> None:
         try:
             t0 = time.perf_counter()
+            if t_dispatched is None:
+                t_dispatched = t0
+            if t_popped is None:
+                t_popped = t0
+            n_rows = sum(request.rows for request in taken)
             # fetch ONLY the keys the tier produced (np.asarray blocks on
             # the device value — this is the worker pool's job, never the
-            # dispatcher's)
-            fetched = {key: np.asarray(value)
-                       for key, value in out.items()}
-            fetch_s = time.perf_counter() - t0
-            n_rows = sum(request.rows for request in taken)
-            results = decode_results(fetched, padded, n_rows,
-                                     self.decode_table)
-            decode_s = time.perf_counter() - t0
+            # dispatcher's).  The wait for this worker is known here, so
+            # it travels on the event that follows it
+            with tracing_lib.phase(
+                    'serving.fetch', batch=seq, rows=n_rows,
+                    handoff_ms=1e3 * (t0 - t_dispatched)) as fetch:
+                fetched = {key: np.asarray(value)
+                           for key, value in out.items()}
+            with tracing_lib.phase('serving.decode', batch=seq) as decode:
+                results = decode_results(fetched, padded, n_rows,
+                                         self.decode_table)
+            t_fetch, t_decode = fetch.t1, decode.t1
+            fetch_s = t_fetch - t0
+            decode_s = t_decode - t0
             self.decode_timer.record(decode_s)
             if tele_core.enabled():
                 self._mirror.timer(
                     'serving/decode_ms').record(decode_s)
-            t_fetch = t0 + fetch_s
-            t_decode = t0 + decode_s
             row = 0
             now = time.perf_counter()
             for request in taken:
-                deliver_span = None
-                if request.trace is not None:
-                    # record BEFORE deliver: the aggregate-completing
-                    # chunk finishes the shared trace inside deliver(),
-                    # and spans added after finish are dropped
-                    tr, parent = request.trace, request.span_parent
-                    # device time comes from the EXISTING async fetch
-                    # boundary (the blocking np.asarray above): dispatch
-                    # return -> fetch completion, never a new sync
-                    dev = tr.span_at(
-                        'serving.device_execute',
-                        t_dispatched if t_dispatched is not None else t0,
-                        t_fetch, parent=parent)
-                    tr.span_at('serving.fetch', t0, t_fetch, parent=dev)
-                    tr.span_at('serving.decode', t_fetch, t_decode,
-                               parent=parent)
-                    # deliver opens at decode end, so the wait behind
-                    # earlier requests' sequential deliveries in this
-                    # loop is attributed, not a phase gap
-                    deliver_span = tr.span(
-                        'serving.deliver', parent=parent, t0=t_decode,
-                        attrs={'rows': request.rows})
-                request.deliver(results[row:row + request.rows])
+                t_turn = time.perf_counter()
+                with tracing_lib.phase(
+                        'serving.deliver', batch=seq, rows=request.rows,
+                        tier=request.tier,
+                        queue_wait_ms=1e3 * (
+                            t_popped - request.t_enqueue),
+                        since_enqueue_ms=1e3 * (
+                            t_turn - request.t_enqueue)) as deliver:
+                    deliver_span = None
+                    if request.trace is not None:
+                        # record BEFORE deliver: the aggregate-completing
+                        # chunk finishes the shared trace inside
+                        # deliver(), and spans added after finish are
+                        # dropped
+                        tr, parent = request.trace, request.span_parent
+                        tr.span_at('serving.handoff', t_dispatched, t0,
+                                   parent=parent)
+                        # device time comes from the EXISTING async fetch
+                        # boundary (the blocking np.asarray above), never
+                        # a new sync
+                        dev = tr.span_at('serving.device_execute', t0,
+                                         t_fetch, parent=parent)
+                        fetch.span(tr, dev, t0=t0)
+                        decode.span(tr, parent, t0=t_fetch)
+                        # deliver opens at decode end, so the wait behind
+                        # earlier requests' sequential deliveries in this
+                        # loop is attributed, not a phase gap
+                        deliver_span = deliver.span(
+                            tr, parent, {'rows': request.rows},
+                            t0=t_decode)
+                    request.deliver(results[row:row + request.rows])
                 row += request.rows
                 latency = now - request.t_enqueue
                 self.latency.record(latency)
@@ -1760,6 +1824,8 @@ class ServingEngine:
         if self._dispatcher is not None:
             self._dispatcher.join()
         self._decode_pool.shutdown(wait=True)
+        if self._gc_hook in gc.callbacks:  # a second close() finds none
+            gc.callbacks.remove(self._gc_hook)
         # retire this engine's ledger entries: the params it swapped in
         # and an armed candidate (release is no-op-safe, so racing the
         # weakref finalizer is fine). The warm-ladder executables stay

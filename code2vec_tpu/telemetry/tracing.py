@@ -29,6 +29,14 @@ precondition for operating continuous rollovers under live traffic:
   open, and engine close — the serving analogue of the divergence
   guard's ``divergence_step<k>.json`` (PR 3).
 
+- **Profiler events.** ``phase(name, **stats)`` is the one opening of
+  an engine phase: it stamps ``perf_counter`` for the request's span
+  AND enters a ``jax.profiler.TraceAnnotation`` named after the catalog
+  entry (``serving.pack`` -> ``serving/pack``), so a profiler capture of
+  a live engine shows the phases on the device trace's own clock
+  (OBSERVABILITY.md "Reading the phases in a profiler capture").  With
+  no profiler session live the annotation is a TraceMe check.
+
 Span names are cataloged in ``SPAN_CATALOG``; the graftlint rule
 ``span-catalog`` (analysis/rules/span_catalog.py) lints every emission
 site against it, the same pattern as the metric and fault-point
@@ -66,10 +74,14 @@ SPAN_CATALOG: Dict[str, str] = {
                         'into a plane batch (reader.process_input_rows).',
     'serving.queue_wait': 'Enqueue to dispatcher pop (includes the '
                           'coalescing window the batch head opened).',
+    'serving.no_work': 'Engine-level, profiler event only: the dispatcher '
+                       'waiting on an EMPTY queue — tells "nothing was '
+                       'offered" from "coalescing" in a capture.',
     'serving.coalesce': 'Batch-level: head-request enqueue to pop — the '
                         'micro-batcher gathering window (overlaps the '
                         'member requests\' queue_wait; excluded from '
-                        'phase sums).',
+                        'phase sums).  Its profiler event is the '
+                        'dispatcher\'s own wait inside that window.',
     'serving.stall': 'Injected slow_dispatch fault stall (drills only).',
     'serving.pack': 'Merge + pad to bucket + packed-wire pack of the '
                     'coalesced micro-batch.',
@@ -77,15 +89,30 @@ SPAN_CATALOG: Dict[str, str] = {
                    'arrays (mesh.shard_batch).',
     'serving.dispatch': 'Async enqueue of the warm predict program '
                         '(plus the canary shadow dispatch when armed).',
-    'serving.device_execute': 'Dispatch return to fetch completion at '
-                              'the async fetch boundary: device execute '
-                              '+ D2H + decode-worker handoff, with NO '
-                              'added sync.',
+    'serving.handoff': 'Dispatch return to the decode worker picking '
+                       'the batch up: the wait for a free worker (the '
+                       'device executes meanwhile).  Cut out of '
+                       'device_execute; on the profiler it travels as '
+                       'handoff_ms of serving/fetch.',
+    'serving.device_execute': 'Decode-worker start to fetch completion at '
+                              'the async fetch boundary: what is left of '
+                              'device execute + D2H once a worker holds '
+                              'the batch, with NO added sync (handoff + '
+                              'this = dispatch return to fetch '
+                              'completion).',
     'serving.fetch': 'The blocking device fetch itself (decode worker '
                      'np.asarray), nested inside device_execute.',
     'serving.decode': 'Host-side top-k word lookup / attention parsing '
                       'of the fetched arrays.',
-    'serving.deliver': 'Resolving one request\'s future with its rows.',
+    'serving.deliver': 'Resolving one request\'s future with its rows '
+                       '(done-callbacks run inside it: a neighbour '
+                       'query\'s index search).  The span opens at '
+                       'decode end, the profiler event when this '
+                       'request\'s turn comes.',
+    'serving.index_search': 'One submit_neighbors index.search on a '
+                            'decode worker (attrs: rows, k); a '
+                            'single-span trace, the callback holds no '
+                            'request trace.',
     'serving.shed': 'Terminal: shed at admission with EngineOverloaded '
                     '(attrs carry the reason).',
     'serving.expired': 'Terminal: SLO deadline passed while queued '
@@ -118,6 +145,9 @@ SPAN_CATALOG: Dict[str, str] = {
                         'rows, memo=exact|semantic); '
                         'latency_report.py --fleet attributes the '
                         'saved work off these.',
+    'process.gc_pause': 'Profiler event only: one generation-2 garbage '
+                        'collection of the serving process, from the '
+                        'gc.callbacks hook an engine installs.',
     'extractor.call': 'One ExtractorPool call (attrs: attempt count, '
                       'breaker state, outcome).',
     'autoscale.transition': 'One autoscaler scale transition, decision '
@@ -149,6 +179,62 @@ DUMP_MIN_INTERVAL_S = 30.0
 #: flight recorder once (debounced above)
 SHED_BURST = 8
 SHED_WINDOW_S = 1.0
+
+
+def profiler_name(name: str) -> str:
+    """A catalog entry's name as a profiler event: ``.`` -> ``/``."""
+    return name.replace('.', '/')
+
+
+_TRACE_ANNOTATION = None
+
+
+def _trace_annotation():
+    # jax is imported on first use, so this module stays importable
+    # (and the span log usable) without it
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
+class Phase:
+    """One opening of a cataloged phase on the thread that does the
+    work, feeding both sinks: ``t0``/``t1`` (``perf_counter``) for the
+    request's ``Span`` and a profiler event with ``stats``.  A ``stats``
+    value has to be known when the phase opens."""
+
+    __slots__ = ('name', 't0', 't1', '_annotation')
+
+    def __init__(self, name: str, stats: dict):
+        self.name = name
+        self.t0 = self.t1 = None
+        self._annotation = _trace_annotation()(profiler_name(name), **stats)
+
+    def __enter__(self) -> 'Phase':
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+
+    def span(self, trace: 'Trace', parent: Optional['Span'] = None,
+             attrs: Optional[dict] = None,
+             t0: Optional[float] = None) -> 'Span':
+        """This phase as a span of ``trace``: closed if the phase is,
+        else open (end it with ``trace.end``).  ``t0`` moves the span's
+        start back to an instant another thread stamped."""
+        return trace._add(self.name, self.t0 if t0 is None else t0,
+                          self.t1, parent, attrs)
+
+
+def phase(name: str, **stats) -> Phase:
+    """``with phase('serving.pack', rows=n) as pack: ...`` — the
+    emission site the ``span-catalog`` lint checks."""
+    return Phase(name, stats)
 
 
 class Span:
@@ -364,13 +450,18 @@ class Tracer:
 
     def single(self, name: str, attrs: Optional[dict] = None,
                t0: Optional[float] = None,
-               t1: Optional[float] = None) -> None:
+               t1: Optional[float] = None,
+               always: bool = True) -> None:
         """One-shot single-span trace for engine-level events that
-        outlive their request traces (canary shadow scoring)."""
+        outlive their request traces (canary shadow scoring).  Rare
+        events are ``always`` retained; one that fires per request (a
+        neighbour query's index search) is head-sampled like a
+        request."""
         trace = self.begin(name, attrs=attrs)
         if t0 is not None:
             trace.root.t0 = t0
-        trace.sampled = True  # engine events are rare: always retained
+        if always:
+            trace.sampled = True
         if t1 is not None:
             trace.root.t1 = t1
         trace.finish(status='ok')

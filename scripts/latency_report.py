@@ -63,7 +63,8 @@ OVERLAPPING = frozenset(('serving.coalesce',))
 PHASE_CHAIN = (
     'serving.admission', 'serving.tokenize', 'serving.queue_wait',
     'serving.stall', 'serving.pack', 'serving.h2d', 'serving.dispatch',
-    'serving.device_execute', 'serving.decode', 'serving.deliver',
+    'serving.handoff', 'serving.device_execute', 'serving.decode',
+    'serving.deliver',
 )
 
 

@@ -122,6 +122,7 @@ def _span_sequence_cost_per_request(reps=2000):
         trace.span_at('serving.h2d', now, now)
         trace.span_at('serving.dispatch', now, now,
                       attrs={'shadow': False})
+        trace.span_at('serving.handoff', now, now)
         dev = trace.span_at('serving.device_execute', now, now)
         trace.span_at('serving.fetch', now, now, parent=dev)
         trace.span_at('serving.decode', now, now)
