@@ -141,7 +141,9 @@ class NativeTokenizer:
     def tokenize_lines(self, lines: Sequence[str]):
         n = len(lines)
         max_contexts = self.config.MAX_CONTEXTS
-        encoded = [line.encode('utf-8') for line in lines]
+        # surrogatepass: a line the Python path accepts is accepted here
+        # (a lone surrogate matches no vocabulary word on either path)
+        encoded = [line.encode('utf-8', 'surrogatepass') for line in lines]
         blob = b'\n'.join(encoded)
         # offsets[i] = byte start of line i; the slice [off[i], off[i+1])
         # includes the '\n' separator, which the C++ side strips

@@ -63,9 +63,7 @@ class PackedBatch(NamedTuple):
     label: np.ndarray                # (B,) int32 — target-name index
     weight: np.ndarray               # (B,) float32 — example validity
     label_strings: Optional[np.ndarray] = None     # (B,) object
-    source_strings: Optional[np.ndarray] = None    # (B, C) object
-    path_strings: Optional[np.ndarray] = None      # (B, C) object
-    target_strings: Optional[np.ndarray] = None    # (B, C) object
+    context_lines: Optional[np.ndarray] = None     # (B,) object
 
     @property
     def num_valid_examples(self) -> int:
@@ -193,9 +191,7 @@ def pack_batch(batch, token_pad: int, path_pad: int, data_shards: int = 1,
                        label=np.ascontiguousarray(batch.label),
                        weight=np.ascontiguousarray(batch.weight),
                        label_strings=batch.label_strings,
-                       source_strings=batch.source_strings,
-                       path_strings=batch.path_strings,
-                       target_strings=batch.target_strings)
+                       context_lines=batch.context_lines)
 
 
 class StickyPacker:
@@ -281,9 +277,7 @@ def unpack_batch_host(packed: PackedBatch, max_contexts: int,
     return Batch(source=source, path=path, target=target, mask=mask,
                  label=packed.label, weight=packed.weight,
                  label_strings=packed.label_strings,
-                 source_strings=packed.source_strings,
-                 path_strings=packed.path_strings,
-                 target_strings=packed.target_strings)
+                 context_lines=packed.context_lines)
 
 
 def segment_structure(count2, cap: int):

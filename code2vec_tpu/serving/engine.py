@@ -84,7 +84,8 @@ import numpy as np
 from code2vec_tpu.data import packed as packed_lib
 from code2vec_tpu.data.reader import (Batch, EstimatorAction,
                                       PathContextReader,
-                                      canonicalize_contexts)
+                                      canonicalize_contexts,
+                                      context_triples)
 from code2vec_tpu.parallel import mesh as mesh_lib
 from code2vec_tpu.resilience import faults
 from code2vec_tpu.serving.errors import (DeadlineExceeded, EngineClosed,
@@ -154,19 +155,19 @@ def pick_bucket(n: int, ladder: Sequence[int]) -> Optional[int]:
     return None
 
 
-def attention_per_context(source_strings, path_strings, target_strings,
-                          attention_weights) -> Dict[Tuple[str, str, str],
-                                                     float]:
-    """Per-context attention dict, skipping padding contexts (reference
-    model_base.py:115-129). Single definition — model_api and the engine
-    decode both use it."""
+def attention_per_context(context_line: str, attention_weights
+                          ) -> Dict[Tuple[str, str, str], float]:
+    """Per-context attention dict of one row, skipping padding contexts
+    (reference model_base.py:115-129). Single definition — model_api and
+    the engine decode both use it.  The contexts' strings are made here,
+    from the row's line as it was tokenized, slot by slot beside the
+    weights: a row of 36 contexts costs 36 splits, and only when its
+    tier returned attention."""
     out: Dict[Tuple[str, str, str], float] = {}
-    for source, path, target, weight in zip(
-            source_strings, path_strings, target_strings,
-            attention_weights):
-        if not source and not path and not target:
-            continue  # padding context
-        out[(str(source), str(path), str(target))] = float(weight)
+    for triple, weight in zip(context_triples(context_line),
+                              attention_weights.tolist()):
+        if any(triple):  # else a padding context
+            out[triple] = weight
     return out
 
 
@@ -184,10 +185,9 @@ def decode_results(fetched: Dict[str, np.ndarray], batch: Batch,
     results = []
     for r in range(n_rows):
         attn = {}
-        if attention is not None and batch.source_strings is not None:
-            attn = attention_per_context(
-                batch.source_strings[r], batch.path_strings[r],
-                batch.target_strings[r], attention[r])
+        if attention is not None and batch.context_lines is not None:
+            attn = attention_per_context(batch.context_lines[r],
+                                         attention[r])
         results.append(ModelPredictionResults(
             original_name=(str(batch.label_strings[r])
                            if batch.label_strings is not None else ''),
@@ -407,8 +407,8 @@ def tokenize_and_chunk(reader: PathContextReader,
     or under the top bucket stays whole; larger ones split into
     ``_Request`` chunks re-joined in order through an ``_Aggregate``
     (chunk spans nest each chunk's phases under the shared trace)."""
-    with tracing_lib.phase('serving.tokenize',
-                           rows=len(lines)) as tokenize:
+    with tracing_lib.phase('serving.tokenize', rows=len(lines),
+                           native=int(reader.native)) as tokenize:
         batch = reader.process_input_rows(lines)
     if trace is not None:
         # from the admission span's end: the two tile
@@ -545,8 +545,11 @@ class ServingEngine:
         self.log = log if log is not None else (lambda msg: None)
         self.mesh = trainer.mesh
         self.data_axis = self.mesh.shape[mesh_lib.DATA_AXIS]
-        # predict semantics: rows are never filtered; strings ride along
-        # for the attention tiers' decode
+        # predict semantics: rows are never filtered; each row's line
+        # rides along for the attention tiers' decode.  Built here, in
+        # set-up, because the reader loads the native tokenizer's
+        # vocabulary (over a second at java14m size; the first run of a
+        # checkout compiles the library too): never on a request
         self.reader = PathContextReader(vocabs, config,
                                         EstimatorAction.Predict)
         import jax
@@ -616,6 +619,10 @@ class ServingEngine:
         self.dispatch_timer = Timer('serving/dispatch_ms')
         self.decode_timer = Timer('serving/decode_ms')
         self.requests_total = Counter('serving/requests_total')
+        self.tokenize_native_rows_total = Counter(
+            'serving/tokenize_native_rows_total')
+        self.tokenize_fallback_rows_total = Counter(
+            'serving/tokenize_fallback_rows_total')
         self.batches_total = Counter('serving/batches_total')
         self.queue_depth = Gauge('serving/queue_depth')
         self.fill_rate = Gauge('serving/batch_fill_rate')
@@ -975,6 +982,14 @@ class ServingEngine:
             if trace is not None:
                 trace.finish(status='error', reason=repr(exc))
             raise
+        # how often the native tokenizer engages, against the fallback
+        native = self.reader.native
+        (self.tokenize_native_rows_total if native
+         else self.tokenize_fallback_rows_total).inc(n)
+        if tele_core.enabled():
+            self._mirror.counter(
+                'serving/tokenize_native_rows_total' if native
+                else 'serving/tokenize_fallback_rows_total').inc(n)
         with self._cond:
             self._reserved_rows -= n
             if self._closed:
@@ -1768,6 +1783,10 @@ class ServingEngine:
         return {
             'replica': self.replica_id,
             'requests_total': self.requests_total.snapshot(),
+            'tokenize_native_rows_total':
+                self.tokenize_native_rows_total.snapshot(),
+            'tokenize_fallback_rows_total':
+                self.tokenize_fallback_rows_total.snapshot(),
             'batches_total': self.batches_total.snapshot(),
             'queue_depth': self.queue_depth.snapshot(),
             'batch_fill_rate': self.fill_rate.snapshot(),

@@ -111,6 +111,14 @@ CATALOG: Dict[str, Dict[str, str]] = {
     # ---- serving engine (code2vec_tpu/serving/, SERVING.md) ----
     'serving/requests_total': _m(COUNTER, 'requests', 'Prediction requests '
                                  'submitted to the serving engine.'),
+    'serving/tokenize_native_rows_total': _m(
+        COUNTER, 'rows', 'Rows a submit() tokenized in the native '
+        'library (native/tokenizer.cpp, outside the interpreter lock).'),
+    'serving/tokenize_fallback_rows_total': _m(
+        COUNTER, 'rows', 'Rows a submit() tokenized in the Python '
+        'fallback (no native library on this host, or '
+        'READER_USE_NATIVE off): under the interpreter lock, slot by '
+        'slot.'),
     'serving/batches_total': _m(COUNTER, 'batches', 'Coalesced '
                                 'micro-batches dispatched to the device.'),
     'serving/queue_depth': _m(GAUGE, 'requests', 'Requests waiting in the '

@@ -71,7 +71,10 @@ SPAN_CATALOG: Dict[str, str] = {
     'serving.admission': 'Admission control: bound check, drain estimate '
                          'vs deadline, degradation ladder, reservation.',
     'serving.tokenize': 'Caller-thread tokenize of the raw context lines '
-                        'into a plane batch (reader.process_input_rows).',
+                        'into a plane batch (reader.process_input_rows). '
+                        'Profiler stats: rows, native (1 the native '
+                        'library, outside the interpreter lock; 0 the '
+                        'Python fallback).',
     'serving.queue_wait': 'Enqueue to dispatcher pop (includes the '
                           'coalescing window the batch head opened).',
     'serving.no_work': 'Engine-level, profiler event only: the dispatcher '

@@ -6,7 +6,8 @@
 // same reason, path_context_reader.py:122-125). Semantics are identical:
 //
 //   line   := label ' ' ctx (' ' ctx)*            (trailing spaces = padding)
-//   ctx    := source ',' path ',' target           (missing parts -> PAD)
+//   ctx    := source ',' path ',' target           (missing parts -> PAD,
+//                                                   further parts dropped)
 //   lookup := vocab.get(word, OOV); empty -> PAD
 //   mask   := any of the three indices != its PAD index
 //
@@ -96,7 +97,10 @@ void tokenize_range(const Tokenizer* tok, const char* buf,
             p_idx = tok->path.lookup(ctx.substr(c1 + 1));
           } else {
             p_idx = tok->path.lookup(ctx.substr(c1 + 1, c2 - c1 - 1));
-            t_idx = tok->token.lookup(ctx.substr(c2 + 1));
+            // a fourth part and beyond are dropped, as the Python
+            // path's split(',') drops them
+            t_idx = tok->token.lookup(
+                ctx.substr(c2 + 1, ctx.find(',', c2 + 1) - c2 - 1));
           }
         }
       }

@@ -6,6 +6,7 @@ import pytest
 from code2vec_tpu.config import Config
 from code2vec_tpu.data import (Batch, EstimatorAction, PathContextReader,
                                parse_c2v_line)
+from code2vec_tpu.data.reader import canonicalize_contexts
 from code2vec_tpu.vocab import Code2VecVocabs
 
 
@@ -162,3 +163,41 @@ def test_process_input_rows_never_filters(small_setup):
     assert batch.label.shape == (1,)
     assert batch.label_strings[0] == 'unknownlbl'
     np.testing.assert_array_equal(batch.mask[0], [0, 0, 0, 0])
+
+
+@pytest.mark.parametrize('use_native', [False, True],
+                         ids=['fallback', 'native'])
+def test_predict_batch_keeps_its_lines_through_slice_concat_and_pad(
+        small_setup, use_native):
+    """A predict batch carries each row's canonical line and label
+    beside its ids; slicing (an oversize request's chunks), merging (a
+    micro-batch) and padding (a bucket) keep them on their rows."""
+    from code2vec_tpu.data import native
+    if use_native and not native.is_available():
+        pytest.skip('native toolchain unavailable')
+    config, vocabs, prefix = small_setup
+    config.READER_USE_NATIVE = use_native
+    reader = PathContextReader(vocabs, config, EstimatorAction.Predict)
+    assert reader.native == use_native
+    lines = ['lbl%d %s' % (i, ' '.join(['s1,p1,t1'] * (i % 4 + 1)
+                                        + ['s2,p2,t1'] * (i % 3)))
+             for i in range(7)]
+    batch = reader.process_input_rows(lines)
+    canonical = canonicalize_contexts(lines, config.MAX_CONTEXTS)
+    assert list(batch.context_lines) == canonical
+
+    def rows_of(b):
+        return [(str(b.label_strings[r]), str(b.context_lines[r]),
+                 int(b.mask[r].sum())) for r in range(b.label.shape[0])]
+
+    want = [(line.split(' ', 1)[0], line, len(line.split(' ')) - 1)
+            for line in canonical]
+    assert rows_of(batch) == want
+    chunks = [PathContextReader._take_rows(batch, slice(i, i + 3))
+              for i in range(0, 7, 3)]
+    assert [rows_of(c) for c in chunks] == [want[0:3], want[3:6], want[6:]]
+    merged = PathContextReader._concat([chunks[2], chunks[0]])
+    assert rows_of(merged) == want[6:] + want[0:3]
+    padded = reader.pad_batch_to(merged, 8)
+    assert rows_of(padded) == want[6:] + want[0:3] + [('', '', 0)] * 4
+    assert list(padded.weight) == [1.0] * 4 + [0.0] * 4

@@ -226,3 +226,150 @@ def test_bulk_predict_streams_in_order(model):
         assert r.original_name == d.original_name
         assert r.topk_predicted_words == d.topk_predicted_words
         assert r.attention_per_context == {}
+
+
+# ------------------------------- tokenizing and the contexts' strings
+def _slotwise_attention(model, lines, tier):
+    """``attention_per_context`` the way the batch used to carry it: the
+    per-slot strings of ``parse_c2v_line`` beside the program's
+    weights."""
+    from code2vec_tpu.data.reader import (canonicalize_contexts,
+                                          parse_c2v_line)
+    contexts = model.config.MAX_CONTEXTS
+    reader = model._get_predict_reader()
+    batch = reader.pad_batch_to(reader.process_input_rows(lines), 8)
+    out = model.trainer.predict_step(model.params, batch, tier=tier)
+    attention = np.asarray(out['attention'])
+    want = []
+    for r, line in enumerate(canonicalize_contexts(lines, contexts)):
+        row = parse_c2v_line(line, contexts)
+        want.append({
+            (s, p, t): float(w) for s, p, t, w in zip(
+                row.source_strs, row.path_strs, row.target_strs,
+                attention[r]) if s or p or t})
+    return want
+
+
+@pytest.fixture
+def native_on(model, monkeypatch):
+    """The module's model (built for the fallback) with the native
+    tokenizer switched on for the readers a test builds."""
+    from code2vec_tpu.data import native
+    if not native.is_available():
+        pytest.skip('native toolchain unavailable')
+    monkeypatch.setattr(model.config, 'READER_USE_NATIVE', True)
+    return model
+
+
+AWKWARD_LINES = PREDICT_LINES + [
+    'get|a toka1,pB,toka2  toka0,pA,toka1 toka0,pA,toka1',  # twice, a gap
+    'nolabel',
+    'set|b tokb0,pA tokb1 ,,',
+    'run|c ' + ' '.join('tokc%d,pC,tokc%d' % (i % 3, (i + 1) % 3)
+                        for i in range(9)),                 # over-long
+]
+
+
+@pytest.mark.parametrize('tier', ['attention', 'full'])
+@pytest.mark.parametrize('tokenizer', ['fallback', 'native'])
+def test_attention_from_strings_made_at_decode(model, monkeypatch, tier,
+                                               tokenizer):
+    from code2vec_tpu.data import native
+    if tokenizer == 'native' and not native.is_available():
+        pytest.skip('native toolchain unavailable')
+    monkeypatch.setattr(model.config, 'READER_USE_NATIVE',
+                        tokenizer == 'native')
+    want = _slotwise_attention(model, AWKWARD_LINES, tier)
+    with model.serving_engine(tiers=(tier,), max_delay_ms=0.0) as engine:
+        assert engine.reader.native == (tokenizer == 'native')
+        served = engine.predict(AWKWARD_LINES, tier=tier, timeout=60)
+    assert [r.attention_per_context for r in served] == want
+    assert any(want) and not want[AWKWARD_LINES.index('nolabel')]
+    assert [r.original_name for r in served] == \
+        [line.split(' ', 1)[0] for line in AWKWARD_LINES]
+
+
+@pytest.mark.parametrize('tier', ['topk', 'vectors'])
+def test_no_context_strings_for_a_tier_without_attention(
+        native_on, monkeypatch, tier):
+    def never(line):
+        raise AssertionError('strings made for a %s row' % tier)
+    monkeypatch.setattr(engine_lib, 'context_triples', never)
+    with native_on.serving_engine(tiers=(tier,),
+                                  max_delay_ms=0.0) as engine:
+        served = engine.predict(AWKWARD_LINES, tier=tier, timeout=60)
+    assert len(served) == len(AWKWARD_LINES)
+    assert all(r.attention_per_context == {} for r in served)
+
+
+@pytest.mark.parametrize('tokenizer', ['fallback', 'native'])
+def test_oversize_split_keeps_lines_on_their_rows(model, monkeypatch,
+                                                  tokenizer):
+    """20 distinct rows over buckets 8,16: the chunks' lines, labels and
+    ids stay aligned, so every row's attention names its own contexts."""
+    from code2vec_tpu.data import native
+    from code2vec_tpu.data.reader import (canonicalize_contexts,
+                                          context_triples)
+    if tokenizer == 'native' and not native.is_available():
+        pytest.skip('native toolchain unavailable')
+    monkeypatch.setattr(model.config, 'READER_USE_NATIVE',
+                        tokenizer == 'native')
+    lines = ['m%d|x %s' % (i, ' '.join(
+        'tok%s%d,p%s,tok%s%d' % ('abc'[(i + j) % 3], j % 3, 'ABC'[j % 3],
+                                 'abc'[i % 3], (i + j) % 3)
+        for j in range(i % 6 + 1))) for i in range(20)]
+    with model.serving_engine(tiers=('attention',),
+                              max_delay_ms=0.0) as engine:
+        served = engine.predict(lines, tier='attention', timeout=60)
+        stats = engine.stats()
+    assert stats['batches_total'] == 2  # 16-row chunk + 4-row chunk
+    direct = model.predict(lines)
+    canonical = canonicalize_contexts(lines, model.config.MAX_CONTEXTS)
+    for i, (s, d) in enumerate(zip(served, direct)):
+        assert s.original_name == d.original_name == 'm%d|x' % i
+        assert set(s.attention_per_context) == \
+            set(context_triples(canonical[i]))
+        assert s.topk_predicted_words == d.topk_predicted_words
+        assert s.attention_per_context.keys() == \
+            d.attention_per_context.keys()
+        for key, weight in s.attention_per_context.items():
+            assert weight == pytest.approx(d.attention_per_context[key],
+                                           rel=1e-5, abs=1e-7)
+
+
+def test_engine_counts_rows_tokenized_natively(native_on):
+    with native_on.serving_engine(tiers=('topk',),
+                                  max_delay_ms=0.0) as engine:
+        # loaded with the reader, in set-up: never on a request
+        assert engine.reader.native
+        direct = native_on.predict(PREDICT_LINES)
+        served = engine.predict(PREDICT_LINES, timeout=60)
+        engine.predict(PREDICT_LINES[:1], timeout=60)
+        stats = engine.stats()
+    assert stats['tokenize_native_rows_total'] == 4
+    assert stats['tokenize_fallback_rows_total'] == 0
+    for s, d in zip(served, direct):
+        assert s.topk_predicted_words == d.topk_predicted_words
+
+
+def test_engine_serves_through_the_fallback_without_the_library(
+        model, monkeypatch):
+    """A host with no toolchain: ``READER_USE_NATIVE`` is on, the library
+    is not there, and the engine serves the same answers through the
+    Python tokenizer and says so."""
+    from code2vec_tpu.data import native
+    direct = model.predict(AWKWARD_LINES)      # the module's fallback
+    monkeypatch.setattr(model.config, 'READER_USE_NATIVE', True)
+    monkeypatch.setattr(native, 'is_available', lambda: False)
+    with model.serving_engine(tiers=('attention',),
+                              max_delay_ms=0.0) as engine:
+        assert not engine.reader.native
+        served = engine.predict(AWKWARD_LINES, tier='attention',
+                                timeout=60)
+        stats = engine.stats()
+    assert stats['tokenize_fallback_rows_total'] == len(AWKWARD_LINES)
+    assert stats['tokenize_native_rows_total'] == 0
+    for s, d in zip(served, direct):
+        assert s.original_name == d.original_name
+        assert s.topk_predicted_words == d.topk_predicted_words
+        assert s.attention_per_context == d.attention_per_context

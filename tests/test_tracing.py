@@ -547,7 +547,7 @@ def test_overload_drill_reconstructs_every_request(model, tmp_path):
 # ------------------------------------------- phases as profiler events
 #: every phase site of the engine: profiler event -> the stats it carries
 PROFILER_SITES = {
-    'serving/tokenize': {'rows'},
+    'serving/tokenize': {'rows', 'native'},
     'serving/no_work': set(),
     'serving/coalesce': set(),
     'serving/pack': {'batch', 'rows', 'bucket', 'requests', 'tier'},
@@ -657,6 +657,8 @@ def test_profiler_events_sit_on_the_thread_that_does_the_work(profiled):
     assert lines['serving/decode'] <= workers
     assert lines['serving/deliver'] <= workers
     assert not lines['serving/tokenize'] & (dispatcher | workers)
+    # the module's model reads with READER_USE_NATIVE off: the fallback
+    assert {int(t[3]['native']) for t in events['serving/tokenize']} == {0}
     # a neighbour query's search runs inside its deliver (from lines), or
     # as a pool task of its own (from vectors): once each
     searches = events['serving/index_search']
