@@ -378,9 +378,13 @@ class Config:
     # dispatch but more programs to pre-compile at load.
     SERVING_BATCH_BUCKETS: str = '8,64,512,1024'
     # Micro-batcher deadline: how long the dispatcher may hold the OLDEST
-    # queued request while coalescing followers into one bucket. The
-    # direct latency/throughput trade — 0 dispatches every request
-    # immediately (still bucketed + warm, just unbatched).
+    # queued request while coalescing followers into one bucket. An
+    # upper bound, not a fixed wait: the batch closes earlier as soon as
+    # a decode slot is free (fewer batches in flight than
+    # SERVING_DECODE_WORKERS) or the largest bucket is full, so the
+    # delay is only spent while the engine's own pipeline is busy. 0
+    # dispatches every request immediately (still bucketed + warm, just
+    # unbatched).
     SERVING_MAX_DELAY_MS: float = 5.0
     # Worker threads for host-side decode (device fetch, top-k word
     # lookup, attention parsing), so device dispatch never waits on
@@ -875,7 +879,9 @@ class Config:
                             default=None, metavar='MS',
                             help='micro-batcher coalescing deadline: max '
                                  'added latency while batching concurrent '
-                                 'requests (0 = dispatch immediately)')
+                                 'requests; a batch closes earlier once a '
+                                 'decode worker is free '
+                                 '(0 = dispatch immediately)')
         parser.add_argument('--serving-deadline-ms',
                             dest='serving_deadline_ms', type=float,
                             default=None, metavar='MS',

@@ -23,7 +23,13 @@ arxiv 2604.15464; Google's ads-serving infrastructure, arxiv 2501.10546
   covering batch bucket, packs them over the compact wire format
   (data/packed.py — the 0.24x bytes win applies directly to the h2d
   serving path), and dispatches asynchronously, so the device queue
-  stays full while the NEXT batch coalesces.
+  stays full while the NEXT batch coalesces.  A batch closes at the
+  first of three: a decode slot is free (fewer batches in flight than
+  decode workers — holding the request would buy nothing), the deadline
+  passed, or the largest bucket is full.  So an idle engine answers at
+  once and a loaded one gathers followers for as long as its own
+  pipeline is busy, never past the deadline
+  (``stats()['early_close_total']`` counts the first kind).
 - **Decode offload.** Host-side decode (device fetch, top-k word lookup,
   attention parsing) runs on a worker pool (``SERVING_DECODE_WORKERS``),
   so device dispatch never waits on Python.
@@ -624,6 +630,7 @@ class ServingEngine:
         self.tokenize_fallback_rows_total = Counter(
             'serving/tokenize_fallback_rows_total')
         self.batches_total = Counter('serving/batches_total')
+        self.early_close_total = Counter('serving/early_close_total')
         self.queue_depth = Gauge('serving/queue_depth')
         self.fill_rate = Gauge('serving/batch_fill_rate')
         self.shed_total = Counter('serving/shed_total')
@@ -639,7 +646,7 @@ class ServingEngine:
         # the queue / rollover / overload state; _cond wraps _lock, so
         # holding either alias guards the fields (lock-discipline rule,
         # ANALYSIS.md):
-        # graftlint: guard ServingEngine._queues,_pending_rows,_reserved_rows,_closed,_drain,params,_rollover,_params_step,_overload_level,_peak_rows,_service_rows_per_s,_service_window,_service_window_rows by _lock|_cond
+        # graftlint: guard ServingEngine._queues,_pending_rows,_reserved_rows,_closed,_drain,params,_rollover,_params_step,_overload_level,_peak_rows,_service_rows_per_s,_service_window,_service_window_rows,_in_flight by _lock|_cond
         # graftlint: guard ServingEngine._warm by _warm_lock
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -665,6 +672,11 @@ class ServingEngine:
         self._service_rows_per_s = 0.0
         self._service_window: collections.deque = collections.deque()
         self._service_window_rows = 0  # sum of rows in _service_window
+        # batches handed to the decode pool and not yet returned from
+        # _decode: with fewer of them than workers a decode slot is
+        # free, and the dispatcher closes a batch without waiting
+        self._in_flight = 0
+        self._decode_slots = max(1, workers)
         self._warm = False
         self._index = None  # attach_index() arms submit_neighbors
         self._warm_lock = threading.Lock()
@@ -723,7 +735,7 @@ class ServingEngine:
         self._gc_hook = _GcPauseHook()
         gc.callbacks.append(self._gc_hook)
         self._decode_pool = ThreadPoolExecutor(
-            max_workers=max(1, workers),
+            max_workers=self._decode_slots,
             thread_name_prefix='serving-decode'
             + ('' if replica_id is None else '-%s' % replica_id))
         if self._external:
@@ -1439,11 +1451,21 @@ class ServingEngine:
                 deadline = (self._queues[tier][0].t_enqueue
                             + self.max_delay_s)
                 max_bucket = self.buckets[-1]
+                early = False
                 with tracing_lib.phase('serving.coalesce'):
                     while not self._closed:
                         remaining = deadline - time.perf_counter()
                         if remaining <= 0 or \
                                 self._pending_rows[tier] >= max_bucket:
+                            break
+                        if self._in_flight < self._decode_slots:
+                            # a free decode slot: holding the head
+                            # request buys nothing.  With every slot
+                            # taken a dispatched batch would wait in
+                            # hand-off anyway: that wait is the
+                            # coalescing window, and the slot's release
+                            # (_decode_in_slot) wakes this loop
+                            early = True
                             break
                         self._cond.wait(remaining)
                 if self._closed and not self._drain:
@@ -1481,7 +1503,7 @@ class ServingEngine:
                        1e3 * (request.t_deadline - request.t_enqueue))))
             if taken:
                 try:
-                    self._dispatch_batch(tier, taken, rows)
+                    self._dispatch_batch(tier, taken, rows, early)
                 except BaseException as exc:  # keep the dispatcher alive
                     # OOM forensics at the jit-dispatch boundary
                     # (telemetry/memory.py): a RESOURCE_EXHAUSTED here
@@ -1523,7 +1545,10 @@ class ServingEngine:
                 np.ascontiguousarray(padded.weight)), capacity
 
     def _dispatch_batch(self, tier: str, taken: List[_Request],
-                        rows: int) -> None:
+                        rows: int, early: bool = False) -> None:
+        """``early``: the dispatcher closed this batch before its
+        deadline because a decode slot was free (never set by a mesh's
+        puller, whose coalescing is serving/frontqueue.py's)."""
         t0 = time.perf_counter()
         traced = [r for r in taken if r.trace is not None]
         for request in traced:
@@ -1540,7 +1565,7 @@ class ServingEngine:
         bucket = pick_bucket(rows, self.buckets)
         with tracing_lib.phase('serving.pack', batch=seq, rows=rows,
                                bucket=bucket, requests=len(taken),
-                               tier=tier) as pack:
+                               tier=tier, early=int(early)) as pack:
             merged = (taken[0].batch if len(taken) == 1 else
                       PathContextReader._concat([r.batch for r in taken]))
             padded = self.reader.pad_batch_to(merged, bucket)
@@ -1624,6 +1649,8 @@ class ServingEngine:
         dispatch_s = t_disp - t0
         self.dispatch_timer.record(dispatch_s)
         self.batches_total.inc()
+        if early:
+            self.early_close_total.inc()
         self.fill_rate.set(rows / bucket)
         self.last_dispatch = {'bucket': bucket, 'rows': rows,
                               'capacity': capacity,
@@ -1632,11 +1659,26 @@ class ServingEngine:
             reg = self._mirror
             reg.timer('serving/dispatch_ms').record(dispatch_s)
             reg.counter('serving/batches_total').inc()
+            if early:
+                reg.counter('serving/early_close_total').inc()
             reg.gauge('serving/batch_fill_rate').set(rows / bucket)
-        self._decode_pool.submit(self._decode, out, shadow_out, rollover,
-                                 padded, taken, t_disp, t0, seq)
+        with self._lock:
+            self._in_flight += 1
+        self._decode_pool.submit(self._decode_in_slot, out, shadow_out,
+                                 rollover, padded, taken, t_disp, t0, seq)
 
     # ----------------------------------------------------------- decode
+    def _decode_in_slot(self, *batch) -> None:
+        """A decode worker's task: ``_decode``, after which (delivered
+        or failed) its slot is free, and a dispatcher holding a batch
+        for one wakes."""
+        try:
+            self._decode(*batch)
+        finally:
+            with self._cond:
+                self._in_flight -= 1
+                self._cond.notify_all()
+
     def _decode(self, out: dict, shadow_out: Optional[dict],
                 rollover: Optional[_Rollover], padded: Batch,
                 taken: List[_Request],
@@ -1788,6 +1830,7 @@ class ServingEngine:
             'tokenize_fallback_rows_total':
                 self.tokenize_fallback_rows_total.snapshot(),
             'batches_total': self.batches_total.snapshot(),
+            'early_close_total': self.early_close_total.snapshot(),
             'queue_depth': self.queue_depth.snapshot(),
             'batch_fill_rate': self.fill_rate.snapshot(),
             'latency_ms': self.latency.snapshot(),

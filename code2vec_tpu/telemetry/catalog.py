@@ -121,6 +121,10 @@ CATALOG: Dict[str, Dict[str, str]] = {
         'slot.'),
     'serving/batches_total': _m(COUNTER, 'batches', 'Coalesced '
                                 'micro-batches dispatched to the device.'),
+    'serving/early_close_total': _m(
+        COUNTER, 'batches', 'Micro-batches the dispatcher closed before '
+        'SERVING_MAX_DELAY_MS because a decode slot was free (the rest '
+        'of batches_total closed at the deadline or on a full bucket).'),
     'serving/queue_depth': _m(GAUGE, 'requests', 'Requests waiting in the '
                               'micro-batcher queue.'),
     'serving/batch_fill_rate': _m(GAUGE, 'fraction', 'Valid rows / bucket '

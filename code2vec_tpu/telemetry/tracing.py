@@ -87,7 +87,9 @@ SPAN_CATALOG: Dict[str, str] = {
                         'dispatcher\'s own wait inside that window.',
     'serving.stall': 'Injected slow_dispatch fault stall (drills only).',
     'serving.pack': 'Merge + pad to bucket + packed-wire pack of the '
-                    'coalesced micro-batch.',
+                    'coalesced micro-batch.  Its profiler event says '
+                    'how the batch closed: early 1 (a decode slot was '
+                    'free) or 0 (deadline, full bucket).',
     'serving.h2d': 'Sharded host-to-device placement of the packed '
                    'arrays (mesh.shard_batch).',
     'serving.dispatch': 'Async enqueue of the warm predict program '

@@ -1,12 +1,16 @@
 """Serving engine (serving/engine.py + serving/bulk.py): bucket
-selection, deadline coalescing, exact parity with ``model.predict``,
-output tiers, oversize splitting, and the corpus-scale bulk paths."""
+selection, when a coalescing batch closes (a free decode slot, the
+deadline), exact parity with ``model.predict``, output tiers, oversize
+splitting, and the corpus-scale bulk paths."""
+import time
+
 import numpy as np
 import pytest
 
 from code2vec_tpu.config import Config
 from code2vec_tpu.data import packed as packed_lib
 from code2vec_tpu.serving import engine as engine_lib
+from tests.serving_slots import decode_slots_held
 from tests.test_train_overfit import make_dataset
 
 # the four labels/token families of make_dataset's corpus
@@ -100,23 +104,105 @@ def test_engine_matches_model_predict_exactly(model):
         assert s.code_vector is None and d.code_vector is None
 
 
-def test_deadline_coalescing_batches_concurrent_requests(model):
-    """Requests submitted inside one deadline window ride ONE dispatched
-    micro-batch, and each future gets exactly its own rows back."""
+def test_idle_engine_answers_a_lone_request_without_the_delay(model):
+    """A free decode slot closes the batch: the delay is the longest a
+    request may be held, not what an idle engine holds it for."""
     with model.serving_engine(tiers=('topk',),
                               max_delay_ms=500.0) as engine:
-        futures = [engine.submit([line], tier='topk')
-                   for line in PREDICT_LINES]
-        results = [f.result(timeout=60) for f in futures]
+        engine.predict([PREDICT_LINES[0]], tier='topk', timeout=60)
+        t0 = time.perf_counter()
+        (result,) = engine.predict([PREDICT_LINES[1]], tier='topk',
+                                   timeout=60)
+        elapsed = time.perf_counter() - t0
         stats = engine.stats()
-    assert stats['batches_total'] == 1
-    assert stats['requests_total'] == len(PREDICT_LINES)
+    assert stats['batches_total'] == 2
+    assert stats['early_close_total'] == 2
+    assert elapsed < 0.25, 'a lone request took %.3fs of a 0.5s delay' \
+        % elapsed
+    assert result.topk_predicted_words == \
+        model.predict([PREDICT_LINES[1]])[0].topk_predicted_words
+
+
+def test_deadline_coalescing_batches_concurrent_requests(model):
+    """Requests submitted while every decode slot is taken ride ONE
+    dispatched micro-batch when a slot frees, and each future gets
+    exactly its own rows back."""
+    with model.serving_engine(tiers=('topk',),
+                              max_delay_ms=60_000.0) as engine:
+        with decode_slots_held(engine, PREDICT_LINES[0]) as held:
+            futures = [engine.submit([line], tier='topk')
+                       for line in PREDICT_LINES]
+            time.sleep(0.05)  # the dispatcher had its chance
+            assert engine.stats()['batches_total'] == held.batches
+            held.release()
+            results = [f.result(timeout=60) for f in futures]
+        stats = engine.stats()
+    assert stats['batches_total'] == held.batches + 1
+    # closed by the freed slot, a minute before its deadline
+    assert stats['early_close_total'] == held.early + 1
+    assert stats['requests_total'] == held.batches + len(PREDICT_LINES)
     assert stats['last_dispatch']['requests'] == len(PREDICT_LINES)
     assert stats['last_dispatch']['rows'] == len(PREDICT_LINES)
     direct = model.predict(PREDICT_LINES)
     for (res,), d in zip(results, direct):
         assert res.original_name == d.original_name
         assert res.topk_predicted_words == d.topk_predicted_words
+
+
+def test_batch_closes_at_the_deadline_while_every_slot_is_taken(model):
+    """The deadline still ends the wait: with the slots held past it
+    the gathered requests go out as one batch, not closed early."""
+    with model.serving_engine(tiers=('topk',),
+                              max_delay_ms=50.0) as engine:
+        with decode_slots_held(engine, PREDICT_LINES[0]) as held:
+            futures = [engine.submit([line], tier='topk')
+                       for line in PREDICT_LINES[:2]]
+            deadline = time.perf_counter() + 30
+            while engine.stats()['batches_total'] == held.batches:
+                assert time.perf_counter() < deadline, \
+                    'no dispatch at the deadline'
+                time.sleep(0.005)
+            stats = engine.stats()  # the slots are still held
+        for future in futures:
+            assert future.result(timeout=60)
+    assert stats['batches_total'] == held.batches + 1
+    assert stats['early_close_total'] == held.early
+    assert stats['last_dispatch']['requests'] == 2
+
+
+def test_a_released_slot_wakes_the_dispatcher(model):
+    """No lost wake-up: with one decode worker every next request finds
+    the slot still taken by the batch that answered the last one, and
+    waits for its release, not for the ten-second deadline."""
+    with model.serving_engine(tiers=('topk',), max_delay_ms=10_000.0,
+                              decode_workers=1) as engine:
+        t0 = time.perf_counter()
+        for i in range(20):
+            engine.predict([PREDICT_LINES[i % 3]], tier='topk',
+                           timeout=60)
+        elapsed = time.perf_counter() - t0
+        stats = engine.stats()
+    assert stats['batches_total'] == stats['early_close_total'] == 20
+    assert elapsed < 10.0, '20 lone requests took %.1fs' % elapsed
+
+
+def test_a_failed_decode_frees_its_slot(model, monkeypatch):
+    with model.serving_engine(tiers=('topk',), max_delay_ms=10_000.0,
+                              decode_workers=1) as engine:
+        def broken(*args, **kwargs):
+            raise RuntimeError('decode broke')
+        monkeypatch.setattr(engine_lib, 'decode_results', broken)
+        with pytest.raises(RuntimeError, match='decode broke'):
+            engine.predict([PREDICT_LINES[0]], tier='topk', timeout=60)
+        monkeypatch.undo()
+        t0 = time.perf_counter()
+        (result,) = engine.predict([PREDICT_LINES[1]], tier='topk',
+                                   timeout=60)
+        elapsed = time.perf_counter() - t0
+        stats = engine.stats()
+    assert result.topk_predicted_words
+    assert stats['early_close_total'] == 2
+    assert elapsed < 5.0, 'the failed batch kept its slot: %.1fs' % elapsed
 
 
 def test_bucket_selection_smallest_cover(model):
@@ -169,13 +255,16 @@ def test_cancelled_request_does_not_poison_batchmates(model):
     running, so cancel() always succeeds) must not break delivery to
     the other requests coalesced into the same micro-batch."""
     with model.serving_engine(tiers=('topk',),
-                              max_delay_ms=300.0) as engine:
-        doomed = engine.submit([PREDICT_LINES[0]], tier='topk')
-        survivor = engine.submit([PREDICT_LINES[1]], tier='topk')
-        assert doomed.cancel()
-        results = survivor.result(timeout=60)
+                              max_delay_ms=60_000.0) as engine:
+        with decode_slots_held(engine, PREDICT_LINES[2]) as held:
+            doomed = engine.submit([PREDICT_LINES[0]], tier='topk')
+            survivor = engine.submit([PREDICT_LINES[1]], tier='topk')
+            assert doomed.cancel()
+            held.release()
+            results = survivor.result(timeout=60)
         stats = engine.stats()
-    assert stats['batches_total'] == 1  # same micro-batch
+    assert stats['batches_total'] == held.batches + 1  # same micro-batch
+    assert stats['last_dispatch']['requests'] == 2
     assert results[0].topk_predicted_words == \
         model.predict([PREDICT_LINES[1]])[0].topk_predicted_words
 

@@ -16,6 +16,7 @@ from code2vec_tpu.config import Config
 from code2vec_tpu.resilience import faults
 from code2vec_tpu.serving.errors import (DeadlineExceeded, EngineClosed,
                                          EngineOverloaded, ServingError)
+from tests.serving_slots import decode_slots_held
 from tests.test_train_overfit import make_dataset
 
 PREDICT_LINES = [
@@ -277,10 +278,23 @@ def test_default_close_fails_queued_futures_typed(model):
 
 def test_close_drain_serves_everything_admitted(model):
     engine = model.serving_engine(tiers=('topk',), max_delay_ms=10_000.0)
-    # parked in the coalescing window: nothing dispatched yet
-    futures = [engine.submit([line], tier='topk')
-               for line in PREDICT_LINES]
-    engine.close(drain=True)
+    with decode_slots_held(engine, PREDICT_LINES[0]) as held:
+        # every decode slot taken: parked in the coalescing window,
+        # nothing of these dispatched yet
+        futures = [engine.submit([line], tier='topk')
+                   for line in PREDICT_LINES]
+        time.sleep(0.05)
+        assert engine.stats()['batches_total'] == held.batches
+        closer = threading.Thread(target=engine.close,
+                                  kwargs={'drain': True})
+        closer.start()
+        # the drain ends the wait with the slots still held, ten
+        # seconds before the deadline
+        _wait_until(
+            lambda: engine.stats()['batches_total'] > held.batches,
+            what='the draining close to dispatch the parked requests')
+    closer.join(timeout=60)
+    assert not closer.is_alive()
     for future, line in zip(futures, PREDICT_LINES):
         (result,) = future.result(timeout=60)
         assert result.topk_predicted_words == \

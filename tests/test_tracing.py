@@ -550,7 +550,8 @@ PROFILER_SITES = {
     'serving/tokenize': {'rows', 'native'},
     'serving/no_work': set(),
     'serving/coalesce': set(),
-    'serving/pack': {'batch', 'rows', 'bucket', 'requests', 'tier'},
+    'serving/pack': {'batch', 'rows', 'bucket', 'requests', 'tier',
+                     'early'},
     'serving/h2d': {'batch'},
     'serving/dispatch': {'batch'},
     'serving/fetch': {'batch', 'rows', 'handoff_ms'},
@@ -602,8 +603,10 @@ def profiled(model, tmp_path_factory):
     out = tmp_path_factory.mktemp('profiled')
     tracer = Tracer(str(out / 'spans'), sample_rate=1.0)
     index = _small_index(model)
+    # one request at a time into an idle engine: a decode slot is always
+    # free, so every batch closes early and the delay is never waited
     engine = model.serving_engine(tiers=('topk', 'vectors'),
-                                  max_delay_ms=2.0, tracer=tracer)
+                                  max_delay_ms=10_000.0, tracer=tracer)
     engine.attach_index(index)
     try:
         engine.predict(PREDICT_LINES[:1], timeout=120)   # warm, untraced
@@ -659,6 +662,8 @@ def test_profiler_events_sit_on_the_thread_that_does_the_work(profiled):
     assert not lines['serving/tokenize'] & (dispatcher | workers)
     # the module's model reads with READER_USE_NATIVE off: the fallback
     assert {int(t[3]['native']) for t in events['serving/tokenize']} == {0}
+    # every batch found a free decode slot (the fixture's delay is 10 s)
+    assert {int(p[3]['early']) for p in events['serving/pack']} == {1}
     # a neighbour query's search runs inside its deliver (from lines), or
     # as a pool task of its own (from vectors): once each
     searches = events['serving/index_search']
