@@ -9,7 +9,7 @@ with nu stored bf16 (training/adam_dtypes.py), to decide whether
 ADAM_NU_DTYPE joins the defaults under the >=2% flip rule.
 
 Prints one JSON line per measurement (chained sync-at-end methodology,
-benchmarks/diag_step_breakdown.py / PERF.md).
+PERF.md).
 """
 from __future__ import annotations
 

@@ -5,8 +5,8 @@ The 2026-07-29 diag capture showed dropout's ~131M threefry draws cost
 same devargs/sync-at-end step with `DROPOUT_PRNG_IMPL='rbg'` against the
 default, to decide whether the knob should become the TPU default.
 
-Prints one JSON line per measurement (same chained methodology as
-benchmarks/diag_step_breakdown.py).
+Prints one JSON line per measurement (chained sync-at-end methodology,
+PERF.md).
 """
 from __future__ import annotations
 

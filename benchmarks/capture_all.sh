@@ -50,7 +50,6 @@ echo "capturing to ${OUT}" >&2
 # Priority order: the decisions blocked on each artifact, most important
 # first.
 run_stage bench 900 python bench.py
-run_stage diag 900 python benchmarks/diag_step_breakdown.py
 run_stage profile 600 python benchmarks/capture_profile.py
 run_stage pallas_ab 900 python benchmarks/bench_pallas_encode.py
 BENCH_CONTEXTS=1024 run_stage pallas_ab_c1024 900 \

@@ -3,7 +3,7 @@
 ``capture_all.sh`` appends stage-wrapped JSON lines ({"stage", "rc",
 "secs", "data": {...}}); the interactive harnesses emit raw measure
 lines. This collates
-both shapes so the A/B verdicts (rbg dropout, embed-grad, fused CE,
+both shapes so the A/B verdicts (rbg dropout, fused CE,
 bf16-mu, Pallas C=1024) can be read off — and defaults flipped on
 evidence — without re-parsing JSONL by hand.
 

@@ -209,6 +209,28 @@ def _moment_dtype_of(abstract_tree, field: str):
     return dtypes.pop() if len(dtypes) == 1 else None
 
 
+def _leaf_paths(tree) -> set:
+    """The key paths of a tree's array leaves, as tuples of strings.
+    Reads an abstract optimizer state and orbax's saved metadata of one
+    alike: there a NamedTuple is a dict by field and a tuple a list."""
+    tree = getattr(tree, 'tree', tree)
+    paths = set()
+
+    def walk(node, path):
+        if isinstance(node, dict):
+            for key, value in node.items():
+                walk(value, path + (str(key),))
+        elif isinstance(node, (list, tuple)):
+            fields = getattr(node, '_fields', range(len(node)))
+            for key, value in zip(fields, node):
+                walk(value, path + (str(key),))
+        elif node is not None:
+            paths.add(path)
+
+    walk(tree, ())
+    return paths
+
+
 def _with_moment_dtype(abstract_tree, dtype, field: str):
     """Abstract tree with the ``field`` moment's floating leaves set to
     ``dtype`` (the STORED moment dtype), keeping shape and sharding — the
@@ -598,6 +620,29 @@ class CheckpointStore:
                 'frameworks.' % (self.model_path, current_fw,
                                  stored_fw)) from exc
 
+    def _raise_if_foreign_optimizer(self, exc: Exception, read_metadata,
+                                    abstract_opt_state) -> None:
+        """Re-raise a failed training restore as a clear, store-wide
+        error when the artifact's optimizer state is not the tree this
+        configuration builds (another framework's, or an optimizer this
+        version no longer has): no older step can help, and orbax's own
+        message is a tree diff."""
+        try:
+            stored = _leaf_paths(read_metadata())
+        except Exception:
+            return  # unreadable metadata: the restore's own error stands
+        stored_opt = {path[1:] for path in stored
+                      if path[:1] == ('opt_state',)}
+        if stored_opt == _leaf_paths(abstract_opt_state):
+            return
+        self._raise_if_permanent(exc)
+        raise CheckpointLayoutError(
+            'Cannot resume TRAINING from `%s`: its optimizer state is '
+            'not the Adam state this version builds (written by an '
+            'optimizer this version does not have). Params-only loads '
+            '(evaluate / predict / --release) work.'
+            % self.model_path) from exc
+
     def restore_training(self, abstract_params, abstract_opt_state,
                          max_step: Optional[int] = None
                          ) -> Optional[RestoredTraining]:
@@ -713,8 +758,13 @@ class CheckpointStore:
         # failures propagate to restore_training's candidate loop, which
         # distinguishes store-wide config errors (_raise_if_permanent)
         # from per-artifact corruption (quarantine + fall back)
-        restored = manager.restore(
-            latest, args=ocp.args.StandardRestore(target))
+        try:
+            restored = manager.restore(
+                latest, args=ocp.args.StandardRestore(target))
+        except Exception as exc:
+            self._raise_if_foreign_optimizer(exc, read_metadata,
+                                             abstract_opt_state)
+            raise
         params, opt_state = restored['params'], restored['opt_state']
         if stored_rows is not None:
             current_rows = self.metadata.get(_TARGET_ROWS_KEY)

@@ -74,19 +74,6 @@ class Config:
     # Learning rate for Adam (reference uses tf.train.AdamOptimizer defaults,
     # tensorflow_model.py:232 -> lr=0.001).
     LEARNING_RATE: float = 0.001
-    # Update the token/path embedding tables with lazy (sparse-row) Adam
-    # (tf.contrib.opt.LazyAdamOptimizer semantics) instead of dense Adam:
-    # moments decay only for rows present in the batch, and the
-    # optimizer's HBM traffic scales with the batch (<=614K touched rows)
-    # instead of the 2.2M-row vocabulary. The DEFAULT dense Adam is the
-    # reference-parity behavior (TF1's AdamOptimizer decays moments
-    # densely even for IndexedSlices gradients); the lazy variant is a
-    # deliberate throughput/semantics trade-off for giant tables and stays
-    # off until the on-chip A/B records a win and a quality check passes
-    # (ops/lazy_adam.py, benchmarks/diag_step_breakdown.py). Dense
-    # parameters (TRANSFORM/ATTENTION/target table) keep optax Adam
-    # either way.
-    LAZY_EMBEDDING_ADAM: bool = False
     # Storage dtype for Adam's FIRST moment (optax mu_dtype). 'bfloat16'
     # halves the first-moment HBM traffic (~1.5 GB/step read+write at
     # java14m's 384M params) in the HBM-bound update (PERF.md roofline);
@@ -134,17 +121,6 @@ class Config:
     # learning-curve twin (profile cpu_full_bf16grads) clear the >=2%
     # flip rule, like every perf knob here (PERF.md).
     GRADS_DTYPE: str = 'float32'
-    # Backward-pass strategy for the token/path table gradients
-    # (ops/embed_grad.py): 'dense' leaves the B*C-row scatter-add to XLA;
-    # 'sorted' sorts the index stream so duplicate row hits are adjacent;
-    # 'dedup' additionally pre-combines duplicates with a segmented scan so
-    # each table row is written at most once. Numerically equivalent up to
-    # fp summation order. The on-chip A/B decided for 'dense' on both
-    # uniform and zipf index streams (48.69 vs 54.45 sorted / 65.42 dedup
-    # ms/step zipf; 2026-07-31, earlier installation, PERF.md): XLA's
-    # native scatter-add beats both pre-combine strategies, which break its
-    # fusion the same way lazy Adam does (PERF.md).
-    EMBED_GRAD_IMPL: str = 'dense'
     # Route the TRAINING cross-entropy through the flash-style fused Pallas
     # kernel (ops/pallas_ce.py): logsumexp + label pick computed blockwise
     # over the target table, so the (B, target_vocab) logits matrix never
@@ -179,7 +155,7 @@ class Config:
     # Parameters stay replicated along 'data' either way (this is
     # optimizer-STATE partitioning, not ZeRO-3). Numerics are unchanged
     # (tests/test_sharding.py); requires PARAM_ROW_ALIGNMENT divisible by
-    # the whole mesh size and the dense optax Adam (not LAZY_EMBEDDING_ADAM).
+    # the whole mesh size.
     OPTIMIZER_STATE_SHARDING: str = 'mirror'
     # Embedding tables are padded to a multiple of this many rows so they
     # shard evenly over any model axis that DIVIDES this value (validated at
@@ -764,11 +740,6 @@ class Config:
                                  'the table-grad scatters and grad tree '
                                  'in bf16 (fp32 master params + fp32 '
                                  'moment math, PERF.md)')
-        parser.add_argument('--embed-grad', dest='embed_grad_impl',
-                            choices=['dense', 'sorted', 'dedup'],
-                            default=None,
-                            help='token/path table gradient strategy '
-                                 '(ops/embed_grad.py, PERF.md)')
         parser.add_argument('--fused-ce', dest='fused_ce',
                             action='store_true',
                             help='train-time CE via the flash-style fused '
@@ -1084,8 +1055,6 @@ class Config:
             self.ADAM_NU_DTYPE = parsed.adam_nu_dtype
         if parsed.grads_dtype:
             self.GRADS_DTYPE = parsed.grads_dtype
-        if parsed.embed_grad_impl:
-            self.EMBED_GRAD_IMPL = parsed.embed_grad_impl
         if parsed.fused_ce:
             self.USE_PALLAS_FUSED_CE = True
         if parsed.ragged_fusion:
@@ -1357,9 +1326,6 @@ class Config:
         if self.DROPOUT_PRNG_IMPL not in {'threefry2x32', 'rbg'}:
             raise ValueError("config.DROPOUT_PRNG_IMPL must be in "
                              "{'threefry2x32', 'rbg'}.")
-        if self.EMBED_GRAD_IMPL not in {'dense', 'sorted', 'dedup'}:
-            raise ValueError("config.EMBED_GRAD_IMPL must be in "
-                             "{'dense', 'sorted', 'dedup'}.")
         if self.ADAM_MU_DTYPE not in {'float32', 'bfloat16'}:
             raise ValueError("config.ADAM_MU_DTYPE must be in "
                              "{'float32', 'bfloat16'}.")
@@ -1369,11 +1335,6 @@ class Config:
         if self.GRADS_DTYPE not in {'float32', 'bfloat16'}:
             raise ValueError("config.GRADS_DTYPE must be in "
                              "{'float32', 'bfloat16'}.")
-        if self.GRADS_DTYPE == 'bfloat16' and self.LAZY_EMBEDDING_ADAM:
-            raise ValueError(
-                'GRADS_DTYPE=\'bfloat16\' requires the dense optax path: '
-                'LAZY_EMBEDDING_ADAM\'s sparse-row update consumes raw '
-                'fp32 gradients.')
         if self.GRADS_DTYPE == 'bfloat16' \
                 and self.COMPUTE_DTYPE != 'bfloat16':
             # The knob works by differentiating wrt the PRE-CAST bf16
@@ -1385,11 +1346,6 @@ class Config:
                 "GRADS_DTYPE='bfloat16' requires "
                 "COMPUTE_DTYPE='bfloat16' (the bf16 pre-cast must round "
                 "exactly where the compute cast already would).")
-        # LAZY_EMBEDDING_ADAM keeps fp32 moments (the sparse-row update
-        # does not implement reduced-precision mu), so ADAM_MU_DTYPE is
-        # simply not consumed on that path. Now that 'bfloat16' is the
-        # DEFAULT, raising here would break lazy users who never touched
-        # the knob — the trainer logs the ignored-knob warning instead.
         if self.TELEMETRY_FLUSH_EVERY_STEPS < 1:
             raise ValueError(
                 'config.TELEMETRY_FLUSH_EVERY_STEPS must be >= 1.')
@@ -1422,12 +1378,6 @@ class Config:
         if self.OPTIMIZER_STATE_SHARDING not in {'mirror', 'zero'}:
             raise ValueError("config.OPTIMIZER_STATE_SHARDING must be in "
                              "{'mirror', 'zero'}.")
-        if self.LAZY_EMBEDDING_ADAM and \
-                self.OPTIMIZER_STATE_SHARDING != 'mirror':
-            raise ValueError(
-                "config.OPTIMIZER_STATE_SHARDING='zero' shards the dense "
-                'optax Adam moment tree; LAZY_EMBEDDING_ADAM keeps its own '
-                'state layout.')
         if self.MAX_DIVERGENCE_REWINDS < 0:
             raise ValueError('config.MAX_DIVERGENCE_REWINDS must be >= 0.')
         if self.HANG_WATCHDOG_SECS < 0:

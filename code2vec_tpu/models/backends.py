@@ -103,7 +103,6 @@ class JaxBackend:
             dropout_keep_rate=self.config.DROPOUT_KEEP_RATE,
             dropout_prng_impl=self.config.DROPOUT_PRNG_IMPL,
             dtype=self.dtype, num_valid_targets=self.num_valid_targets,
-            embed_grad_impl=self.config.EMBED_GRAD_IMPL,
             use_fused_ce=self.config.USE_PALLAS_FUSED_CE,
             fused_ce_mesh=mesh,
             remat_encode=self.config.REMAT_ENCODE)
@@ -140,7 +139,6 @@ class JaxBackend:
             dropout_keep_rate=self.config.DROPOUT_KEEP_RATE,
             dropout_prng_impl=self.config.DROPOUT_PRNG_IMPL,
             dtype=self.dtype, num_valid_targets=self.num_valid_targets,
-            embed_grad_impl=self.config.EMBED_GRAD_IMPL,
             use_fused_ce=self.config.USE_PALLAS_FUSED_CE,
             fused_ce_mesh=mesh,
             remat_encode=self.config.REMAT_ENCODE,
@@ -158,7 +156,6 @@ class JaxBackend:
             params, ctx, count, max_contexts=self.config.MAX_CONTEXTS,
             token_pad=self.token_pad_index,
             path_pad=self.path_pad_index, dtype=self.dtype,
-            embed_grad_impl=self.config.EMBED_GRAD_IMPL,
             use_kernel=use_kernel, mesh=mesh)
         logits = functional.compute_logits(
             params, code_vectors, dtype=self.dtype,

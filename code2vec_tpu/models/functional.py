@@ -114,8 +114,7 @@ def encode(params: Code2VecParams, source: jax.Array, path: jax.Array,
            dropout_keep_rate: float = 1.0,
            dropout_prng_impl: str = 'threefry2x32',
            dtype: jnp.dtype = jnp.float32,
-           use_pallas: bool = False,
-           embed_grad_impl: str = 'dense'
+           use_pallas: bool = False
            ) -> Tuple[jax.Array, jax.Array]:
     """Bag-of-contexts → (code_vectors (B, D) fp32, attention (B, C) fp32).
 
@@ -127,15 +126,12 @@ def encode(params: Code2VecParams, source: jax.Array, path: jax.Array,
     (ops/pallas_encode.py; a TPU kernel — off a TPU it raises
     ``KernelRequiresTPU``); the dropout path always uses plain jnp.
     """
-    # take_rows == jnp.take for the default 'dense'; other impls reshape
-    # the backward scatter-add (ops/embed_grad.py, Config.EMBED_GRAD_IMPL)
-    from code2vec_tpu.ops.embed_grad import take_rows
-    source_embed = take_rows(params.token_embedding, source,
-                             impl=embed_grad_impl).astype(dtype)  # (B, C, d)
-    path_embed = take_rows(params.path_embedding, path,
-                           impl=embed_grad_impl).astype(dtype)    # (B, C, d)
-    target_embed = take_rows(params.token_embedding, target,
-                             impl=embed_grad_impl).astype(dtype)  # (B, C, d)
+    source_embed = jnp.take(params.token_embedding, source,
+                            axis=0).astype(dtype)                 # (B, C, d)
+    path_embed = jnp.take(params.path_embedding, path,
+                          axis=0).astype(dtype)                   # (B, C, d)
+    target_embed = jnp.take(params.token_embedding, target,
+                            axis=0).astype(dtype)                 # (B, C, d)
 
     apply_dropout = dropout_rng is not None and dropout_keep_rate < 1.0
     if use_pallas and not apply_dropout:
@@ -194,7 +190,6 @@ def encode_packed(params: Code2VecParams, ctx: jax.Array, count: jax.Array,
                   dropout_keep_rate: float = 1.0,
                   dropout_prng_impl: str = 'threefry2x32',
                   dtype: jnp.dtype = jnp.float32,
-                  embed_grad_impl: str = 'dense',
                   use_kernel: bool = False,
                   interpret: bool = False,
                   mesh=None) -> Tuple[jax.Array, jax.Array]:
@@ -218,8 +213,7 @@ def encode_packed(params: Code2VecParams, ctx: jax.Array, count: jax.Array,
         token_pad=token_pad, path_pad=path_pad, dtype=dtype,
         dropout_rng=dropout_rng, dropout_keep_rate=dropout_keep_rate,
         dropout_prng_impl=dropout_prng_impl,
-        embed_grad_impl=embed_grad_impl, use_kernel=use_kernel,
-        interpret=interpret, mesh=mesh)
+        use_kernel=use_kernel, interpret=interpret, mesh=mesh)
 
 
 def compute_logits(params: Code2VecParams, code_vectors: jax.Array,
@@ -270,7 +264,6 @@ def loss_and_aux(params: Code2VecParams, source: jax.Array, path: jax.Array,
                  dropout_prng_impl: str = 'threefry2x32',
                  dtype: jnp.dtype = jnp.float32,
                  num_valid_targets: Optional[int] = None,
-                 embed_grad_impl: str = 'dense',
                  use_fused_ce: bool = False,
                  fused_ce_mesh=None,
                  remat_encode: bool = False):
@@ -295,8 +288,7 @@ def loss_and_aux(params: Code2VecParams, source: jax.Array, path: jax.Array,
         return encode(
             params_, source_, path_, target_, mask_, dropout_rng=rng_,
             dropout_keep_rate=dropout_keep_rate,
-            dropout_prng_impl=dropout_prng_impl, dtype=dtype,
-            embed_grad_impl=embed_grad_impl)[0]
+            dropout_prng_impl=dropout_prng_impl, dtype=dtype)[0]
 
     if remat_encode:
         _encode = jax.checkpoint(_encode)
@@ -340,7 +332,6 @@ def loss_and_aux_packed(params: Code2VecParams, ctx: jax.Array,
                         dropout_prng_impl: str = 'threefry2x32',
                         dtype: jnp.dtype = jnp.float32,
                         num_valid_targets: Optional[int] = None,
-                        embed_grad_impl: str = 'dense',
                         use_fused_ce: bool = False,
                         fused_ce_mesh=None,
                         remat_encode: bool = False,
@@ -355,9 +346,8 @@ def loss_and_aux_packed(params: Code2VecParams, ctx: jax.Array,
     custom VJP: the backward recomputes the per-slot state off the
     packed segments instead of storing the (D, cap, 3d) gathered
     embeddings / (D, cap, D) activations as residuals, and emits the
-    token/path table gradients as packed-stream scatter-adds
-    (EMBED_GRAD_IMPL / lazy-Adam compatible). ``use_ragged_kernel``
-    routes both passes through the Pallas pair
+    token/path table gradients as packed-stream scatter-adds.
+    ``use_ragged_kernel`` routes both passes through the Pallas pair
     (Config.RAGGED_TRAIN_KERNEL; TPU only); False = the jnp twin pair,
     the default on every platform.
     ``max_contexts`` only shapes the attention planes the loss never
@@ -374,7 +364,6 @@ def loss_and_aux_packed(params: Code2VecParams, ctx: jax.Array,
             token_pad=token_pad, path_pad=path_pad, dropout_rng=rng_,
             dropout_keep_rate=dropout_keep_rate,
             dropout_prng_impl=dropout_prng_impl, dtype=dtype,
-            embed_grad_impl=embed_grad_impl,
             use_kernel=use_ragged_kernel, mesh=ragged_mesh,
             custom_vjp=ragged_custom_vjp)
 

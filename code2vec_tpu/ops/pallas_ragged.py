@@ -49,9 +49,8 @@ embeddings, re-draws the SAME dropout mask from the threaded key, and
 recomputes ``x``/``scores``/``w`` per slot tile — the FuseMax
 recompute-over-store schedule — before emitting exact softmax-backward
 gradients: TRANSFORM/ATTENTION densely (per-tile MXU accumulation) and
-the token/path table gradients as segment scatter-adds through
-``ops/embed_grad.table_grad`` (so ``EMBED_GRAD_IMPL`` and the lazy-Adam
-sparse-row substrate compose). The ``(D, cap, 3d)`` gathered context
+the token/path table gradients as segment scatter-adds over the packed
+index stream. The ``(D, cap, 3d)`` gathered context
 embeddings and the ``(D, cap, D)`` activations exist only transiently
 inside each pass, never as residuals between them — the autodiff twin
 saved all of them (tests/test_pallas_ragged.py asserts the residual set
@@ -405,7 +404,6 @@ def ragged_encode(token_embedding: jax.Array, path_embedding: jax.Array,
                   dropout_rng: Optional[jax.Array] = None,
                   dropout_keep_rate: float = 1.0,
                   dropout_prng_impl: str = 'threefry2x32',
-                  embed_grad_impl: str = 'dense',
                   use_kernel: bool = False,
                   interpret: bool = False,
                   mesh=None) -> Tuple[jax.Array, jax.Array]:
@@ -439,13 +437,9 @@ def ragged_encode(token_embedding: jax.Array, path_embedding: jax.Array,
         interpret = resolve_interpret(interpret, 'ragged fused encode',
                                       mesh)
 
-    from code2vec_tpu.ops.embed_grad import take_rows
-    src_e = take_rows(token_embedding, src,
-                      impl=embed_grad_impl).astype(dtype)    # (D, cap, d)
-    pth_e = take_rows(path_embedding, pth,
-                      impl=embed_grad_impl).astype(dtype)
-    tgt_e = take_rows(token_embedding, tgt,
-                      impl=embed_grad_impl).astype(dtype)
+    src_e = jnp.take(token_embedding, src, axis=0).astype(dtype)  # (D, cap, d)
+    pth_e = jnp.take(path_embedding, pth, axis=0).astype(dtype)
+    tgt_e = jnp.take(token_embedding, tgt, axis=0).astype(dtype)
     token_dim = src_e.shape[-1]
     path_dim = pth_e.shape[-1]
 
@@ -727,7 +721,6 @@ def ragged_encode_code(token_embedding: jax.Array,
                        dropout_rng: Optional[jax.Array] = None,
                        dropout_keep_rate: float = 1.0,
                        dropout_prng_impl: str = 'threefry2x32',
-                       embed_grad_impl: str = 'dense',
                        use_kernel: bool = False,
                        interpret: bool = False,
                        mesh=None, custom_vjp: bool = True) -> jax.Array:
@@ -735,8 +728,7 @@ def ragged_encode_code(token_embedding: jax.Array,
     ``(B, D) fp32`` under a ``jax.custom_vjp`` whose backward RECOMPUTES
     the per-slot state instead of storing it (module docstring). Only
     the four encoder params are differentiable; ``ctx``/``count``/the
-    PRNG key get ``None`` cotangents (the embed_grad.take_rows
-    precedent).
+    PRNG key get ``None`` cotangents.
 
     ``use_kernel`` routes BOTH passes: False runs the jnp twin pair,
     True the Pallas pair (``Config.RAGGED_TRAIN_KERNEL``; off a TPU that
@@ -760,8 +752,7 @@ def ragged_encode_code(token_embedding: jax.Array,
             count, max_contexts=1, token_pad=token_pad, path_pad=path_pad,
             dtype=dtype, dropout_rng=dropout_rng,
             dropout_keep_rate=dropout_keep_rate,
-            dropout_prng_impl=dropout_prng_impl,
-            embed_grad_impl=embed_grad_impl, use_kernel=False,
+            dropout_prng_impl=dropout_prng_impl, use_kernel=False,
             mesh=mesh)[0]
 
     precision = _precision(dtype)
@@ -773,8 +764,6 @@ def ragged_encode_code(token_embedding: jax.Array,
         per_shard = count2.shape[1]
         token_dim = tok_t.shape[1]
         path_dim = path_t.shape[1]
-        # plain takes: the custom VJP below owns the whole backward, so
-        # take_rows' selectable-gradient wrapper would be dead weight
         src_e = jnp.take(tok_t, src, axis=0).astype(dtype)
         pth_e = jnp.take(path_t, pth, axis=0).astype(dtype)
         tgt_e = jnp.take(tok_t, tgt, axis=0).astype(dtype)
@@ -860,21 +849,20 @@ def ragged_encode_code(token_embedding: jax.Array,
                             precision=precision)             # (3d,)
         d_trans = (jnp.concatenate([dw_src, dw_pth, dw_tgt], axis=0)
                    + dw_pad).astype(trans.dtype)
-        # table grads as segment scatter-adds over the packed index
-        # stream — THE reshaped-scatter substrate (ops/embed_grad.py),
-        # so EMBED_GRAD_IMPL composes exactly as on the dense path
-        from code2vec_tpu.ops.embed_grad import table_grad
+        # table grads as scatter-adds over the packed index stream;
+        # duplicate indices accumulate
         tok_idx = jnp.concatenate([src.reshape(-1), tgt.reshape(-1)])
         tok_cot = jnp.concatenate([de_src.reshape(-1, token_dim),
                                    de_tgt.reshape(-1, token_dim)])
-        d_tok = table_grad(tok_cot, tok_idx, tok_t.shape[0], tok_t.dtype,
-                           embed_grad_impl)
+        d_tok = jnp.zeros((tok_t.shape[0], token_dim), tok_t.dtype).at[
+            tok_idx].add(tok_cot.astype(tok_t.dtype))
         d_tok = d_tok.at[token_pad].add(
             (de_pad[:token_dim]
              + de_pad[token_dim + path_dim:]).astype(tok_t.dtype))
-        d_path = table_grad(de_pth.reshape(-1, path_dim), pth.reshape(-1),
-                            path_t.shape[0], path_t.dtype,
-                            embed_grad_impl)
+        pth_cot = de_pth.reshape(-1, path_dim)
+        pth_idx = pth.reshape(-1)
+        d_path = jnp.zeros((path_t.shape[0], path_dim), path_t.dtype).at[
+            pth_idx].add(pth_cot.astype(path_t.dtype))
         d_path = d_path.at[path_pad].add(
             de_pad[token_dim:token_dim + path_dim].astype(path_t.dtype))
         return d_tok, d_path, d_trans, d_attn.astype(attn.dtype)

@@ -56,8 +56,7 @@ def grouped_top_k(x: jax.Array, k: int, group_size: int = 2048
     Motivation: one monolithic top-k over a (B, 261K) logits matrix makes
     the selection network as wide as the vocab; two narrow stages map
     better onto the VPU. Whether that wins on a given chip is measured,
-    not assumed (benchmarks/diag_step_breakdown.py stages a lax-vs-grouped
-    A/B); callers opt in explicitly.
+    not assumed; callers opt in explicitly.
 
     MEASURED VERDICT (2026-07-29, v5e-class chip, PERF.md): 119.3 ms vs
     lax.top_k's 24.8 ms at (1024, 261K), k=10 — XLA's monolithic top-k

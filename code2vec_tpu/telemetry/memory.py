@@ -143,13 +143,6 @@ ALLOC_CATALOG = (
                'into the staging bucket (telemetry on) and release at '
                'pop'},
     {'file': 'code2vec_tpu/training/trainer.py',
-     'func': 'Trainer._build_steps.<locals>.packed_rows', 'count': 2,
-     'reason': 'traced INSIDE the jitted packed train step (the PAD-row '
-               'append that completes lazy Adam\'s touched-row set off '
-               'the packed ctx stream, ISSUE 12) — two 4-byte '
-               'compile-time constants in the XLA program, never a '
-               'host-initiated device allocation; nothing to ledger'},
-    {'file': 'code2vec_tpu/training/trainer.py',
      'func': 'Trainer.eval_step', 'count': 1,
      'reason': 'one-shot eval batch placement, consumed within the '
                'call (the eval loop goes through stage_batches)'},

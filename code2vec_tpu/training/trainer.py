@@ -99,53 +99,28 @@ class Trainer:
         # variant's V % model_axis == 0 requirement.
         # Reference uses tf.train.AdamOptimizer() defaults
         # (tensorflow_model.py:232): lr=1e-3, b1=0.9, b2=0.999, eps=1e-8.
-        # LAZY_EMBEDDING_ADAM swaps in LazyAdam-style sparse-row updates
-        # for the token/path tables (a throughput trade-off, NOT the
-        # reference's semantics — see ops/lazy_adam.py); dense params keep
-        # optax Adam either way.
-        if config.LAZY_EMBEDDING_ADAM:
-            if (config.ADAM_MU_DTYPE != 'float32'
-                    or config.ADAM_NU_DTYPE != 'float32'):
-                # bf16 mu is the config DEFAULT; lazy Adam's sparse-row
-                # update keeps fp32 moments and does not consume either
-                # dtype knob, so this must warn, not raise.
-                logger.warning(
-                    'ADAM_MU_DTYPE=%r / ADAM_NU_DTYPE=%r are ignored: '
-                    'they apply to the dense optax Adam only; '
-                    'LAZY_EMBEDDING_ADAM keeps fp32 moments.',
-                    config.ADAM_MU_DTYPE, config.ADAM_NU_DTYPE)
-            logger.warning(
-                'LAZY_EMBEDDING_ADAM is measured SLOWER on v5e-class chips '
-                '(0.54x the dense step at java14m shapes, PERF.md): the '
-                'scatter update serializes against the fused dense update. '
-                'It remains available for semantics experiments only.')
-            from code2vec_tpu.ops.lazy_adam import LazyEmbeddingAdam
-            self.optimizer = LazyEmbeddingAdam(config.LEARNING_RATE, backend)
+        # ADAM_MU_DTYPE / ADAM_NU_DTYPE = 'bfloat16' store the moments in
+        # bf16 — HBM-traffic knobs for the HBM-bound dense update (config
+        # comments + PERF.md); None keeps optax's param-dtype default.
+        mu_dtype = (jnp.bfloat16
+                    if config.ADAM_MU_DTYPE == 'bfloat16' else None)
+        if (config.ADAM_NU_DTYPE == 'bfloat16'
+                or config.GRADS_DTYPE == 'bfloat16'):
+            # optax.adam has no nu_dtype; the local transform keeps
+            # optax's ScaleByAdamState field names so checkpoints stay
+            # field-compatible (training/adam_dtypes.py). It is also
+            # mandatory under bf16 grads: its moment math is EXPLICIT
+            # fp32, where optax's dtype-promotion rules would let a bf16
+            # grad meet a bf16-stored mu and accumulate the EMA in bf16.
+            from code2vec_tpu.training import adam_dtypes
+            nu_dtype = (jnp.bfloat16
+                        if config.ADAM_NU_DTYPE == 'bfloat16' else None)
+            self.optimizer = adam_dtypes.adam(
+                config.LEARNING_RATE, mu_dtype=mu_dtype,
+                nu_dtype=nu_dtype)
         else:
-            # ADAM_MU_DTYPE / ADAM_NU_DTYPE = 'bfloat16' store the
-            # moments in bf16 — HBM-traffic knobs for the HBM-bound dense
-            # update (config comments + PERF.md); None keeps optax's
-            # param-dtype default.
-            mu_dtype = (jnp.bfloat16
-                        if config.ADAM_MU_DTYPE == 'bfloat16' else None)
-            if (config.ADAM_NU_DTYPE == 'bfloat16'
-                    or config.GRADS_DTYPE == 'bfloat16'):
-                # optax.adam has no nu_dtype; the local transform keeps
-                # optax's ScaleByAdamState field names so checkpoints
-                # stay field-compatible (training/adam_dtypes.py). It is
-                # also mandatory under bf16 grads: its moment math is
-                # EXPLICIT fp32, where optax's dtype-promotion rules
-                # would let a bf16 grad meet a bf16-stored mu and
-                # accumulate the EMA in bf16.
-                from code2vec_tpu.training import adam_dtypes
-                nu_dtype = (jnp.bfloat16
-                            if config.ADAM_NU_DTYPE == 'bfloat16' else None)
-                self.optimizer = adam_dtypes.adam(
-                    config.LEARNING_RATE, mu_dtype=mu_dtype,
-                    nu_dtype=nu_dtype)
-            else:
-                self.optimizer = optax.adam(config.LEARNING_RATE,
-                                            mu_dtype=mu_dtype)
+            self.optimizer = optax.adam(config.LEARNING_RATE,
+                                        mu_dtype=mu_dtype)
         # Telemetry (OBSERVABILITY.md): None when disabled — every
         # instrumented site below is then a single `is None` check.
         self._telemetry = None
@@ -179,7 +154,6 @@ class Trainer:
         optimizer = self.optimizer
         top_k = self.config.TOP_K_WORDS_CONSIDERED_DURING_PREDICTION
 
-        lazy = self.config.LAZY_EMBEDDING_ADAM
         # the mesh only matters to the loss when the fused CE must be
         # shard_mapped; None keeps single-device tracing mesh-free
         loss_mesh = self.mesh if self.mesh.size > 1 else None
@@ -205,11 +179,7 @@ class Trainer:
         # fused gather + encode + single-pass attention softmax, no
         # device-side unpack, no (B, C, .) planes — and the TRAIN step's
         # custom-VJP backward recomputes off the same segments instead
-        # of storing per-slot residuals. Lazy Adam now runs fused too:
-        # its sparse-row update reads the touched rows straight off the
-        # packed index stream (rows_of below), which covers exactly the
-        # rows the plane wire would touch — every slot up to each
-        # example's effective length plus the PAD row.
+        # of storing per-slot residuals.
         ragged = (self.config.USE_PALLAS_RAGGED_FUSION
                   and hasattr(backend, 'forward_packed'))
         ragged_train = ragged
@@ -226,10 +196,7 @@ class Trainer:
                 'Pallas kernel' if ragged_kernel else 'jnp twin',
                 platform, self.mesh.size)
 
-        def plane_rows(arrays):
-            return arrays[0], arrays[1], arrays[2]
-
-        def make_train_step(loss_call, rows_of=plane_rows):
+        def make_train_step(loss_call):
             def train_step(state: TrainerState, arrays
                            ) -> Tuple[TrainerState, jax.Array]:
                 dropout_rng = jax.random.fold_in(state.rng, state.step)
@@ -241,15 +208,9 @@ class Trainer:
                 diff_params = (cast_for_grads(state.params) if grads_bf16
                                else state.params)
                 loss, grads = jax.value_and_grad(loss_fn)(diff_params)
-                if lazy:
-                    source, path, target = rows_of(arrays)
-                    new_params, new_opt_state = optimizer.update_sparse(
-                        state.params, grads, state.opt_state, state.step,
-                        source, path, target)
-                else:
-                    updates, new_opt_state = optimizer.update(
-                        grads, state.opt_state, state.params)
-                    new_params = optax.apply_updates(state.params, updates)
+                updates, new_opt_state = optimizer.update(
+                    grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
                 new_state = TrainerState(params=new_params,
                                          opt_state=new_opt_state,
                                          step=state.step + 1, rng=state.rng)
@@ -369,29 +330,11 @@ class Trainer:
                 ctx, count, max_contexts, token_pad, path_pad)
             return (source, path, target, mask, label, weight)
 
-        def packed_rows(arrays):
-            # lazy Adam's touched-row sets off the packed wire: the ctx
-            # stream holds every slot up to each example's effective
-            # length (capacity padding carries the PAD triple). The PAD
-            # rows are appended explicitly so the x_pad-path gradient of
-            # count==0 rows is covered even when a batch packs with zero
-            # capacity padding — O(1), and duplicates are idempotent
-            # (ops/lazy_adam.py module doc).
-            ctx = arrays[0]
-            source = jnp.concatenate([
-                ctx[..., 0].reshape(-1),
-                jnp.full((1,), token_pad, jnp.int32)])
-            path = jnp.concatenate([
-                ctx[..., 1].reshape(-1),
-                jnp.full((1,), path_pad, jnp.int32)])
-            return source, path, ctx[..., 2].reshape(-1)
-
         if ragged_train:
             train_step_packed = make_train_step(
                 lambda params, arrays, rng:
                 backend.loss_fn_packed(params, arrays, rng,
-                                       mesh=loss_mesh),
-                rows_of=packed_rows)
+                                       mesh=loss_mesh))
         else:
             def train_step_packed(state, packed_arrays):
                 return train_step(state, unpack(packed_arrays))

@@ -390,11 +390,10 @@ def test_bench_index_quant_arms_smoke():
 
 def test_workloads_files_stay_within_tier1_budget():
     """ISSUE 20 satellite: the scenario-traffic-plane test files ride
-    tier-1 with TINY in-code profiles — the full replay drills are
-    slow-marked.  The suite sits close to the tier-1 wall-clock cap,
-    so the headroom contract is enforced here: both files, cold
-    interpreter, well under the budget.  A full-corpus replay sneaking
-    into the tier-1 lane fails THIS assert before it blows the cap."""
+    tier-1 with TINY in-code profiles and one full replay drill
+    (~1 s).  The headroom contract is enforced here: both files, cold
+    interpreter, well under the budget.  A replay that drifts into
+    minutes fails THIS assert before it eats tier-1's time."""
     import time
     env = dict(os.environ, JAX_PLATFORMS='cpu', PYTHONPATH=REPO)
     t0 = time.monotonic()

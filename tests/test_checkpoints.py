@@ -29,15 +29,9 @@ def test_save_creates_sidecar_and_checkpoints(tmp_path):
     assert (model_dir / 'saved_model__entire-model').is_dir()
 
 
-# tier-1 budget (conftest report): the same-backend pairs carry the
-# round-trip property; the cross-backend pairs re-run the full train
-# for ~6s each and ride in the slow tier
 @pytest.mark.parametrize('train_framework,load_framework',
                          [('jax', 'jax'), ('flax', 'flax'),
-                          pytest.param('jax', 'flax',
-                                       marks=pytest.mark.slow),
-                          pytest.param('flax', 'jax',
-                                       marks=pytest.mark.slow)])
+                          ('jax', 'flax'), ('flax', 'jax')])
 def test_load_params_reproduces_predictions(tmp_path, train_framework,
                                             load_framework):
     """Checkpoints use a canonical params layout: a model trained under
@@ -100,6 +94,41 @@ def test_cross_framework_training_resume_raises_clearly(tmp_path):
         Code2VecModel(config2)
 
 
+def test_resume_from_foreign_optimizer_tree_raises_clearly(tmp_path):
+    """A checkpoint whose optimizer state is not dense Adam's tree (as a
+    lazy-Adam checkpoint of an older version is: token/path moments in
+    dicts beside a small optax state) must end a TRAINING resume in the
+    store's clear error, not in orbax's tree diff; its parameters still
+    load."""
+    import collections
+    import jax.numpy as jnp
+    prefix = make_dataset(tmp_path)
+    model = Code2VecModel(_train_config(tmp_path, prefix))
+    named = model.backend.named_params(model.state.params)._asdict()
+    tables = {name: jnp.zeros_like(named[name])
+              for name in ('token_embedding', 'path_embedding')}
+    foreign = collections.namedtuple('Foreign', 'dense mu nu')(
+        dense=(jnp.zeros((), jnp.int32),), mu=tables, nu=dict(tables))
+    model.save(state=model.state._replace(opt_state=foreign))
+    model.close_stores()
+
+    load_path = str(tmp_path / 'models' / 'saved_model')
+    with pytest.raises(ValueError, match='optimizer state') as raised:
+        Code2VecModel(_train_config(tmp_path, prefix,
+                                    MODEL_LOAD_PATH=load_path))
+    assert 'Params-only loads' in str(raised.value)
+    # a store-wide cause, not corruption: nothing was renamed aside
+    assert (tmp_path / 'models' / 'saved_model__entire-model' / '0').is_dir()
+
+    loaded = Code2VecModel(_train_config(
+        tmp_path, prefix, TRAIN_DATA_PATH_PREFIX=None,
+        MODEL_SAVE_PATH=None, MODEL_LOAD_PATH=load_path))
+    got = loaded.backend.named_params(loaded.params)._asdict()
+    for name, want in named.items():
+        np.testing.assert_array_equal(np.asarray(got[name]),
+                                      np.asarray(want), err_msg=name)
+
+
 def test_resume_training_continues_from_epoch(tmp_path):
     prefix = make_dataset(tmp_path)
     config = _train_config(tmp_path, prefix, NUM_TRAIN_EPOCHS=2)
@@ -118,8 +147,7 @@ def test_resume_training_continues_from_epoch(tmp_path):
 
 @pytest.mark.parametrize('saved_mu,resume_mu',
                          [('float32', 'bfloat16'),
-                          pytest.param('bfloat16', 'float32',
-                                       marks=pytest.mark.slow)])
+                          ('bfloat16', 'float32')])
 def test_resume_across_adam_mu_dtype(tmp_path, saved_mu, resume_mu):
     """ADAM_MU_DTYPE's default flipped fp32 -> bf16 (2026-07-31 A/B):
     resuming an older checkpoint under the new default (and vice versa)
@@ -146,8 +174,7 @@ def test_resume_across_adam_mu_dtype(tmp_path, saved_mu, resume_mu):
 
 @pytest.mark.parametrize('saved_nu,resume_nu',
                          [('float32', 'bfloat16'),
-                          pytest.param('bfloat16', 'float32',
-                                       marks=pytest.mark.slow)])
+                          ('bfloat16', 'float32')])
 def test_resume_across_adam_nu_dtype(tmp_path, saved_nu, resume_nu):
     """ADAM_NU_DTYPE is gated on the same flip rule as mu was: cross-dtype
     resume must adapt in both directions — restore the second moment as
@@ -172,7 +199,6 @@ def test_resume_across_adam_nu_dtype(tmp_path, saved_nu, resume_nu):
     model2.train()  # epoch 1 runs under the configured nu dtype
 
 
-@pytest.mark.slow  # two full trains (~10s); tier-1 budget headroom
 def test_resume_across_opt_state_sharding_modes(tmp_path):
     """A checkpoint written with the mirrored moment layout resumes under
     OPTIMIZER_STATE_SHARDING='zero' (and the moments land zero-sharded):
@@ -199,7 +225,6 @@ def test_resume_across_opt_state_sharding_modes(tmp_path):
     model2.train()  # epoch 1 runs under the zero layout without error
 
 
-@pytest.mark.slow  # three full trains (~11s); tier-1 budget headroom
 @pytest.mark.usefixtures('pallas_interpret')
 def test_resume_across_fused_ce_and_mesh_reshape(tmp_path):
     """ADVICE r3: the fused-CE target-table allocation folds in the vocab
@@ -261,7 +286,6 @@ def test_resume_across_fused_ce_and_mesh_reshape(tmp_path):
         after_train.topk_predicted_words[:m]
 
 
-@pytest.mark.slow  # train + release + resume (~10s); budget headroom
 @pytest.mark.usefixtures('pallas_interpret')
 def test_release_rows_rewrite_does_not_poison_older_checkpoints(tmp_path):
     """ADVICE r4: one meta.json serves the whole history, and its

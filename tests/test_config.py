@@ -60,7 +60,7 @@ def test_cli_parsing(tmp_path):
         '--data', 'd/prefix', '--test', 'd/prefix.val.c2v',
         '--save', str(tmp_path / 'model'), '--framework', 'jax',
         '--mesh', '4x2', '--dtype', 'float32', '--batch-size', '256',
-        '--embed-grad', 'dedup', '--fused-ce', '--ragged-fusion'])
+        '--fused-ce', '--ragged-fusion'])
     assert config.TRAIN_DATA_PATH_PREFIX == 'd/prefix'
     assert config.TEST_DATA_PATH == 'd/prefix.val.c2v'
     assert config.DL_FRAMEWORK == 'jax'
@@ -68,7 +68,6 @@ def test_cli_parsing(tmp_path):
     assert config.MESH_MODEL_AXIS_SIZE == 2
     assert config.COMPUTE_DTYPE == 'float32'
     assert config.TRAIN_BATCH_SIZE == 256
-    assert config.EMBED_GRAD_IMPL == 'dedup'
     assert config.USE_PALLAS_FUSED_CE is True
     assert config.USE_PALLAS_RAGGED_FUSION is True
     config.verify()
@@ -82,7 +81,6 @@ def test_cli_parsing(tmp_path):
     assert plain.USE_PALLAS_FUSED_CE is False
     assert plain.USE_PALLAS_RAGGED_FUSION is True
     assert plain.RAGGED_TRAIN_KERNEL is False
-    assert plain.EMBED_GRAD_IMPL == 'dense'
 
     unfused = Config().load_from_args(['--data', 'd/prefix',
                                        '--no-ragged-fusion'])

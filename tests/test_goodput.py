@@ -531,7 +531,6 @@ def _read_tags(path):
     return by_tag
 
 
-@pytest.mark.slow
 def test_goodput_acceptance_rewind_run_reconstructs(tmp_path):
     """ISSUE 17 acceptance: a CPU fit with eval + checkpoints + one
     injected divergence rewind -> the report reconstructs the run

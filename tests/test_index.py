@@ -218,9 +218,8 @@ def test_float16_store_recall_parity(tmp_path):
     assert overlap >= 0.97, overlap
 
 
-@pytest.mark.slow
 def test_ivf_recall_at_default_nprobe_50k(tmp_path):
-    """ISSUE 5 acceptance (slow tier): recall@10 >= 0.95 at the default
+    """ISSUE 5 acceptance: recall@10 >= 0.95 at the default
     nprobe on a >= 50k-vector corpus."""
     vecs = clustered_corpus(50000, 64, centers=500, seed=11)
     store = store_lib.build(str(tmp_path / 'big.vecindex'), [vecs])

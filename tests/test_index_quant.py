@@ -285,10 +285,9 @@ def test_insert_landing_mid_compaction_is_not_lost(tmp_path):
 
 
 # ------------------------------------------------------ 50k acceptance
-@pytest.mark.slow
 @pytest.mark.parametrize('kind', ['int8', 'pq'])
 def test_quant_recall_at_default_nprobe_50k(tmp_path, kind):
-    """ISSUE 19 acceptance (slow tier): recall@10 >= 0.95 vs exact at
+    """ISSUE 19 acceptance: recall@10 >= 0.95 vs exact at
     the default nprobe with the default re-rank on the 50k clustered
     corpus, at <= 1/2 (int8) / <= 1/4 (pq) the device bytes/vector of
     f16."""

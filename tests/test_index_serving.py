@@ -48,8 +48,6 @@ def write_corpus_from_lines(tmp_path, lines):
     return prefix
 
 
-@pytest.mark.slow  # heaviest index-family test (~4s): the fast path
-# is covered by test_build_query_and_jsonl_batch_mode below
 @pytest.mark.skipif(not os.path.isfile(EXTRACTOR),
                     reason='extractor binary not built')
 def test_extract_to_neighbors_round_trip(tmp_path):

@@ -133,12 +133,12 @@ def test_full_train_step_with_fused_ce():
     """A jitted Trainer step with USE_PALLAS_FUSED_CE produces the same
     losses as the default path (interpreter mode on CPU) — the kernel
     composes with donation, optimizer update, and the trainer jit."""
-    from tests.test_embed_grad import _single_device_trainer
-    from tests.test_sharding import _run_steps
+    from tests.test_sharding import _run_steps, _trainer
 
-    _, dense = _run_steps(_single_device_trainer(), n=2)
+    one_device = dict(MESH_DEVICE_INDICES='0')
+    _, dense = _run_steps(_trainer(1, 1, **one_device), n=2)
     _, fused = _run_steps(
-        _single_device_trainer(USE_PALLAS_FUSED_CE=True), n=2)
+        _trainer(1, 1, USE_PALLAS_FUSED_CE=True, **one_device), n=2)
     np.testing.assert_allclose(fused, dense, rtol=1e-5)
 
 

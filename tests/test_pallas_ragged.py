@@ -10,8 +10,8 @@ acceptance: five-param gradient parity across the regime, dropout-mask
 bit-match between the fused pair and the twin, bf16 smoke, and the
 no-per-slot-residuals contract (vjp-closure assertion). Trainer
 integration covers packed train/eval and all four predict tiers, the
-zero-post-warmup-compiles guards on predict AND the fused train step,
-and lazy Adam training fused off the packed-stream rows."""
+and the zero-post-warmup-compiles guards on predict AND the fused
+train step."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,11 +65,7 @@ class TestTwinVsDense:
     encode, over the full structural property space."""
 
     @pytest.mark.parametrize('token_pad,path_pad', [(0, 0), (1, 2)])
-    # tier-1 budget: 1 (unsharded) and 4 (the real mesh width) bound
-    # the property space; the intermediate width rides the slow tier
-    @pytest.mark.parametrize(
-        'data_shards',
-        [1, pytest.param(2, marks=pytest.mark.slow), 4])
+    @pytest.mark.parametrize('data_shards', [1, 2, 4])
     def test_property_regime(self, token_pad, path_pad, data_shards):
         rng = np.random.default_rng(7)
         params = small_params()
@@ -408,7 +404,45 @@ class TestFusedBackward:
             ragged_mesh=mesh))(params)
         assert_grads_close(grads_k, grads_d, params._fields)
 
-    @pytest.mark.slow  # three consumers x jit (~11s); budget headroom
+    @pytest.mark.parametrize('hits', ['all_same', 'all_distinct',
+                                      'half_one_row'])
+    def test_table_grad_extremes(self, hits):
+        """The duplicate-index extremes of the table-gradient
+        scatter-adds: every slot of the batch on one row (one giant
+        run), no two slots on a row, and half of them on one row. The
+        token- and path-table gradients of the custom VJP must equal
+        autodiff of the plane path."""
+        from code2vec_tpu.data.reader import Batch
+        rows, contexts = 4, 3
+        slots = rows * contexts
+        distinct = np.arange(1, 1 + slots, dtype=np.int32)
+        if hits == 'all_same':
+            source, path, target = (np.full(slots, row, np.int32)
+                                    for row in (7, 5, 7))
+        else:
+            source, path, target = distinct, distinct, distinct + slots
+            if hits == 'half_one_row':
+                source, path, target = (
+                    np.where(np.arange(slots) % 2 == 0, row, a)
+                    for row, a in ((31, source), (15, path), (31, target)))
+        batch = Batch(
+            source=source.reshape(rows, contexts),
+            path=path.reshape(rows, contexts),
+            target=target.reshape(rows, contexts),
+            mask=np.ones((rows, contexts), np.float32),
+            label=np.arange(1, 1 + rows, dtype=np.int32),
+            weight=np.ones((rows,), np.float32))
+        params = small_params(path_dim=8)
+        packed = packed_lib.pack_batch(batch, 0, 0, capacity_minimum=4)
+        grads_d = jax.grad(_dense_loss(params, batch))(params)
+        grads_r = jax.grad(_packed_losses(params, packed, contexts))(params)
+        for name in ('token_embedding', 'path_embedding'):
+            got = np.asarray(getattr(grads_r, name))
+            want = np.asarray(getattr(grads_d, name))
+            assert np.abs(want).sum() > 0
+            np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-6,
+                                       err_msg=name)
+
     def test_dropout_bit_match_fused_vs_twin(self):
         """One threaded key, three consumers — the autodiff twin, the
         custom-VJP twin pair, the custom-VJP kernel pair — must all
@@ -677,37 +711,3 @@ class TestTrainerIntegration:
                                       fused.mesh, False)
         info = fused.train_program_memory(state, placed)
         assert info is not None and 'temp_bytes' in info
-
-    def test_lazy_adam_trains_fused_with_parity(self):
-        """The lifted `ragged and lazy` exclusion (ISSUE 12): lazy Adam
-        now trains FUSED — the custom-VJP backward's table grads are
-        dense scatter-adds over the packed stream, and the sparse-row
-        update reads its touched rows straight off the packed ctx
-        indices. Touched-row sets are provably identical to the unpack
-        path's (every slot up to each example's effective length + the
-        PAD row), so params must match the unpack-then-dense lazy step
-        to fp32 rounding — including rows a batch did NOT touch staying
-        bit-identical (the lazy semantics)."""
-        fused = make_trainer(DROPOUT_KEEP_RATE=1.0,
-                             LAZY_EMBEDDING_ADAM=True)
-        plain = make_trainer(DROPOUT_KEEP_RATE=1.0,
-                             LAZY_EMBEDDING_ADAM=True,
-                             USE_PALLAS_RAGGED_FUSION=False)
-        packed = self._packed(fused, n=2)
-        state_f = fused.init_state(seed=0)
-        state_p = plain.init_state(seed=0)
-        for pb in packed:
-            state_f, loss_f = fused.train_step(state_f, pb)
-            state_p, loss_p = plain.train_step(state_p, pb)
-            np.testing.assert_allclose(float(loss_f), float(loss_p),
-                                       rtol=1e-5)
-        for name, leaf_f, leaf_p in zip(
-                state_f.params._fields,
-                jax.tree_util.tree_leaves(state_f.params),
-                jax.tree_util.tree_leaves(state_p.params)):
-            np.testing.assert_allclose(np.asarray(leaf_f),
-                                       np.asarray(leaf_p),
-                                       rtol=2e-4, atol=1e-6,
-                                       err_msg=name)
-        out = fused.predict_step(state_f.params, packed[0], tier='topk')
-        assert np.asarray(out['topk_indices']).shape[0] == 8

@@ -356,12 +356,14 @@ def test_mesh_oversize_split_rejoin_memo_bit_identity(model):
         assert handle.done()
         _assert_rows_identical(handle.result(), live)
         # independent live compute (model.predict serves the full tier:
-        # compare the fields the topk tier produces)
+        # compare the fields the topk tier produces). One 20-row program
+        # against the mesh's 16+4 split: the softmax scores agree to
+        # float32 rounding, not bitwise
         for cached, ref in zip(handle.result(), model.predict(lines)):
             assert cached.topk_predicted_words == ref.topk_predicted_words
-            np.testing.assert_array_equal(
+            np.testing.assert_allclose(
                 cached.topk_predicted_words_scores,
-                ref.topk_predicted_words_scores)
+                ref.topk_predicted_words_scores, rtol=1e-6)
     finally:
         mesh.close()
 

@@ -3,9 +3,9 @@ recorded-then-replayed round trip through a live ServingMesh, replay
 determinism (bit-identical admitted set AND bit-identical results), a
 mixed Java+C# stream with ZERO post-warmup compiles, retrieval-blend
 weight=0 bit-parity against the plain softmax path, and the typed
-no-index fallback.  Tier-1 drills use tiny in-code profiles; the full
-synthetic-corpus replay is slow-marked (tests/test_bench_smoke.py
-budgets this file's tier-1 wall time)."""
+no-index fallback.  Most drills use tiny in-code profiles; the last
+replays the full synthetic corpus (tests/test_bench_smoke.py budgets
+this file's tier-1 wall time)."""
 import os
 import sys
 
@@ -279,8 +279,7 @@ def test_mixed_stream_zero_postwarm_compiles(mesh):
         'delivered'] == 1
 
 
-# ------------------------------------------------ full drill (slow-mark)
-@pytest.mark.slow
+# ------------------------------------------------------------ full drill
 @pytest.mark.skipif(
     not os.path.exists(os.path.join(REPO, 'extractor', 'build',
                                     'c2v-extract')),
