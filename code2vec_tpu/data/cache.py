@@ -152,9 +152,13 @@ class TokenCache:
 
     def _packer_for(self, data_shards: int) -> packed_lib.StickyPacker:
         if self._packer is None or self._packer.data_shards != data_shards:
+            # the cache holds TRAINING data: its packed batches name the
+            # rows they touch when they feed a data-parallel mesh
             self._packer = packed_lib.StickyPacker(
                 self.vocabs.token_vocab.pad_index,
-                self.vocabs.path_vocab.pad_index, data_shards=data_shards)
+                self.vocabs.path_vocab.pad_index, data_shards=data_shards,
+                table_rows=packed_lib.embedding_table_rows(
+                    self.vocabs, self.config.PARAM_ROW_ALIGNMENT))
         return self._packer
 
     # ------------------------------------------------------------ building
@@ -295,9 +299,8 @@ class TokenCache:
         if weight is None:
             weight = np.ones((count.shape[0],), np.float32)
         if wire_format == 'packed':
-            ctx = self._packer_for(data_shards).pack_ragged(ctx_rows, count)
-            return packed_lib.PackedBatch(ctx=ctx, count=count, label=label,
-                                          weight=weight)
+            return self._packer_for(data_shards).pack_ragged(
+                ctx_rows, count, label, weight)
         source, path, target = packed_lib.unpack_ragged_np(
             ctx_rows, count, self.meta['max_contexts'], token_pad, path_pad)
         mask = context_valid_mask(source, path, target, token_pad, path_pad)

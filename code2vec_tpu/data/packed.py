@@ -17,6 +17,27 @@ contiguous stream of ``(source, path, target)`` int32 triples:
   label   (B,) int32
   weight  (B,) float32
 
+A TRAINING stream packed for a data-parallel mesh (``data_shards > 1``)
+also names, per shard, the embedding rows the shard's slots touch, so the
+step can reduce the two tables' gradients over those rows and not over
+the tables (ops/pallas_ragged.py ``_rows_table_grad``):
+
+  tok_rows  (data_shards, U_tok)  int32 — the shard's distinct token rows
+            (source and target slots together), ascending, its PAD row
+            among them
+  path_rows (data_shards, U_path) int32 — the same for the path rows
+  inv       (data_shards, capacity, 3) int32 — for every slot of ``ctx``
+            the position of its row in ``tok_rows[s]`` (columns 0, 2) or
+            ``path_rows[s]`` (column 1): ``rows[s][inv[s]] == ctx[s]`` on
+            every slot, the tail padding included
+
+Past a shard's distinct rows each row array goes on with distinct
+ascending ids BEYOND the table's last row (``rows_in_table + k``), so the
+whole array is sorted and unique and a ``mode='drop'`` scatter discards
+the padding. ``U_tok``/``U_path`` are sticky and bucketed like
+``capacity``. Every other stream (eval, predict, serving, one data shard)
+ships the four arrays alone.
+
 12 bytes per RETAINED slot + 12 bytes per example. Keeping everything up
 to the last valid slot (not only the mask-valid slots) is what makes the
 round trip BIT-exact: an interior all-PAD hole (e.g. a ``,,`` context in
@@ -42,12 +63,18 @@ so the data layer stays importable without it.
 """
 from __future__ import annotations
 
+import logging
 import time as _time
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
+logger = logging.getLogger(__name__)
+
 WIRE_FORMATS = ('planes', 'packed')
+# how many arrays a packed batch ships: the wire alone, or with a training
+# batch's touched rows (the plane wire ships six)
+PACKED_ARITIES = (4, 7)
 
 # Floor for the bucketed capacity. Small enough that tiny (test/smoke)
 # batches still see a byte win; large batches are governed by the
@@ -64,6 +91,10 @@ class PackedBatch(NamedTuple):
     weight: np.ndarray               # (B,) float32 — example validity
     label_strings: Optional[np.ndarray] = None     # (B,) object
     context_lines: Optional[np.ndarray] = None     # (B,) object
+    # per-shard touched rows, training streams on data_shards > 1 only
+    tok_rows: Optional[np.ndarray] = None          # (D, U_tok) int32
+    path_rows: Optional[np.ndarray] = None         # (D, U_path) int32
+    inv: Optional[np.ndarray] = None               # (D, cap, 3) int32
 
     @property
     def num_valid_examples(self) -> int:
@@ -71,8 +102,12 @@ class PackedBatch(NamedTuple):
 
     def device_arrays(self):
         """The arrays the jitted packed step functions consume, in a
-        fixed order (the host-only strings never ship)."""
-        return (self.ctx, self.count, self.label, self.weight)
+        fixed order (the host-only strings never ship): the four wire
+        arrays, then the touched-row arrays where the batch has them."""
+        arrays = (self.ctx, self.count, self.label, self.weight)
+        if self.inv is not None:
+            arrays += (self.tok_rows, self.path_rows, self.inv)
+        return arrays
 
 
 def wire_bytes(batch) -> int:
@@ -116,6 +151,66 @@ def capacity_ladder(max_total: int, minimum: int = MIN_CAPACITY,
         cap *= growth
     rungs.append(max(max_total, minimum))
     return tuple(rungs)
+
+
+def table_rows(vocab_size: int, alignment: int) -> int:
+    """Rows of an embedding table holding ``vocab_size`` words: rounded up
+    to the row alignment (Config.PARAM_ROW_ALIGNMENT) — the one definition
+    the backends allocate by and the packer pads the touched-row arrays
+    past."""
+    alignment = max(int(alignment), 1)
+    return -(-int(vocab_size) // alignment) * alignment
+
+
+def embedding_table_rows(vocabs, alignment: int) -> Tuple[int, int]:
+    """(token table rows, path table rows) for ``vocabs``."""
+    return (table_rows(vocabs.token_vocab.size, alignment),
+            table_rows(vocabs.path_vocab.size, alignment))
+
+
+def row_capacity(distinct: int, current: int,
+                 minimum: int = MIN_CAPACITY) -> int:
+    """Capacity of a touched-row array that has to hold ``distinct`` rows:
+    ``current`` if that does, else an eighth of head-room on top, rounded
+    up to a bucket of a sixteenth to an eighth of the result. The
+    head-room is what keeps a stream whose batches name about as many
+    rows each (a corpus at one batch size: a standard deviation under 2%)
+    on the capacity its first batch gave it."""
+    distinct = int(distinct)
+    if distinct <= current:
+        return current
+    want = distinct + distinct // 8
+    bucket = max(minimum, 1 << max(want.bit_length() - 4, 0))
+    return -(-want // bucket) * bucket
+
+
+def distinct_rows(ids: np.ndarray, pad_row: int, lut: np.ndarray
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """The distinct values of ``ids`` and ``pad_row``, ascending, and each
+    id's position among them. ``lut`` is scratch, one int32 per table row:
+    a sort of the ids (numpy's int32 sort is the cheap part) and two passes
+    through the table-sized lookup take a little over half of
+    ``np.unique``'s time with ``return_inverse``."""
+    ordered = np.sort(np.append(ids, np.int32(pad_row)))
+    first = np.empty(ordered.shape, bool)
+    first[0] = True
+    np.not_equal(ordered[1:], ordered[:-1], out=first[1:])
+    rows = ordered[first]
+    lut[rows] = np.arange(rows.shape[0], dtype=np.int32)
+    return rows, lut[ids]
+
+
+def pad_rows(rows, capacity: int, rows_in_table: int) -> np.ndarray:
+    """Per-shard ascending row sets -> the rectangular (D, capacity) int32
+    array, each shard continued with ``rows_in_table + k``: still
+    ascending and unique, and out of the table's bounds."""
+    out = np.empty((len(rows), capacity), np.int32)
+    for shard, own in enumerate(rows):
+        n = own.shape[0]
+        out[shard, :n] = own
+        out[shard, n:] = rows_in_table + np.arange(capacity - n,
+                                                   dtype=np.int32)
+    return out
 
 
 def shard_totals(count: np.ndarray, data_shards: int) -> np.ndarray:
@@ -200,26 +295,86 @@ class StickyPacker:
     program instead of ping-ponging specializations. One instance per
     data source (reader / cache), living across epochs.
 
+    ``table_rows`` = (token table rows, path table rows) marks a TRAINING
+    stream: with ``data_shards > 1`` every batch then also carries its
+    shards' touched rows (module docstring), under sticky capacities of
+    their own.
+
     Instrumented (telemetry enabled only — one bool read otherwise):
     pack time (``step/pack_ms``, recorded from whichever reader/prefetch
-    thread packs) and the packed fill rate (retained slots / wire
+    thread packs), the packed fill rate (retained slots / wire
     capacity — the padding waste the capacity buckets trade for fewer
-    jit specializations)."""
+    jit specializations) and, where rows ship, ``input/unique_row_share``
+    (distinct rows / retained index slots) and
+    ``input/row_capacity_fill`` (distinct rows / row capacity)."""
 
     def __init__(self, token_pad: int, path_pad: int, data_shards: int = 1,
-                 minimum: int = MIN_CAPACITY):
+                 minimum: int = MIN_CAPACITY,
+                 table_rows: Optional[Tuple[int, int]] = None):
         self.token_pad = token_pad
         self.path_pad = path_pad
         self.data_shards = data_shards
-        self.capacity = minimum
+        self.capacity = self.minimum = minimum
+        self.table_rows = table_rows if data_shards > 1 else None
+        self.tok_capacity = self.path_capacity = minimum
+        if self.table_rows is not None:
+            self._tok_lut = np.empty((table_rows[0],), np.int32)
+            self._path_lut = np.empty((table_rows[1],), np.int32)
 
-    @staticmethod
-    def _record(seconds: float, ctx: np.ndarray, retained: int) -> None:
+    def _touched_rows(self, ctx: np.ndarray):
+        """(tok_rows, path_rows, inv, distinct) of one packed ``ctx``."""
+        shards, cap, _ = ctx.shape
+        inv = np.empty_like(ctx)
+        tok, pth = [], []
+        for shard in range(shards):
+            rows, pos = distinct_rows(
+                np.concatenate([ctx[shard, :, 0], ctx[shard, :, 2]]),
+                self.token_pad, self._tok_lut)
+            inv[shard, :, 0] = pos[:cap]
+            inv[shard, :, 2] = pos[cap:]
+            tok.append(rows)
+            rows, pos = distinct_rows(np.ascontiguousarray(ctx[shard, :, 1]),
+                                      self.path_pad, self._path_lut)
+            inv[shard, :, 1] = pos
+            pth.append(rows)
+        grown = (row_capacity(max(r.shape[0] for r in tok),
+                              self.tok_capacity, self.minimum),
+                 row_capacity(max(r.shape[0] for r in pth),
+                              self.path_capacity, self.minimum))
+        if grown != (self.tok_capacity, self.path_capacity):
+            logger.info('packed touched-row capacities: token %d -> %d, '
+                        'path %d -> %d (one more train-step program)',
+                        self.tok_capacity, grown[0], self.path_capacity,
+                        grown[1])
+            self.tok_capacity, self.path_capacity = grown
+        distinct = sum(r.shape[0] for r in tok) + sum(r.shape[0] for r in pth)
+        return (pad_rows(tok, self.tok_capacity, self.table_rows[0]),
+                pad_rows(pth, self.path_capacity, self.table_rows[1]),
+                inv, distinct)
+
+    def _finish(self, packed: PackedBatch, t0: float) -> PackedBatch:
+        """Attach the touched rows where this stream ships them, and
+        record the batch's instruments."""
         from code2vec_tpu.telemetry import core
-        reg = core.registry()
-        reg.timer('step/pack_ms').record(seconds)
-        slots = int(ctx.shape[0]) * int(ctx.shape[1])
-        reg.gauge('input/packed_fill_rate').set(retained / max(slots, 1))
+        distinct = None
+        if self.table_rows is not None:
+            tok_rows, path_rows, inv, distinct = self._touched_rows(
+                packed.ctx)
+            packed = packed._replace(tok_rows=tok_rows, path_rows=path_rows,
+                                     inv=inv)
+        if core.enabled():
+            reg = core.registry()
+            reg.timer('step/pack_ms').record(_time.perf_counter() - t0)
+            retained = int(packed.count.sum())
+            slots = int(packed.ctx.shape[0]) * int(packed.ctx.shape[1])
+            reg.gauge('input/packed_fill_rate').set(retained / max(slots, 1))
+            if distinct is not None:
+                reg.gauge('input/unique_row_share').set(
+                    distinct / max(3 * retained, 1))
+                reg.gauge('input/row_capacity_fill').set(
+                    distinct / (self.data_shards * (self.tok_capacity
+                                                    + self.path_capacity)))
+        return packed
 
     def pack_batch(self, batch) -> PackedBatch:
         from code2vec_tpu.telemetry import core
@@ -228,22 +383,17 @@ class StickyPacker:
                             data_shards=self.data_shards,
                             capacity_minimum=self.capacity)
         self.capacity = max(self.capacity, packed.ctx.shape[1])
-        if core.enabled():
-            self._record(_time.perf_counter() - t0, packed.ctx,
-                         int(packed.count.sum()))
-        return packed
+        return self._finish(packed, t0)
 
-    def pack_ragged(self, ctx_rows: np.ndarray,
-                    count: np.ndarray) -> np.ndarray:
+    def pack_ragged(self, ctx_rows: np.ndarray, count: np.ndarray,
+                    label: np.ndarray, weight: np.ndarray) -> PackedBatch:
         from code2vec_tpu.telemetry import core
         t0 = _time.perf_counter() if core.enabled() else 0.0
         ctx = pack_ragged(ctx_rows, count, self.token_pad, self.path_pad,
                           self.data_shards, capacity_minimum=self.capacity)
         self.capacity = max(self.capacity, ctx.shape[1])
-        if core.enabled():
-            self._record(_time.perf_counter() - t0, ctx,
-                         int(count.sum()))
-        return ctx
+        return self._finish(PackedBatch(ctx=ctx, count=count, label=label,
+                                        weight=weight), t0)
 
 
 def unpack_ragged_np(ctx_rows: np.ndarray, count: np.ndarray,

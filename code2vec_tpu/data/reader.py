@@ -544,10 +544,15 @@ class PathContextReader:
         if wire_format == 'packed':
             from code2vec_tpu.data import packed as packed_lib
             if self._packer is None:
+                # a training stream names the rows it touches where it
+                # feeds a data-parallel mesh (data/packed.py)
                 self._packer = packed_lib.StickyPacker(
                     self.vocabs.token_vocab.pad_index,
                     self.vocabs.path_vocab.pad_index,
-                    data_shards=self.data_shards)
+                    data_shards=self.data_shards,
+                    table_rows=(packed_lib.embedding_table_rows(
+                        self.vocabs, self.config.PARAM_ROW_ALIGNMENT)
+                        if self.estimator_action.is_train else None))
             batches = (self._packer.pack_batch(batch) for batch in batches)
         yield from _counted_batches(batches)
 

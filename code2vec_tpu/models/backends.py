@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from code2vec_tpu.config import Config
+from code2vec_tpu.data import packed as packed_lib
 from code2vec_tpu.models import functional
 from code2vec_tpu.models.flax_model import Code2VecModule
 from code2vec_tpu.vocab import Code2VecVocabs
@@ -29,10 +30,6 @@ from code2vec_tpu.vocab import Code2VecVocabs
 
 def compute_dtype(config: Config) -> jnp.dtype:
     return jnp.bfloat16 if config.COMPUTE_DTYPE == 'bfloat16' else jnp.float32
-
-
-def _round_up(n: int, multiple: int) -> int:
-    return -(-n // multiple) * multiple
 
 
 def target_row_alignment(config: Config) -> int:
@@ -78,11 +75,13 @@ class JaxBackend:
         # joined PAD==OOV policy puts both at 0 there.
         self.token_pad_index = getattr(vocabs.token_vocab, 'pad_index', 0)
         self.path_pad_index = getattr(vocabs.path_vocab, 'pad_index', 0)
+        # the packer pads its touched-row arrays past these two counts
+        token_rows, path_rows = packed_lib.embedding_table_rows(vocabs,
+                                                                align)
         self.sizes = dict(
-            token_vocab_size=_round_up(vocabs.token_vocab.size, align),
-            path_vocab_size=_round_up(vocabs.path_vocab.size, align),
-            target_vocab_size=_round_up(vocabs.target_vocab.size,
-                                        target_align),
+            token_vocab_size=token_rows, path_vocab_size=path_rows,
+            target_vocab_size=packed_lib.table_rows(
+                vocabs.target_vocab.size, target_align),
             token_dim=config.TOKEN_EMBEDDINGS_SIZE,
             path_dim=config.PATH_EMBEDDINGS_SIZE,
             code_dim=config.CODE_VECTOR_SIZE)
@@ -128,8 +127,9 @@ class JaxBackend:
         both train passes through the Pallas kernel pair (TPU only: off
         a TPU the forced kernel raises ``KernelRequiresTPU``); off, the
         jnp twin pair runs — the default pending the >=2% flip verdict
-        (scripts/flip_verdict.py)."""
-        ctx, count, label, weight = packed_arrays
+        (scripts/flip_verdict.py). A training batch's touched-row
+        arrays, where it has them, follow the four wire arrays."""
+        ctx, count, label, weight = packed_arrays[:4]
         return functional.loss_and_aux_packed(
             params, ctx, count, label, weight,
             max_contexts=self.config.MAX_CONTEXTS,
@@ -143,7 +143,7 @@ class JaxBackend:
             fused_ce_mesh=mesh,
             remat_encode=self.config.REMAT_ENCODE,
             use_ragged_kernel=self.config.RAGGED_TRAIN_KERNEL,
-            ragged_mesh=mesh)
+            ragged_mesh=mesh, rows=tuple(packed_arrays[4:]))
 
     def forward_packed(self, params, packed_arrays, mesh=None,
                        use_kernel: bool = False):

@@ -337,7 +337,8 @@ def loss_and_aux_packed(params: Code2VecParams, ctx: jax.Array,
                         remat_encode: bool = False,
                         use_ragged_kernel: bool = False,
                         ragged_mesh=None,
-                        ragged_custom_vjp: bool = True):
+                        ragged_custom_vjp: bool = True,
+                        rows: tuple = ()):
     """``loss_and_aux`` straight off the packed wire: the ragged fused
     encoder replaces unpack + dense encode (USE_PALLAS_RAGGED_FUSION;
     ops/pallas_ragged.py), the CE tail is shared with the plane path.
@@ -353,7 +354,10 @@ def loss_and_aux_packed(params: Code2VecParams, ctx: jax.Array,
     ``max_contexts`` only shapes the attention planes the loss never
     reads; it stays in the signature so the packed twins share one call
     shape. ``ragged_custom_vjp=False`` keeps the autodiff twin — the
-    residual-storing reference the tests compare against."""
+    residual-storing reference the tests compare against. ``rows`` are
+    the batch's touched-row arrays where it carries them
+    (data/packed.py): the backward reduces the table gradients over
+    them."""
     del max_contexts  # loss consumes code vectors only
     from code2vec_tpu.ops import pallas_ragged
 
@@ -365,7 +369,7 @@ def loss_and_aux_packed(params: Code2VecParams, ctx: jax.Array,
             dropout_keep_rate=dropout_keep_rate,
             dropout_prng_impl=dropout_prng_impl, dtype=dtype,
             use_kernel=use_ragged_kernel, mesh=ragged_mesh,
-            custom_vjp=ragged_custom_vjp)
+            custom_vjp=ragged_custom_vjp, rows=rows)
 
     if remat_encode:
         _encode = jax.checkpoint(_encode)

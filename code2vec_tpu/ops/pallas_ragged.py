@@ -95,6 +95,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from jax.experimental import pallas as pl
@@ -712,6 +713,41 @@ def _grads_jnp(src_e, pth_e, tgt_e, seg, slot_valid, w_src, w_path, w_tgt,
     return de_src, de_pth, de_tgt, dw_src, dw_pth, dw_tgt, d_attn
 
 
+def _rows_table_grad(table_rows: int, rows, inv, cot, mesh):
+    """One embedding table's gradient, reduced over the rows the step
+    touched and not over the table (data/packed.py has the wire):
+    ``rows`` (D, U) int32 each shard's distinct rows, ascending, padded
+    past the table's end; ``inv`` (D, S) each slot's position in its
+    shard's rows; ``cot`` (D, S, d) the slots' cotangents.
+
+    Each shard sums its slots into a (U, d) buffer of its own: the same
+    row-updates as the dense scatter-add, into a destination that stays
+    on its chip. Buffers and rows are then made whole everywhere (the
+    one collective: an all-gather of D x U rows, where the dense form
+    all-reduces the table), and every device adds the D buffers into the
+    dense gradient in turn. A buffer's rows are unique and sorted, which
+    the scatter is told; rows that shards share are summed by the
+    sequence; the padding is out of bounds and dropped. Same sums as the
+    dense form, reassociated, in ``cot``'s dtype throughout."""
+    shards, capacity = rows.shape
+    dim = cot.shape[-1]
+    compact = jax.vmap(
+        lambda i, c: jnp.zeros((capacity, dim), cot.dtype).at[i].add(c))(
+            inv, cot)                                       # (D, U, d)
+    if mesh is not None:
+        compact = jax.lax.with_sharding_constraint(
+            compact, NamedSharding(mesh, P(DATA_AXIS)))
+        whole = NamedSharding(mesh, P())
+        compact = jax.lax.with_sharding_constraint(compact, whole)
+        rows = jax.lax.with_sharding_constraint(rows, whole)
+    grad = jnp.zeros((table_rows, dim), cot.dtype)
+    for shard in range(shards):
+        grad = grad.at[rows[shard]].add(
+            compact[shard], unique_indices=True, indices_are_sorted=True,
+            mode='drop')
+    return grad
+
+
 # ------------------------------------------------- custom-VJP train path
 def ragged_encode_code(token_embedding: jax.Array,
                        path_embedding: jax.Array, transform: jax.Array,
@@ -723,12 +759,18 @@ def ragged_encode_code(token_embedding: jax.Array,
                        dropout_prng_impl: str = 'threefry2x32',
                        use_kernel: bool = False,
                        interpret: bool = False,
-                       mesh=None, custom_vjp: bool = True) -> jax.Array:
+                       mesh=None, custom_vjp: bool = True,
+                       rows: tuple = ()) -> jax.Array:
     """The TRAIN-path encode: packed wire arrays -> code vectors
     ``(B, D) fp32`` under a ``jax.custom_vjp`` whose backward RECOMPUTES
     the per-slot state instead of storing it (module docstring). Only
     the four encoder params are differentiable; ``ctx``/``count``/the
-    PRNG key get ``None`` cotangents.
+    PRNG key/``rows`` get ``None`` cotangents.
+
+    ``rows`` = ``(tok_rows, path_rows, inv)`` where the batch names the
+    rows it touches (a training stream on a data-parallel mesh,
+    data/packed.py): the backward then reduces the two table gradients
+    over those rows (``_rows_table_grad``); the forward never reads them.
 
     ``use_kernel`` routes BOTH passes: False runs the jnp twin pair,
     True the Pallas pair (``Config.RAGGED_TRAIN_KERNEL``; off a TPU that
@@ -790,7 +832,7 @@ def ragged_encode_code(token_embedding: jax.Array,
         return code.reshape(count_.shape[0], -1), m, z
 
     def _bwd_compute(tok_t, path_t, trans, attn, ctx_, count_, rng_,
-                     m, z, code, g):
+                     rows_, m, z, code, g):
         count2, seg, _pos, slot_valid, src, pth, tgt = _segment_inputs(
             ctx_, count_, token_pad, path_pad)
         shards, cap = seg.shape
@@ -849,45 +891,64 @@ def ragged_encode_code(token_embedding: jax.Array,
                             precision=precision)             # (3d,)
         d_trans = (jnp.concatenate([dw_src, dw_pth, dw_tgt], axis=0)
                    + dw_pad).astype(trans.dtype)
-        # table grads as scatter-adds over the packed index stream;
-        # duplicate indices accumulate
-        tok_idx = jnp.concatenate([src.reshape(-1), tgt.reshape(-1)])
-        tok_cot = jnp.concatenate([de_src.reshape(-1, token_dim),
-                                   de_tgt.reshape(-1, token_dim)])
-        d_tok = jnp.zeros((tok_t.shape[0], token_dim), tok_t.dtype).at[
-            tok_idx].add(tok_cot.astype(tok_t.dtype))
+        # table grads as scatter-adds over the packed index stream
+        # (duplicate indices accumulate), or, where the batch names its
+        # rows, reduced over those and not over the tables. Token table,
+        # its PAD term, then the path table: the order the dense form's
+        # operands have always been built in (its lowered text is pinned)
+        if rows_:
+            tok_rows, path_rows, inv = rows_
+            d_tok = _rows_table_grad(
+                tok_t.shape[0], tok_rows,
+                jnp.concatenate([inv[..., 0], inv[..., 2]], axis=1),
+                jnp.concatenate([de_src, de_tgt],
+                                axis=1).astype(tok_t.dtype), mesh)
+        else:
+            tok_idx = jnp.concatenate([src.reshape(-1), tgt.reshape(-1)])
+            tok_cot = jnp.concatenate([de_src.reshape(-1, token_dim),
+                                       de_tgt.reshape(-1, token_dim)])
+            d_tok = jnp.zeros((tok_t.shape[0], token_dim),
+                              tok_t.dtype).at[tok_idx].add(
+                                  tok_cot.astype(tok_t.dtype))
         d_tok = d_tok.at[token_pad].add(
             (de_pad[:token_dim]
              + de_pad[token_dim + path_dim:]).astype(tok_t.dtype))
-        pth_cot = de_pth.reshape(-1, path_dim)
-        pth_idx = pth.reshape(-1)
-        d_path = jnp.zeros((path_t.shape[0], path_dim), path_t.dtype).at[
-            pth_idx].add(pth_cot.astype(path_t.dtype))
+        if rows_:
+            d_path = _rows_table_grad(
+                path_t.shape[0], path_rows, inv[..., 1],
+                de_pth.astype(path_t.dtype), mesh)
+        else:
+            pth_cot = de_pth.reshape(-1, path_dim)
+            pth_idx = pth.reshape(-1)
+            d_path = jnp.zeros((path_t.shape[0], path_dim),
+                               path_t.dtype).at[pth_idx].add(
+                                   pth_cot.astype(path_t.dtype))
         d_path = d_path.at[path_pad].add(
             de_pad[token_dim:token_dim + path_dim].astype(path_t.dtype))
         return d_tok, d_path, d_trans, d_attn.astype(attn.dtype)
 
     @jax.custom_vjp
-    def encode_code(tok_t, path_t, trans, attn, ctx_, count_, rng_):
+    def encode_code(tok_t, path_t, trans, attn, ctx_, count_, rng_, rows_):
         return _fwd_compute(tok_t, path_t, trans, attn, ctx_, count_,
                             rng_)[0]
 
-    def fwd(tok_t, path_t, trans, attn, ctx_, count_, rng_):
+    def fwd(tok_t, path_t, trans, attn, ctx_, count_, rng_, rows_):
         code, m, z = _fwd_compute(tok_t, path_t, trans, attn, ctx_,
                                   count_, rng_)
         # residuals: the inputs (live anyway) + per-example (m, z) +
         # the (B, D) code — NO per-slot tensor
         return code, (tok_t, path_t, trans, attn, ctx_, count_, rng_,
-                      m, z, code)
+                      rows_, m, z, code)
 
     def bwd(res, g):
-        tok_t, path_t, trans, attn, ctx_, count_, rng_, m, z, code = res
+        (tok_t, path_t, trans, attn, ctx_, count_, rng_, rows_, m, z,
+         code) = res
         grads = _bwd_compute(tok_t, path_t, trans, attn, ctx_, count_,
-                             rng_, m, z, code, g)
-        return grads + (None, None, None)
+                             rng_, rows_, m, z, code, g)
+        return grads + (None, None, None, None)
 
     encode_code.defvjp(fwd, bwd)
     rng_arg = (dropout_rng if apply_dropout
                else jnp.zeros((0,), jnp.uint32))
     return encode_code(token_embedding, path_embedding, transform,
-                       attention, ctx, count, rng_arg)
+                       attention, ctx, count, rng_arg, tuple(rows))

@@ -108,6 +108,15 @@ CATALOG: Dict[str, Dict[str, str]] = {
     'input/packed_fill_rate': _m(GAUGE, 'fraction', 'Retained context slots '
                                  '/ packed wire capacity of the last packed '
                                  'batch (padding waste = 1 - this).'),
+    'input/unique_row_share': _m(GAUGE, 'fraction', 'Distinct embedding '
+                                 'rows the last batch\'s shards named / its '
+                                 'retained index slots, tokens and paths '
+                                 'together (training on data_shards > 1: '
+                                 'the rows the gradient reduction carries).'),
+    'input/row_capacity_fill': _m(GAUGE, 'fraction', 'Distinct embedding '
+                                  'rows the last batch\'s shards named / '
+                                  'the touched-row capacity they ship '
+                                  'under.'),
     # ---- serving engine (code2vec_tpu/serving/, SERVING.md) ----
     'serving/requests_total': _m(COUNTER, 'requests', 'Prediction requests '
                                  'submitted to the serving engine.'),

@@ -65,13 +65,15 @@ class CapacityTracker:
         self._log = log
         self._seen = set()
 
-    def observe(self, capacity: int, step: int) -> None:
+    def observe(self, capacity: int, step: int, rows: tuple = ()) -> None:
+        """``rows``: the batch's touched-row capacities (token, path)
+        where it ships them — a new one re-specializes the step too."""
         reg = core.registry()
         reg.gauge('jit/packed_capacity').set(capacity)
-        if capacity in self._seen:
+        if (capacity,) + rows in self._seen:
             return
         first = not self._seen
-        self._seen.add(capacity)
+        self._seen.add((capacity,) + rows)
         if not first:
             # the first capacity is the program's initial specialization,
             # already billed by the compile listener — only GROWTH beyond
@@ -79,7 +81,9 @@ class CapacityTracker:
             reg.counter('jit/respecializations_total').inc()
         if self._log is not None:
             self._log('telemetry: packed-capacity %s at step %d '
-                      '(bucket %d; %d seen) — new step-program '
+                      '(bucket %d%s; %d seen) — new step-program '
                       'specialization'
                       % ('re-specialization' if not first else
-                         'specialization', step, capacity, len(self._seen)))
+                         'specialization', step, capacity,
+                         '; touched rows %d token, %d path' % rows
+                         if rows else '', len(self._seen)))

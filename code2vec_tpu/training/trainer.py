@@ -61,6 +61,12 @@ class TrainerState(NamedTuple):
     rng: jax.Array      # dropout PRNG root
 
 
+def _packed(arrays) -> bool:
+    """Whether a batch's arrays (host or placed) are the packed wire: 4
+    of them, 7 with a training batch's touched rows; planes are 6."""
+    return len(arrays) in packed_lib.PACKED_ARITIES
+
+
 class Trainer:
     def __init__(self, config: Config, backend,
                  mesh: Optional[jax.sharding.Mesh] = None):
@@ -325,7 +331,7 @@ class Trainer:
         max_contexts = self.config.MAX_CONTEXTS
 
         def unpack(packed_arrays):
-            ctx, count, label, weight = packed_arrays
+            ctx, count, label, weight = packed_arrays[:4]
             source, path, target, mask = packed_lib.unpack_device(
                 ctx, count, max_contexts, token_pad, path_pad)
             return (source, path, target, mask, label, weight)
@@ -468,7 +474,7 @@ class Trainer:
     def train_step(self, state: TrainerState, batch: Batch
                    ) -> Tuple[TrainerState, jax.Array]:
         host_arrays = batch.device_arrays()
-        if len(host_arrays) == 4:
+        if _packed(host_arrays):
             self._check_packed(host_arrays)  # clear error BEFORE placement
         arrays = mesh_lib.shard_batch(host_arrays, self.mesh,
                                       self.config.SHARD_CONTEXTS)
@@ -557,15 +563,15 @@ class Trainer:
                           ) -> Tuple[TrainerState, jax.Array]:
         """train_step over arrays already placed by ``stage_batches`` —
         either wire format, dispatched on the tuple's arity (packed = 4
-        arrays, planes = 6)."""
-        if len(arrays) == 4:
+        arrays, 7 with a training batch's touched rows; planes = 6)."""
+        if _packed(arrays):
             self._check_packed(arrays)
             return self._train_step_packed(state, arrays)
         return self._train_step(state, arrays)
 
     def eval_step_placed(self, params, arrays) -> dict:
         """eval_step over arrays already placed by ``stage_batches``."""
-        if len(arrays) == 4:
+        if _packed(arrays):
             self._check_packed(arrays)
             return self._eval_step_packed(params, arrays)
         return self._eval_step(params, arrays)
@@ -584,7 +590,7 @@ class Trainer:
         if tier not in PREDICT_TIERS:
             raise ValueError('tier must be one of %s, got %r'
                              % (PREDICT_TIERS, tier))
-        if len(arrays) == 4:
+        if _packed(arrays):
             self._check_packed(arrays)
             return self._predict_steps[(tier, 'packed')](params, arrays)
         return self._predict_steps[(tier, 'planes')](params, arrays)
@@ -597,7 +603,7 @@ class Trainer:
         one extra XLA compile, so the serving engine only calls it at
         warmup with telemetry enabled; returns None where the backend
         has no memory analysis."""
-        wire = 'packed' if len(arrays) == 4 else 'planes'
+        wire = 'packed' if _packed(arrays) else 'planes'
         return self._program_memory(self._predict_steps[(tier, wire)],
                                     params, arrays)
 
@@ -643,7 +649,7 @@ class Trainer:
         """AOT FLOPs/bytes of the train-step program for the shapes of
         ``arrays`` (either wire) — the MFU/roofline numerator
         (telemetry/goodput.py, OBSERVABILITY.md "Training goodput")."""
-        fn = (self._train_step_packed if len(arrays) == 4
+        fn = (self._train_step_packed if _packed(arrays)
               else self._train_step)
         return self._program_cost(fn, state, arrays)
 
@@ -670,7 +676,7 @@ class Trainer:
         autodiff twin's (benchmarks/bench_pallas_ragged.py records the
         per-arm value). Costs one extra XLA compile — bench/offline use
         only, never the hot path."""
-        fn = (self._train_step_packed if len(arrays) == 4
+        fn = (self._train_step_packed if _packed(arrays)
               else self._train_step)
         return self._program_memory(fn, state, arrays)
 
@@ -918,12 +924,15 @@ class Trainer:
                         # window holds the profiler
                         tele.trace.maybe_update(batch_num,
                                                 sync_tree=state.params)
-                    if len(arrays) == 4:
+                    if _packed(arrays):
                         # each NEW packed capacity = one more jit
                         # specialization of the whole step program
-                        shape_key = 'packed:%d' % int(arrays[0].shape[1])
-                        tele.capacity.observe(int(arrays[0].shape[1]),
-                                              batch_num)
+                        # (so is each new touched-row capacity)
+                        shapes = tuple(int(a.shape[1])
+                                       for a in arrays[:1] + arrays[4:6])
+                        shape_key = 'packed:' + ':'.join(map(str, shapes))
+                        tele.capacity.observe(shapes[0], batch_num,
+                                              rows=shapes[1:])
                     else:
                         shape_key = 'planes:%d' % int(arrays[0].shape[0])
                     # first sight of a dispatch shape: AOT step FLOPs/
