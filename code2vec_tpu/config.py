@@ -370,6 +370,37 @@ class Config:
     # {topk, attention, full, vectors} (training/trainer.py
     # PREDICT_TIERS). Fewer tiers = proportionally fewer eager compiles.
     SERVING_WARM_TIERS: str = 'topk,attention,full'
+    # ---- model seam (code2vec_tpu/models/families.py, SERVING.md) ----
+    # Which model this configuration names: 'code2vec' (the bag-of-
+    # contexts model; DL_FRAMEWORK picks the framework of its equations)
+    # or 'mellum' (a decoder-only token language model served through the
+    # same ServingEngine, tier 'generate'; models/decoder.py).
+    MODEL_FAMILY: str = 'code2vec'
+    # The decoder's published config.json (hidden sizes, layer_types,
+    # rope_parameters ...). Weights are the program's own seeded init
+    # (LM_PARAM_SEED), made on the device in bfloat16.
+    LM_CONFIG_PATH: str = ''
+    # Layers of that stack this process holds, named as config.json names
+    # it: a pipeline stage's cut (the first n layers). 0 = all of them.
+    num_hidden_layers: int = 0
+    LM_PARAM_SEED: int = 0
+    # Sequences resident at once: decode rows of a step and slots of the
+    # sliding layers' ring pool (serving/lm_cache.py).
+    LM_MAX_SEQS: int = 16
+    # Positions a page of the key/value cache holds, and the pages of the
+    # full layers' pool (each page is LM_PAGE_SIZE positions in every
+    # full layer).
+    LM_PAGE_SIZE: int = 128
+    LM_PAGE_POOL_PAGES: int = 1024
+    # Longest context (prompt + generated) a generate request may ask.
+    LM_MAX_CONTEXT: int = 8192
+    # Prompt tokens a step may carry beside its decode rows,
+    # comma-separated ascending: one program each, and one with none.
+    LM_CHUNK_BUCKETS: str = '256,512,1024,2048'
+    # A chunk reaches the sliding layers' attention as sub-sequences of
+    # this many queries, each rebased to its own window, so that a long
+    # chunk does not read keys its window excludes.
+    LM_WINDOW_SUBCHUNK: int = 512
     # ---- serving resilience (SERVING.md "Overload & rollover") ----
     # Default per-request SLO deadline in milliseconds (submit's
     # deadline_ms= overrides per request; 0 = no deadline). A deadlined
@@ -1226,6 +1257,21 @@ class Config:
         return buckets
 
     @property
+    def lm_chunk_buckets(self) -> Tuple[int, ...]:
+        """Parsed, sorted LM_CHUNK_BUCKETS."""
+        try:
+            buckets = tuple(sorted(
+                int(part) for part in
+                str(self.LM_CHUNK_BUCKETS).split(',') if part.strip()))
+        except ValueError:
+            raise ValueError('LM_CHUNK_BUCKETS must be comma-separated '
+                             'ints, got %r' % self.LM_CHUNK_BUCKETS)
+        if not buckets or any(bucket < 1 for bucket in buckets):
+            raise ValueError('LM_CHUNK_BUCKETS needs at least one bucket '
+                             '>= 1, got %r' % self.LM_CHUNK_BUCKETS)
+        return buckets
+
+    @property
     def serving_warm_tiers(self) -> Tuple[str, ...]:
         """Parsed SERVING_WARM_TIERS (validated against PREDICT_TIERS in
         verify() and at engine construction)."""
@@ -1383,6 +1429,15 @@ class Config:
         if self.HANG_WATCHDOG_SECS < 0:
             raise ValueError('config.HANG_WATCHDOG_SECS must be >= 0 '
                              '(0 disables the watchdog).')
+        if self.MODEL_FAMILY not in {'code2vec', 'mellum'}:
+            raise ValueError("config.MODEL_FAMILY must be in "
+                             "{'code2vec', 'mellum'}.")
+        self.lm_chunk_buckets  # raises on malformed bucket specs
+        if min(self.LM_MAX_SEQS, self.LM_PAGE_SIZE, self.LM_PAGE_POOL_PAGES,
+               self.LM_MAX_CONTEXT, self.LM_WINDOW_SUBCHUNK) < 1:
+            raise ValueError('config.LM_MAX_SEQS, LM_PAGE_SIZE, '
+                             'LM_PAGE_POOL_PAGES, LM_MAX_CONTEXT and '
+                             'LM_WINDOW_SUBCHUNK must be >= 1.')
         self.serving_batch_buckets  # raises on malformed bucket specs
         if self.SERVING_MAX_DELAY_MS < 0:
             raise ValueError('config.SERVING_MAX_DELAY_MS must be >= 0.')

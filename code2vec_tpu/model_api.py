@@ -925,3 +925,55 @@ class ServingParamSource:
         """Newest retained step of the model's store (None when the
         path holds no checkpoints yet)."""
         return self._store.newest_step()
+
+
+class DecoderLMModel:
+    """A decoder-only language model behind the same entry points: built
+    from a published ``config.json`` (``LM_CONFIG_PATH``) with the
+    program's own seeded weights, served by ``serving_engine()`` through
+    the ``generate`` tier (``models/decoder.py``, ``serving/
+    lm_scheduler.py``).  Serving only: it has no trainer."""
+
+    def __init__(self, config: Config):
+        from code2vec_tpu.models import decoder as decoder_lib
+        self.config = config
+        self.log = config.log
+        if not config.LM_CONFIG_PATH:
+            raise ValueError('MODEL_FAMILY %r needs LM_CONFIG_PATH'
+                             % config.MODEL_FAMILY)
+        with open(config.LM_CONFIG_PATH, 'r') as f:
+            published = json.load(f)
+        if config.num_hidden_layers:
+            published['num_hidden_layers'] = config.num_hidden_layers
+        self.decoder_config = decoder_lib.DecoderConfig.from_dict(published)
+        self.log('Creating decoder language model: %s'
+                 % decoder_lib.describe(self.decoder_config))
+        self.params = decoder_lib.init_params(self.decoder_config,
+                                              config.LM_PARAM_SEED)
+
+    def serving_engine(self, warmup: bool = True, **overrides):
+        """The same ``ServingEngine`` as code2vec's, its dispatcher running
+        the decoder's step loop."""
+        from code2vec_tpu.serving.engine import ServingEngine
+        from code2vec_tpu.serving.lm_scheduler import LMRuntime
+        runtime = LMRuntime(self.config, self.decoder_config, self.params)
+        engine = ServingEngine(self.config, None, self.params, None,
+                               decode_table=None, lm_runtime=runtime,
+                               log=self.log, **overrides)
+        try:
+            if warmup:
+                engine.warmup()
+        except BaseException:
+            engine.close()
+            raise
+        return engine
+
+    def close_stores(self) -> None:
+        """Nothing to close: the weights come from a seed, not a store."""
+
+
+def create_model(config: Config):
+    """The model ``config.MODEL_FAMILY`` names (``models/families.py``):
+    every family's model has ``serving_engine()`` and ``close_stores()``."""
+    from code2vec_tpu.models.families import family_of
+    return family_of(config).build(config)

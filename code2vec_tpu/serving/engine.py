@@ -92,6 +92,7 @@ from code2vec_tpu.data.reader import (Batch, EstimatorAction,
                                       PathContextReader,
                                       canonicalize_contexts,
                                       context_triples)
+from code2vec_tpu.models.families import family_of
 from code2vec_tpu.parallel import mesh as mesh_lib
 from code2vec_tpu.resilience import faults
 from code2vec_tpu.serving.errors import (DeadlineExceeded, EngineClosed,
@@ -528,8 +529,17 @@ class ServingEngine:
                  replica_id: Optional[str] = None,
                  external_dispatch: bool = False,
                  on_batch_done=None,
+                 lm_runtime=None,
                  log=None):
         self.config = config
+        # the model seam (models/families.py): the family declares the
+        # tiers; a language model brings its runtime (the weights, the
+        # cache pools and the step programs) and the dispatcher thread
+        # runs its step loop (serving/lm_scheduler.py) in place of the
+        # micro-batcher below.  None for code2vec: every branch on it
+        # is taken at construction or at the top of submit()/warmup()
+        self.family = family_of(config)
+        self._lm = None
         # mesh-replica identity (serving/mesh.py): labels this engine's
         # registry mirrors so N coexisting replicas never double-count a
         # counter or overwrite each other's gauges, and stamps the
@@ -549,15 +559,6 @@ class ServingEngine:
         self.params = params
         self.decode_table = decode_table
         self.log = log if log is not None else (lambda msg: None)
-        self.mesh = trainer.mesh
-        self.data_axis = self.mesh.shape[mesh_lib.DATA_AXIS]
-        # predict semantics: rows are never filtered; each row's line
-        # rides along for the attention tiers' decode.  Built here, in
-        # set-up, because the reader loads the native tokenizer's
-        # vocabulary (over a second at java14m size; the first run of a
-        # checkout compiles the library too): never on a request
-        self.reader = PathContextReader(vocabs, config,
-                                        EstimatorAction.Predict)
         import jax
         if jax.process_count() > 1:
             # per-host request queues cannot agree on batch contents
@@ -567,21 +568,44 @@ class ServingEngine:
                 'ServingEngine is single-host only (runs on %d '
                 'processes); serve one engine replica per host.'
                 % jax.process_count())
-        self.wire = config.wire_format_for(jax.process_count())
-        self.buckets = batch_ladder(config.serving_batch_buckets,
-                                    self.data_axis)
-        # capacity rungs per bucket: a bucket's per-shard stream can hold
-        # at most (bucket / data_axis) * MAX_CONTEXTS retained slots
-        self.capacities: Dict[int, Tuple[int, ...]] = {
-            bucket: packed_lib.capacity_ladder(
-                (bucket // self.data_axis) * config.MAX_CONTEXTS)
-            for bucket in self.buckets}
-        tiers = tuple(tiers if tiers is not None
-                      else config.serving_warm_tiers)
+        if lm_runtime is None:
+            self.mesh = trainer.mesh
+            self.data_axis = self.mesh.shape[mesh_lib.DATA_AXIS]
+            # predict semantics: rows are never filtered; each row's line
+            # rides along for the attention tiers' decode.  Built here, in
+            # set-up, because the reader loads the native tokenizer's
+            # vocabulary (over a second at java14m size; the first run of
+            # a checkout compiles the library too): never on a request
+            self.reader = PathContextReader(vocabs, config,
+                                            EstimatorAction.Predict)
+            self.wire = config.wire_format_for(jax.process_count())
+            self.buckets = batch_ladder(config.serving_batch_buckets,
+                                        self.data_axis)
+            # capacity rungs per bucket: a bucket's per-shard stream can
+            # hold at most (bucket / data_axis) * MAX_CONTEXTS retained
+            # slots
+            self.capacities: Dict[int, Tuple[int, ...]] = {
+                bucket: packed_lib.capacity_ladder(
+                    (bucket // self.data_axis) * config.MAX_CONTEXTS)
+                for bucket in self.buckets}
+            tiers = tuple(tiers if tiers is not None
+                          else config.serving_warm_tiers)
+        else:
+            if external_dispatch:
+                raise NotImplementedError(
+                    'a language model serves from one engine; it is not a '
+                    'mesh replica yet')
+            self.mesh = self.reader = self.wire = None
+            self.data_axis = 1
+            # the admission bound's unit: one request is one row, and a
+            # "bucket" is the sequences resident at once
+            self.buckets = (lm_runtime.slots,)
+            self.capacities = {}
+            tiers = tuple(tiers if tiers is not None else self.family.tiers)
         for tier in tiers:
-            if tier not in PREDICT_TIERS:
+            if tier not in self.family.tiers:
                 raise ValueError('unknown tier %r; expected a subset of %s'
-                                 % (tier, PREDICT_TIERS))
+                                 % (tier, self.family.tiers))
         self.tiers = tiers
         self.max_delay_s = (max_delay_ms if max_delay_ms is not None
                             else config.SERVING_MAX_DELAY_MS) / 1e3
@@ -651,8 +675,9 @@ class ServingEngine:
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         self._queues: Dict[str, collections.deque] = {
-            tier: collections.deque() for tier in PREDICT_TIERS}
-        self._pending_rows: Dict[str, int] = {t: 0 for t in PREDICT_TIERS}
+            tier: collections.deque() for tier in self.family.tiers}
+        self._pending_rows: Dict[str, int] = {
+            t: 0 for t in self.family.tiers}
         # rows admitted but not yet enqueued (tokenizing on the caller
         # thread): counted against the bound so concurrent submitters
         # cannot overshoot it between admission and enqueue
@@ -723,7 +748,8 @@ class ServingEngine:
         # load_params budget precheck.
         self._mem_prefix = 'engine:%x' % id(self)
         self._params_nbytes = memory_lib.tree_nbytes(
-            trainer.backend.param_shapes())
+            trainer.backend.param_shapes() if lm_runtime is None
+            else params)
         self._follow_thread: Optional[threading.Thread] = None
         self._follow_stop = threading.Event()
         # joins a batch's profiler events across the dispatcher and the
@@ -738,14 +764,18 @@ class ServingEngine:
             max_workers=self._decode_slots,
             thread_name_prefix='serving-decode'
             + ('' if replica_id is None else '-%s' % replica_id))
+        if lm_runtime is not None:
+            from code2vec_tpu.serving.lm_scheduler import LMScheduler
+            self._lm = LMScheduler(self, lm_runtime)
         if self._external:
             # a mesh replica owns no queue: the mesh's replica puller
             # is the dispatcher (serving/mesh.py)
             self._dispatcher: Optional[threading.Thread] = None
         else:
             self._dispatcher = threading.Thread(
-                target=self._dispatch_loop, daemon=True,
-                name='serving-dispatch')
+                target=(self._dispatch_loop if self._lm is None
+                        else self._lm.loop),
+                daemon=True, name='serving-dispatch')
             self._dispatcher.start()
 
     # ---------------------------------------------------------- warmup
@@ -780,6 +810,10 @@ class ServingEngine:
         import jax
         with self._warm_lock:
             if self._warm:
+                return self
+            if self._lm is not None:
+                self._warm_lm()
+                self._warm = True
                 return self
             with self._lock:
                 params = self.params
@@ -851,6 +885,24 @@ class ServingEngine:
             self._warm = True
         return self
 
+    def _warm_lm(self) -> None:
+        """A language model's warm-up: one program a chunk bucket and the
+        decode-only one."""
+        t0 = time.perf_counter()
+        runtime = self._lm.runtime
+        try:
+            programs = self._lm.warm()
+        except Exception as exc:
+            memory_lib.ledger().note_oom(exc, 'serving.warmup')
+            raise
+        warm_s = time.perf_counter() - t0
+        if tele_core.enabled():
+            self._mirror.gauge('serving/warmup_s').set(warm_s)
+            self._mirror.gauge('serving/programs_warm').set(programs)
+        self.log('serving: warmed %d step programs (chunk buckets %s, %d '
+                 'decode rows) in %.1fs'
+                 % (programs, list(runtime.buckets), runtime.slots, warm_s))
+
     # ------------------------------------------------------- admission
     def _shed_locked(self, rows: int, why: str) -> None:
         """Reject one submission at admission (typed, nothing enqueued)."""
@@ -867,14 +919,21 @@ class ServingEngine:
         return sum(self._pending_rows.values()) + self._reserved_rows
 
     def _admit(self, rows: int, tier: str,
-               deadline_s: Optional[float]) -> str:
+               deadline_s: Optional[float], context: int = 0) -> str:
         """Admission control for one submission: bound check, drain
         estimate vs deadline, degradation ladder. Reserves ``rows``
         against the bound (released on enqueue or failure) and returns
-        the EFFECTIVE tier to serve."""
+        the EFFECTIVE tier to serve.  A ``generate`` request states its
+        ``context`` (positions of cache it will hold): the cache manager
+        is asked whether that can ever be resident; when it can is the
+        step loop's question (serving/lm_scheduler.py)."""
         with self._cond:
             if self._closed:
                 raise EngineClosed('ServingEngine is closed')
+            if context and not self._lm.cache.fits_ever(context):
+                raise ValueError(
+                    'a context of %d positions can never be admitted to '
+                    'this engine\'s cache' % context)
             if faults.maybe_fire('reject_all'):
                 self._shed_locked(rows, 'reject_all drill')
             admitted = self._admitted_rows_locked()
@@ -912,7 +971,9 @@ class ServingEngine:
     # ---------------------------------------------------------- submit
     def submit(self, context_lines: Sequence[str],
                tier: str = 'topk',
-               deadline_ms: Optional[float] = None) -> Future:
+               deadline_ms: Optional[float] = None,
+               max_new_tokens: int = 1,
+               return_logits: bool = False) -> Future:
         """Enqueue one prediction request (raw extractor/``.c2v`` context
         lines, like ``model.predict``). Returns a Future resolving to
         one ``ModelPredictionResults`` per line, in order. Requests
@@ -926,6 +987,9 @@ class ServingEngine:
             raise RuntimeError(
                 'this engine is a mesh replica (external dispatch); '
                 'submit through its ServingMesh (serving/mesh.py)')
+        if self._lm is not None:
+            return self._submit_generate(context_lines, tier,
+                                         max_new_tokens, return_logits)
         if tier not in self.tiers:
             raise ValueError('tier %r is not warmed on this engine '
                              '(tiers=%s)' % (tier, list(self.tiers)))
@@ -1025,6 +1089,48 @@ class ServingEngine:
                 trace.finish(status='closed')
             raise closed_exc
         return future
+
+    def _submit_generate(self, prompt_ids, tier: str, max_new_tokens: int,
+                         return_logits: bool) -> Future:
+        """``submit`` of a language model's engine: ``prompt_ids`` (token
+        ids) in, a Future of a ``GenerationResult`` out: ``max_new_tokens``
+        greedy ids, always run to their end, and with ``return_logits``
+        the float32 logits each was picked from."""
+        from code2vec_tpu.serving.lm_scheduler import GenerateRequest
+        if tier not in self.tiers:
+            raise ValueError('tier %r is not served by this engine '
+                             '(tiers=%s)' % (tier, list(self.tiers)))
+        prompt = np.ascontiguousarray(prompt_ids, dtype=np.int32)
+        self._lm.check_request(prompt, max_new_tokens)
+        # graftlint: disable=lock-discipline -- benign racy read: warmup() is idempotent and re-checks _warm under _warm_lock
+        if not self._warm:
+            self.warmup()
+        self.requests_total.inc()
+        if tele_core.enabled():
+            self._mirror.counter('serving/requests_total').inc()
+        request = GenerateRequest(prompt, int(max_new_tokens),
+                                  bool(return_logits))
+        self._admit(1, tier, None, context=request.context)
+        with self._cond:
+            self._reserved_rows -= 1
+            if self._closed:
+                raise EngineClosed('ServingEngine is closed')
+            self._queues[tier].append(request)
+            self._pending_rows[tier] += 1
+            self._set_queue_depth_locked()
+            self._cond.notify_all()
+        return request.future
+
+    def lm_runtime(self):
+        """A language model's runtime (``serving/lm_scheduler.py::
+        LMRuntime``: weights, cache pools, step programs); None for
+        code2vec."""
+        return self._lm.runtime if self._lm is not None else None
+
+    def lm_step_log(self) -> list:
+        """What every finished step of a language model carried
+        (``LMScheduler.step_log``); empty for code2vec."""
+        return self._lm.step_log() if self._lm is not None else []
 
     def predict(self, context_lines: Sequence[str], tier: str = 'topk',
                 timeout: Optional[float] = None) -> list:
@@ -1848,6 +1954,7 @@ class ServingEngine:
             'params_step': params_step,
             'tracing': (self._tracer.stats()
                         if self._tracer is not None else None),
+            'lm': self._lm.stats() if self._lm is not None else None,
         }
 
     def close(self, drain: bool = False) -> None:

@@ -155,6 +155,38 @@ CATALOG: Dict[str, Dict[str, str]] = {
     'serving/bulk_examples_per_sec': _m(GAUGE, 'examples/s', 'Streaming '
                                         'bulk predict / embedding-export '
                                         'throughput.'),
+    # ---- language-model step loop (serving/lm_scheduler.py) ----
+    'serving/lm_steps_total': _m(COUNTER, 'steps', 'Steps the language '
+                                 'model\'s dispatcher enqueued (one '
+                                 'program run each).'),
+    'serving/lm_tokens_total': _m(COUNTER, 'tokens', 'Tokens those steps '
+                                  'carried: decode rows and prompt-chunk '
+                                  'tokens, padding not counted.'),
+    'serving/lm_tokens_per_step': _m(GAUGE, 'tokens', 'Tokens of the last '
+                                     'step enqueued.'),
+    'serving/lm_decode_step_ms': _m(TIMER, 'ms', 'A step of decode rows '
+                                    'only, device-paced: from the end of '
+                                    'the step before it (or its own '
+                                    'enqueue, if the device was idle) to '
+                                    'its tokens on the host.'),
+    'serving/lm_prefill_chunk_ms': _m(TIMER, 'ms', 'The same for a step '
+                                      'that carried a prompt chunk.'),
+    'serving/lm_ttft_ms': _m(TIMER, 'ms', 'submit() of a generate request '
+                             'to its first generated token on the host.'),
+    'serving/lm_admit_wait_ms': _m(TIMER, 'ms', 'submit() to leaving the '
+                                   'queue for the running set: the wait '
+                                   'for a ring slot and pages.'),
+    'serving/lm_admit_held_total': _m(COUNTER, 'admissions', 'Times the '
+                                      'queue\'s head was held because the '
+                                      'cache manager had no ring slot or '
+                                      'too few pages for it.'),
+    'serving/lm_ring_pool_fill': _m(GAUGE, 'fraction', 'Ring slots in use '
+                                    '/ slots (the sliding layers\' pool).'),
+    'serving/lm_page_pool_fill': _m(GAUGE, 'fraction', 'Pages in use / '
+                                    'pages (the full layers\' pool).'),
+    'serving/lm_expert_load_max_over_mean': _m(
+        GAUGE, 'ratio', 'Tokens of the busiest expert over the mean '
+        'expert, in the last step, mean over the layers (1 = even).'),
     # ---- serving resilience (admission control / rollover / breaker) ----
     'serving/shed_total': _m(COUNTER, 'requests', 'Requests rejected at '
                              'admission (queue bound, drain-estimate vs '

@@ -118,6 +118,30 @@ SPAN_CATALOG: Dict[str, str] = {
                             'decode worker (attrs: rows, k); a '
                             'single-span trace, the callback holds no '
                             'request trace.',
+    'serving.lm_step': 'Language model, profiler event only: the '
+                       'dispatcher plans one step (a token for every '
+                       'decoding sequence, a chunk of the prompt in '
+                       'prefill), builds its inputs and enqueues it.  '
+                       'Stats: step, decode_rows, prefill_tokens, '
+                       'bucket.',
+    'serving.lm_prefill_chunk': 'Language model, profiler event only: '
+                                'the dispatcher waits for the tokens of '
+                                'a step that carried a prompt chunk '
+                                '(the device runs it, the next step is '
+                                'already enqueued).  Stats: step, '
+                                'tokens.',
+    'serving.lm_decode': 'Language model, profiler event only: the same '
+                         'wait for a step of decode rows only.  Stats: '
+                         'step, tokens.',
+    'serving.lm_first_token': 'Language model, profiler event only: a '
+                              'request\'s first generated token reached '
+                              'the host.  Stats: since_submit_ms, '
+                              'prompt.',
+    'serving.lm_admit_wait': 'Language model, profiler event only: a '
+                             'request left the queue for the running '
+                             'set (the cache manager had a ring slot '
+                             'and pages for it).  Stats: waited_ms, '
+                             'prompt.',
     'serving.shed': 'Terminal: shed at admission with EngineOverloaded '
                     '(attrs carry the reason).',
     'serving.expired': 'Terminal: SLO deadline passed while queued '

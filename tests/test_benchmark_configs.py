@@ -75,8 +75,44 @@ def write_corpus(tmp_path, methods=16, tags=12):
     return prefix, lines
 
 
+def language_model_agrees_at_toy_width(tmp_path, name):
+    """A language model's configuration at its own ``rehearsal`` widths, in
+    float32: a prompt longer than the ring through chunked prefill and
+    decode, against ``chipbench/reference_mellum2.py``."""
+    from chipbench import reference_mellum2, run
+    from chipbench.runners import serve_lm
+    from code2vec_tpu import model_api
+    with open(os.path.join(REPO, 'chipbench', 'configs',
+                           name + '.json')) as f:
+        spec = json.load(f)
+    spec = run.merged(spec, spec['rehearsal'])
+    model_config = {k: spec[k] for k in serve_lm.MODEL_KEYS if k in spec}
+    path = tmp_path / 'config.json'
+    path.write_text(json.dumps(model_config))
+    ctx = types.SimpleNamespace(
+        settings=dict(spec['settings'], COMPUTE_DTYPE='float32'),
+        cell=types.SimpleNamespace(config_name=name))
+    model = model_api.create_model(common.make_config(
+        ctx, LM_CONFIG_PATH=str(path), LM_PARAM_SEED=5, VERBOSE_MODE=0))
+    prompt = np.random.default_rng(3).integers(
+        0, model_config['vocab_size'], 75)
+    with model.serving_engine() as engine:
+        result = engine.submit(prompt, tier='generate', max_new_tokens=6,
+                               return_logits=True).result(timeout=300)
+    wanted = np.asarray(reference_mellum2.forward(
+        model_config, serve_lm.reference_weights(model.params, model_config),
+        np.concatenate([prompt, result.token_ids[:-1]]),
+        first_logit=len(prompt) - 1))
+    np.testing.assert_allclose(
+        np.stack([np.asarray(row) for row in result.logits]), wanted,
+        atol=1e-4)
+
+
 @pytest.mark.parametrize('name', CONFIG_NAMES)
 def test_program_agrees_with_reference_at_toy_width(tmp_path, name):
+    if context_for(name).settings.get('MODEL_FAMILY',
+                                      'code2vec') != 'code2vec':
+        return language_model_agrees_at_toy_width(tmp_path, name)
     prefix, lines = write_corpus(tmp_path)
     ctx = context_for(name, **TOY)
     devices = (ctx.settings['MESH_DATA_AXIS_SIZE']
