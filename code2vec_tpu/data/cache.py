@@ -153,7 +153,7 @@ class TokenCache:
     def _packer_for(self, data_shards: int) -> packed_lib.StickyPacker:
         if self._packer is None or self._packer.data_shards != data_shards:
             # the cache holds TRAINING data: its packed batches name the
-            # rows they touch when they feed a data-parallel mesh
+            # rows they touch
             self._packer = packed_lib.StickyPacker(
                 self.vocabs.token_vocab.pad_index,
                 self.vocabs.path_vocab.pad_index, data_shards=data_shards,

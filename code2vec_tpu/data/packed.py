@@ -17,10 +17,11 @@ contiguous stream of ``(source, path, target)`` int32 triples:
   label   (B,) int32
   weight  (B,) float32
 
-A TRAINING stream packed for a data-parallel mesh (``data_shards > 1``)
-also names, per shard, the embedding rows the shard's slots touch, so the
-step can reduce the two tables' gradients over those rows and not over
-the tables (ops/pallas_ragged.py ``_rows_table_grad``):
+A TRAINING stream (a packer built with ``table_rows``) also names, per
+data shard, the embedding rows the shard's slots touch, so the step can
+build and reduce the two tables' gradients over those rows and not over
+the tables (ops/pallas_ragged.py ``_rows_table_grad``), on one shard as
+on several:
 
   tok_rows  (data_shards, U_tok)  int32 — the shard's distinct token rows
             (source and target slots together), ascending, its PAD row
@@ -35,8 +36,9 @@ Past a shard's distinct rows each row array goes on with distinct
 ascending ids BEYOND the table's last row (``rows_in_table + k``), so the
 whole array is sorted and unique and a ``mode='drop'`` scatter discards
 the padding. ``U_tok``/``U_path`` are sticky and bucketed like
-``capacity``. Every other stream (eval, predict, serving, one data shard)
-ships the four arrays alone.
+``capacity``. Every other stream (eval, predict, serving, bulk) ships
+the four arrays alone, and so does a one-shard training stream from the
+batch on whose capacity passes ``ONE_SHARD_ROWS_MAX_CAPACITY``.
 
 12 bytes per RETAINED slot + 12 bytes per example. Keeping everything up
 to the last valid slot (not only the mask-valid slots) is what makes the
@@ -81,6 +83,16 @@ PACKED_ARITIES = (4, 7)
 # total/8 bucket below.
 MIN_CAPACITY = 64
 
+# The packed capacity up to which ONE data shard's row-wise table gradients
+# beat the dense scatter-adds on the chip (PERF.md section 6, PR 34: +7.4 ms
+# a step at 40,960 slots, +4.0..5.5 at 102,400, -1.3..-3.8 at 147,456, at
+# either extreme of index skew: XLA's dense scatter-add runs at 13-17 ns an
+# update from about 2**17 updates on and at 76-90 ns under that, the row
+# form at 15-20 ns throughout). Past it a one-shard stream ships the four
+# wire arrays. On a data-parallel mesh the rows also replace the tables'
+# all-reduce, and ship whatever the capacity.
+ONE_SHARD_ROWS_MAX_CAPACITY = 1 << 17
+
 
 class PackedBatch(NamedTuple):
     """One device-ready batch in the packed wire format. Mirrors
@@ -91,7 +103,7 @@ class PackedBatch(NamedTuple):
     weight: np.ndarray               # (B,) float32 — example validity
     label_strings: Optional[np.ndarray] = None     # (B,) object
     context_lines: Optional[np.ndarray] = None     # (B,) object
-    # per-shard touched rows, training streams on data_shards > 1 only
+    # per-shard touched rows, training streams only
     tok_rows: Optional[np.ndarray] = None          # (D, U_tok) int32
     path_rows: Optional[np.ndarray] = None         # (D, U_path) int32
     inv: Optional[np.ndarray] = None               # (D, cap, 3) int32
@@ -296,9 +308,10 @@ class StickyPacker:
     data source (reader / cache), living across epochs.
 
     ``table_rows`` = (token table rows, path table rows) marks a TRAINING
-    stream: with ``data_shards > 1`` every batch then also carries its
-    shards' touched rows (module docstring), under sticky capacities of
-    their own.
+    stream: every batch then also carries its shards' touched rows
+    (module docstring), under sticky capacities of their own, whatever
+    the number of data shards; one shard stops once its sticky capacity
+    passes ``ONE_SHARD_ROWS_MAX_CAPACITY``, for good.
 
     Instrumented (telemetry enabled only — one bool read otherwise):
     pack time (``step/pack_ms``, recorded from whichever reader/prefetch
@@ -315,7 +328,7 @@ class StickyPacker:
         self.path_pad = path_pad
         self.data_shards = data_shards
         self.capacity = self.minimum = minimum
-        self.table_rows = table_rows if data_shards > 1 else None
+        self.table_rows = table_rows
         self.tok_capacity = self.path_capacity = minimum
         if self.table_rows is not None:
             self._tok_lut = np.empty((table_rows[0],), np.int32)
@@ -357,6 +370,14 @@ class StickyPacker:
         record the batch's instruments."""
         from code2vec_tpu.telemetry import core
         distinct = None
+        capacity = packed.ctx.shape[1]
+        if (self.table_rows is not None and self.data_shards == 1
+                and capacity > ONE_SHARD_ROWS_MAX_CAPACITY):
+            logger.info('packed capacity %d is past %d: this one-shard '
+                        'stream ships the four wire arrays from here on '
+                        '(the dense scatter-adds are the cheaper form '
+                        'there)', capacity, ONE_SHARD_ROWS_MAX_CAPACITY)
+            self.table_rows = None
         if self.table_rows is not None:
             tok_rows, path_rows, inv, distinct = self._touched_rows(
                 packed.ctx)
@@ -366,7 +387,7 @@ class StickyPacker:
             reg = core.registry()
             reg.timer('step/pack_ms').record(_time.perf_counter() - t0)
             retained = int(packed.count.sum())
-            slots = int(packed.ctx.shape[0]) * int(packed.ctx.shape[1])
+            slots = int(packed.ctx.shape[0]) * int(capacity)
             reg.gauge('input/packed_fill_rate').set(retained / max(slots, 1))
             if distinct is not None:
                 reg.gauge('input/unique_row_share').set(

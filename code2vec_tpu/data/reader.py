@@ -544,8 +544,8 @@ class PathContextReader:
         if wire_format == 'packed':
             from code2vec_tpu.data import packed as packed_lib
             if self._packer is None:
-                # a training stream names the rows it touches where it
-                # feeds a data-parallel mesh (data/packed.py)
+                # a training stream names the rows it touches
+                # (data/packed.py)
                 self._packer = packed_lib.StickyPacker(
                     self.vocabs.token_vocab.pad_index,
                     self.vocabs.path_vocab.pad_index,

@@ -728,7 +728,13 @@ def _rows_table_grad(table_rows: int, rows, inv, cot, mesh):
     dense gradient in turn. A buffer's rows are unique and sorted, which
     the scatter is told; rows that shards share are summed by the
     sequence; the padding is out of bounds and dropped. Same sums as the
-    dense form, reassociated, in ``cot``'s dtype throughout."""
+    dense form, reassociated, in ``cot``'s dtype throughout.
+
+    One shard (D = 1) is the cheap case and has no collective: one
+    compact scatter-add and one unique sorted scatter into zeros, which
+    the TPU compiler fuses with the zero-fill and runs at the gathers'
+    rate (a later shard's scatter meets a live table and does not,
+    PERF.md). The ``vmap`` over one shard lowers to the plain scatter."""
     shards, capacity = rows.shape
     dim = cot.shape[-1]
     compact = jax.vmap(
@@ -768,9 +774,9 @@ def ragged_encode_code(token_embedding: jax.Array,
     PRNG key/``rows`` get ``None`` cotangents.
 
     ``rows`` = ``(tok_rows, path_rows, inv)`` where the batch names the
-    rows it touches (a training stream on a data-parallel mesh,
-    data/packed.py): the backward then reduces the two table gradients
-    over those rows (``_rows_table_grad``); the forward never reads them.
+    rows it touches (a training stream, data/packed.py): the backward
+    then builds the two table gradients over those rows
+    (``_rows_table_grad``); the forward never reads them.
 
     ``use_kernel`` routes BOTH passes: False runs the jnp twin pair,
     True the Pallas pair (``Config.RAGGED_TRAIN_KERNEL``; off a TPU that

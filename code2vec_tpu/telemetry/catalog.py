@@ -111,8 +111,9 @@ CATALOG: Dict[str, Dict[str, str]] = {
     'input/unique_row_share': _m(GAUGE, 'fraction', 'Distinct embedding '
                                  'rows the last batch\'s shards named / its '
                                  'retained index slots, tokens and paths '
-                                 'together (training on data_shards > 1: '
-                                 'the rows the gradient reduction carries).'),
+                                 'together (a training stream on the '
+                                 'packed wire: the rows the table gradients '
+                                 'are built and reduced over).'),
     'input/row_capacity_fill': _m(GAUGE, 'fraction', 'Distinct embedding '
                                   'rows the last batch\'s shards named / '
                                   'the touched-row capacity they ship '

@@ -1,12 +1,14 @@
 """Touched rows on the packed wire (data/packed.py) and the gradient
-reduction that uses them (ops/pallas_ragged.py ``_rows_table_grad``): on
-a data-parallel mesh a training batch names, per shard, the embedding
-rows its slots touch, and the step gathers those rows' sums where the
-dense form all-reduces the tables.
+reduction that uses them (ops/pallas_ragged.py ``_rows_table_grad``): a
+training batch names, per data shard, the embedding rows its slots touch;
+the step sums its slots into those rows and scatters them, unique and
+sorted, into the dense gradient, and on a data-parallel mesh gathers the
+rows' sums where the dense form all-reduces the tables.
 
-CPU, four of the eight virtual devices: what the packer emits, that the
-train step's table gradients are the dense form's, which collectives the
-compiled step holds, and that one data shard sees none of it."""
+CPU, up to four of the eight virtual devices: what the packer emits, that
+the train step's table gradients are the dense form's on one shard and on
+several, which collectives and scatters the step holds, and that a stream
+packed without ``table_rows`` (eval, predict) sees none of it."""
 import re
 
 import jax
@@ -192,19 +194,59 @@ def test_row_capacity_leaves_head_room_and_holds_what_fits():
 
 
 @pytest.mark.parametrize('table_rows_given', [True, False])
-def test_one_data_shard_ships_the_four_arrays_unchanged(table_rows_given):
+def test_one_data_shard_ships_the_plain_pack_and_its_rows_where_asked(
+        table_rows_given):
     rng = np.random.default_rng(7)
     batch = plane_batch(rng)
     packer = packed_lib.StickyPacker(
         0, 0, data_shards=1, minimum=8,
         table_rows=(128, 128) if table_rows_given else None)
     packed = packer.pack_batch(batch)
-    assert packed.inv is None and packed.tok_rows is None
     want = packed_lib.pack_batch(batch, 0, 0, data_shards=1,
                                  capacity_minimum=8)
-    assert len(packed.device_arrays()) == 4
-    for got, ref in zip(packed.device_arrays(), want.device_arrays()):
+    arrays = packed.device_arrays()
+    for got, ref in zip(arrays, want.device_arrays()):
         np.testing.assert_array_equal(got, ref)
+    if not table_rows_given:
+        assert packed.inv is None and packed.tok_rows is None
+        assert len(arrays) == 4
+        return
+    assert len(arrays) == 7
+    assert packed.tok_rows.shape == (1, packer.tok_capacity)
+    assert packed.path_rows.shape == (1, packer.path_capacity)
+    assert packed.inv.shape == packed.ctx.shape
+    np.testing.assert_array_equal(
+        packed.tok_rows[0][packed.inv[0][:, (0, 2)]],
+        packed.ctx[0][:, (0, 2)])
+    np.testing.assert_array_equal(packed.path_rows[0][packed.inv[0, :, 1]],
+                                  packed.ctx[0, :, 1])
+
+
+@pytest.mark.parametrize('shards,arrays_past_the_limit', [(1, 4), (2, 7)])
+def test_one_shard_stops_naming_rows_past_the_measured_capacity(
+        monkeypatch, shards, arrays_past_the_limit):
+    # PERF.md section 6 (PR 34): past 2**17 slots a shard the dense
+    # scatter-adds win on one chip; across chips the rows also replace the
+    # tables' all-reduce and stay
+    monkeypatch.setattr(packed_lib, 'ONE_SHARD_ROWS_MAX_CAPACITY', 48)
+
+    def batch_of(contexts):
+        """Every method with ``contexts`` contexts: 16 x 2 = 32 slots fit
+        under the limit on one shard and on two, 16 x 8 = 128 do not."""
+        ids = np.where(np.arange(CONTEXTS) < contexts,
+                       1 + np.arange(BATCH * CONTEXTS).reshape(
+                           BATCH, CONTEXTS) % 7, 0).astype(np.int32)
+        return Batch(source=ids, path=ids, target=ids,
+                     mask=context_valid_mask(ids, ids, ids, 0, 0),
+                     label=np.ones((BATCH,), np.int32),
+                     weight=np.ones((BATCH,), np.float32))
+
+    packer = packed_lib.StickyPacker(0, 0, data_shards=shards, minimum=8,
+                                     table_rows=(128, 128))
+    seen = [len(packer.pack_batch(batch_of(contexts)).device_arrays())
+            for contexts in (2, 8, 2)]
+    assert packer.capacity == BATCH * CONTEXTS // shards > 48
+    assert seen == [7, arrays_past_the_limit, arrays_past_the_limit]
 
 
 def test_training_reader_names_rows_and_eval_reader_does_not(
@@ -223,13 +265,14 @@ def test_training_reader_names_rows_and_eval_reader_does_not(
         vocabs, config.PARAM_ROW_ALIGNMENT)
     assert packed.tok_rows.max() >= token_rows > packed.ctx[..., 0].max()
     assert packed.path_rows.max() >= path_rows > packed.ctx[..., 1].max()
-    for action, shards in ((EstimatorAction.Evaluate, 2),
-                           (EstimatorAction.Train, 1)):
+    for action, shards, arrays in ((EstimatorAction.Evaluate, 2, 4),
+                                   (EstimatorAction.Evaluate, 1, 4),
+                                   (EstimatorAction.Train, 1, 7)):
         reader = PathContextReader(vocabs, config, action,
                                    data_shards=shards)
         packed = next(iter(reader.iter_epoch(shuffle=False,
                                              wire_format='packed')))
-        assert len(packed.device_arrays()) == 4
+        assert len(packed.device_arrays()) == arrays, (action, shards)
 
 
 # -------------------------------------------------------- gradient parity
@@ -240,11 +283,11 @@ def table_grads(state):
             np.asarray(mu.path_embedding) / 0.1)
 
 
-@pytest.mark.parametrize('data,model,opt_sharding', [
-    (4, 1, 'mirror'), (2, 2, 'mirror'), (4, 1, 'zero')])
-def test_table_gradients_equal_the_dense_form(data, model, opt_sharding):
-    trainer = make_trainer(data, model,
-                           OPTIMIZER_STATE_SHARDING=opt_sharding)
+def assert_corner_step_equals_the_dense_form(trainer):
+    """One real train step on ``corner_batch`` from a packer that names
+    rows and from one that does not: the same loss, and the same token and
+    path gradients through ``mu``, to 1e-6."""
+    data = trainer.mesh.shape[mesh_lib.DATA_AXIS]
     with_rows, plain = packers(trainer, minimum=4)
     rng = np.random.default_rng(13)
     # the first batch sets the row capacities; the second's first shard
@@ -256,9 +299,12 @@ def test_table_gradients_equal_the_dense_form(data, model, opt_sharding):
     assert packed.path_rows.shape == first.path_rows.shape
     paths_in_table = table_rows(trainer.config)[1]
     assert (packed.path_rows[0] < paths_in_table).all()        # U, exactly
-    assert (packed.path_rows[1:] >= paths_in_table).any()
-    shared = set(packed.tok_rows[0]) & set(packed.tok_rows[1])
-    assert len(shared) > 2                                     # shared rows
+    if data > 1:
+        assert (packed.path_rows[1:] >= paths_in_table).any()
+        shared = set(packed.tok_rows[0]) & set(packed.tok_rows[1])
+        assert len(shared) > 2                                 # shared rows
+    else:
+        assert (packed.tok_rows[0] >= table_rows(trainer.config)[0]).any()
     assert packed.count[-1] == 0 and packed.count[1] == CONTEXTS
 
     state = trainer.init_state(seed=0)
@@ -272,8 +318,24 @@ def test_table_gradients_equal_the_dense_form(data, model, opt_sharding):
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
-def test_eight_steps_end_at_the_dense_forms_loss():
-    trainer = make_trainer(4, 1)
+@pytest.mark.parametrize('data,model,opt_sharding', [
+    (4, 1, 'mirror'), (2, 2, 'mirror'), (4, 1, 'zero'),
+    (1, 1, 'mirror'), (1, 2, 'mirror')])
+def test_table_gradients_equal_the_dense_form(data, model, opt_sharding):
+    assert_corner_step_equals_the_dense_form(make_trainer(
+        data, model, OPTIMIZER_STATE_SHARDING=opt_sharding))
+
+
+def test_pallas_pair_on_one_shard_reduces_over_the_rows(pallas_interpret):
+    # the kernels end where the slots' cotangents do: the scatters behind
+    # them are _bwd_compute's tail, the jnp pair's
+    assert_corner_step_equals_the_dense_form(make_trainer(
+        1, 1, RAGGED_TRAIN_KERNEL=True))
+
+
+@pytest.mark.parametrize('data', [4, 1])
+def test_eight_steps_end_at_the_dense_forms_loss(data):
+    trainer = make_trainer(data, 1)
     with_rows, plain = packers(trainer)
     rng = np.random.default_rng(17)
     batches = [plane_batch(rng) for _ in range(8)]
@@ -299,11 +361,15 @@ def collectives(text):
             (COLLECTIVE.search(line) for line in text.splitlines()) if m]
 
 
-def compiled_text(trainer, packed):
+def lowered(trainer, packed):
+    """The packed train step lowered for ``packed``'s shapes."""
     arrays = mesh_lib.shard_batch(packed.device_arrays(), trainer.mesh)
-    state = trainer.init_state(seed=0)
-    return trainer._train_step_packed.lower(state, arrays).compile(
-        ).as_text()
+    return trainer._train_step_packed.lower(trainer.init_state(seed=0),
+                                            arrays)
+
+
+def compiled_text(trainer, packed):
+    return lowered(trainer, packed).compile().as_text()
 
 
 def test_no_collective_of_the_step_carries_a_table():
@@ -328,24 +394,54 @@ def test_no_collective_of_the_step_carries_a_table():
     assert any(shape.startswith('f32[') for shape in gathered), by_rows
 
 
-def test_one_device_step_takes_the_four_arrays_and_lowers_as_before():
+def test_a_packer_without_table_rows_lowers_the_one_device_step_as_before():
+    # eval, predict, serving and bulk build their packer without
+    # ``table_rows``: four arrays, the step program of the plain pack
     trainer = make_trainer(1, 1)
     batch = plane_batch(np.random.default_rng(23))
     packed = packed_lib.StickyPacker(
-        0, 0, data_shards=1, minimum=8,
-        table_rows=table_rows(trainer.config)).pack_batch(batch)
+        0, 0, data_shards=1, minimum=8).pack_batch(batch)
     assert len(packed.device_arrays()) == 4
-    state = trainer.init_state(seed=0)
-
-    def lowered(wire):
-        arrays = mesh_lib.shard_batch(wire.device_arrays(), trainer.mesh)
-        return trainer._train_step_packed.lower(state, arrays).as_text()
-
-    text = lowered(packed)
-    assert text == lowered(packed_lib.pack_batch(
-        batch, 0, 0, data_shards=1, capacity_minimum=8))
-    new_state, loss = trainer.train_step(state, packed)
+    assert lowered(trainer, packed).as_text() == lowered(
+        trainer, packed_lib.pack_batch(
+            batch, 0, 0, data_shards=1, capacity_minimum=8)).as_text()
+    new_state, loss = trainer.train_step(trainer.init_state(seed=0), packed)
     assert np.isfinite(float(loss)) and int(new_state.step) == 1
+
+
+SCATTER = re.compile(
+    r'"stablehlo\.scatter"\(.*?unique_indices = (true|false)\}>.*?'
+    r'\}\) : \([^\n]*\) -> tensor<([0-9x]+)xf32>', re.S)
+
+
+def test_one_device_step_scatters_unique_rows_into_the_tables():
+    # tables of their own size, so a row count cannot be mistaken
+    trainer = make_trainer(1, 1, PARAM_ROW_ALIGNMENT=8,
+                           MAX_TOKEN_VOCAB_SIZE=TOKENS)
+    with_rows, plain = packers(trainer)
+    batch = plane_batch(np.random.default_rng(23))
+    tokens_in_table, paths_in_table = table_rows(trainer.config)
+    assert (tokens_in_table, paths_in_table) == (40, 16)
+    tables = {'%dx8' % tokens_in_table, '%dx8' % paths_in_table}
+
+    def into_tables(wire):
+        """``unique_indices`` of every scatter whose result is a table's
+        gradient, in program order."""
+        text = lowered(trainer, wire).as_text()
+        assert not re.search(r'stablehlo\.(all_|reduce_scatter|collective_)'
+                             r'|@Sharding', text)
+        return [unique for unique, shape in SCATTER.findall(text)
+                if shape in tables]
+
+    # the dense form: a scatter-add of every slot, then the PAD row's term
+    assert into_tables(plain.pack_batch(batch)) == [
+        'false', 'true', 'false', 'true']
+    # by rows: the duplicates meet in the compact buffers, and nothing
+    # reaches a table but rows the scatter is told are unique
+    packed = with_rows.pack_batch(batch)
+    assert len(packed.device_arrays()) == 7
+    assert into_tables(packed) == ['true'] * 4
+    assert not collectives(compiled_text(trainer, packed))
 
 
 def test_capacity_tracker_counts_a_new_row_capacity_once():
