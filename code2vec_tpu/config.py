@@ -372,9 +372,11 @@ class Config:
     SERVING_WARM_TIERS: str = 'topk,attention,full'
     # ---- model seam (code2vec_tpu/models/families.py, SERVING.md) ----
     # Which model this configuration names: 'code2vec' (the bag-of-
-    # contexts model; DL_FRAMEWORK picks the framework of its equations)
-    # or 'mellum' (a decoder-only token language model served through the
-    # same ServingEngine, tier 'generate'; models/decoder.py).
+    # contexts model; DL_FRAMEWORK picks the framework of its equations),
+    # 'mellum' (a decoder-only token language model served through the
+    # same ServingEngine, tier 'generate'; models/decoder.py) or
+    # 'minicpm_sala' (linear-attention and block-sparse layers mixed, the
+    # same tier and loop; models/hybrid_decoder.py).
     MODEL_FAMILY: str = 'code2vec'
     # The decoder's published config.json (hidden sizes, layer_types,
     # rope_parameters ...). Weights are the program's own seeded init
@@ -383,6 +385,10 @@ class Config:
     # Layers of that stack this process holds, named as config.json names
     # it: a pipeline stage's cut (the first n layers). 0 = all of them.
     num_hidden_layers: int = 0
+    # ... and where that cut starts in config.json's list of layers (a
+    # middle stage; models/hybrid_decoder.py reads it, a mellum holds the
+    # first layers).
+    first_hidden_layer: int = 0
     LM_PARAM_SEED: int = 0
     # Sequences resident at once: decode rows of a step and slots of the
     # sliding layers' ring pool (serving/lm_cache.py).
@@ -1429,9 +1435,9 @@ class Config:
         if self.HANG_WATCHDOG_SECS < 0:
             raise ValueError('config.HANG_WATCHDOG_SECS must be >= 0 '
                              '(0 disables the watchdog).')
-        if self.MODEL_FAMILY not in {'code2vec', 'mellum'}:
+        if self.MODEL_FAMILY not in {'code2vec', 'mellum', 'minicpm_sala'}:
             raise ValueError("config.MODEL_FAMILY must be in "
-                             "{'code2vec', 'mellum'}.")
+                             "{'code2vec', 'mellum', 'minicpm_sala'}.")
         self.lm_chunk_buckets  # raises on malformed bucket specs
         if min(self.LM_MAX_SEQS, self.LM_PAGE_SIZE, self.LM_PAGE_POOL_PAGES,
                self.LM_MAX_CONTEXT, self.LM_WINDOW_SUBCHUNK) < 1:

@@ -13,6 +13,7 @@ load-or-create params.
 from __future__ import annotations
 
 import contextlib
+import importlib
 import json
 import os
 import time
@@ -931,11 +932,10 @@ class DecoderLMModel:
     """A decoder-only language model behind the same entry points: built
     from a published ``config.json`` (``LM_CONFIG_PATH``) with the
     program's own seeded weights, served by ``serving_engine()`` through
-    the ``generate`` tier (``models/decoder.py``, ``serving/
+    the ``generate`` tier (the family's module, ``serving/
     lm_scheduler.py``).  Serving only: it has no trainer."""
 
     def __init__(self, config: Config):
-        from code2vec_tpu.models import decoder as decoder_lib
         self.config = config
         self.log = config.log
         if not config.LM_CONFIG_PATH:
@@ -945,18 +945,25 @@ class DecoderLMModel:
             published = json.load(f)
         if config.num_hidden_layers:
             published['num_hidden_layers'] = config.num_hidden_layers
-        self.decoder_config = decoder_lib.DecoderConfig.from_dict(published)
+        if config.first_hidden_layer:
+            published['first_hidden_layer'] = config.first_hidden_layer
+        # the family's module: its configuration, weights and step program
+        from code2vec_tpu.models.families import family_of
+        lib = importlib.import_module(family_of(config).module)
+        self.decoder_config = lib.load_config(published)
+        self.lib = lib
         self.log('Creating decoder language model: %s'
-                 % decoder_lib.describe(self.decoder_config))
-        self.params = decoder_lib.init_params(self.decoder_config,
-                                              config.LM_PARAM_SEED)
+                 % lib.describe(self.decoder_config))
+        self.params = lib.init_params(self.decoder_config,
+                                      config.LM_PARAM_SEED)
 
     def serving_engine(self, warmup: bool = True, **overrides):
         """The same ``ServingEngine`` as code2vec's, its dispatcher running
         the decoder's step loop."""
         from code2vec_tpu.serving.engine import ServingEngine
         from code2vec_tpu.serving.lm_scheduler import LMRuntime
-        runtime = LMRuntime(self.config, self.decoder_config, self.params)
+        runtime = LMRuntime(self.config, self.decoder_config, self.params,
+                            self.lib)
         engine = ServingEngine(self.config, None, self.params, None,
                                decode_table=None, lm_runtime=runtime,
                                log=self.log, **overrides)

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from code2vec_tpu.ops import grouped_experts, lm_attention
+from code2vec_tpu.serving import lm_cache
 
 SLIDING = 'sliding_attention'
 FULL = 'full_attention'
@@ -275,16 +276,15 @@ def layer_pool_index(cfg: DecoderConfig) -> List[int]:
     return out
 
 
-def make_step(cfg: DecoderConfig, shape: StepShape, ring_pages: int,
-              pool_pages: int, dtype=jnp.bfloat16):
+def make_step(cfg: DecoderConfig, shape: StepShape,
+              geometry: lm_cache.CacheGeometry, dtype=jnp.bfloat16):
     """The step function for one shape (jit it with ``cache`` donated).
 
     ``step(params, cache, prev_ids, batch)`` ->
     ``(cache, next_ids [outputs], logits [outputs, vocab] float32,
     expert_counts [layers, experts])``.  ``cache`` is ``{'ring', 'pages'}``
     (``serving/lm_cache.py``), each ``[layers of the kind x pages of one
-    layer, page_size, 2 x kv_heads, head_dim]``, donated and returned;
-    ``ring_pages``/``pool_pages`` are the pages one layer owns of each.
+    layer, page_size, 2 x kv_heads, head_dim]``, donated and returned.
     ``prev_ids`` is the previous step's ``next_ids`` still on the device: a
     token whose ``token_src`` is not negative is read from there, so the
     host never waits for a sampled token before it plans the next step.
@@ -292,6 +292,9 @@ def make_step(cfg: DecoderConfig, shape: StepShape, ring_pages: int,
     """
     h, d = cfg.hidden_size, cfg.head_dim
     q_heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
+    # the pages one layer owns of each pool
+    ring_pages, pool_pages = (geometry.ring_layer_pages,
+                              geometry.pool_layer_pages)
     tables = {}
     for kind, rope in ((SLIDING, dict(cfg.rope_sliding)),
                        (FULL, dict(cfg.rope_full))):
@@ -383,25 +386,25 @@ def take_row(logits, row):
     return jax.lax.dynamic_index_in_dim(logits, row, axis=0, keepdims=False)
 
 
-def cache_shapes(cfg: DecoderConfig, ring_pages: int, pool_pages: int,
-                 page_size: int) -> Dict[str, tuple]:
-    """Shapes of the two pools: ``ring_pages``/``pool_pages`` are the pages
-    ONE layer owns of each (its last page takes the padding rows' writes).
-    """
+def cache_shapes(cfg: DecoderConfig, geometry: lm_cache.CacheGeometry
+                 ) -> Dict[str, tuple]:
+    """Shapes of the two pools: every layer of a kind owns
+    ``ring_layer_pages``/``pool_layer_pages`` pages of its kind's (the last
+    takes the padding rows' writes)."""
     kinds = cfg.layer_types
     combined = 2 * cfg.num_key_value_heads
+    g = geometry
     return {
-        'ring': (max(kinds.count(SLIDING), 1) * ring_pages, page_size,
-                 combined, cfg.head_dim),
-        'pages': (max(kinds.count(FULL), 1) * pool_pages, page_size,
-                  combined, cfg.head_dim)}
+        'ring': (max(kinds.count(SLIDING), 1) * g.ring_layer_pages,
+                 g.page_size, combined, cfg.head_dim),
+        'pages': (max(kinds.count(FULL), 1) * g.pool_layer_pages,
+                  g.page_size, combined, cfg.head_dim)}
 
 
-def zero_cache(cfg: DecoderConfig, ring_pages: int, pool_pages: int,
-               page_size: int, dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
-    shapes = cache_shapes(cfg, ring_pages, pool_pages, page_size)
+def zero_cache(cfg: DecoderConfig, geometry: lm_cache.CacheGeometry,
+               dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
     return {name: jnp.zeros(shape, dtype)
-            for name, shape in shapes.items()}
+            for name, shape in cache_shapes(cfg, geometry).items()}
 
 
 def describe(cfg: DecoderConfig) -> str:
@@ -414,3 +417,135 @@ def describe(cfg: DecoderConfig) -> str:
                cfg.num_key_value_heads, cfg.head_dim, cfg.num_experts,
                cfg.num_experts_per_tok, cfg.moe_intermediate_size,
                cfg.vocab_size, cfg.parameters() / 1e9))
+
+
+# ------------------------------------------ what the step loop asks of it
+# serving/lm_scheduler.py serves whichever model's module it is handed
+# through these names and `batch_shapes`, `make_step`, `zero_cache`,
+# `take_row` above, and knows nothing else of the model.
+load_config = DecoderConfig.from_dict
+#: the gauge a slot's share is read on: in this model a slot is a ring
+SLOT_GAUGE = 'serving/lm_ring_pool_fill'
+#: the counters of the model's own that ``log_counts`` feeds
+COUNTERS: Tuple[str, ...] = ()
+#: the name ``stats()`` gives the sum of every step's counts
+COUNTS_STAT = 'expert_tokens'
+
+
+def ring_window(cfg: DecoderConfig) -> int:
+    """Positions a slot's ring has to keep behind a query."""
+    return cfg.sliding_window
+
+
+def check_geometry(cfg: DecoderConfig,
+                   geometry: lm_cache.CacheGeometry) -> None:
+    """Any page size serves: keys and values lie a position a row."""
+
+
+def counts_shape(cfg: DecoderConfig) -> Tuple[int, int]:
+    return cfg.num_layers, cfg.num_experts
+
+
+def step_shape(cfg: DecoderConfig, geometry: lm_cache.CacheGeometry,
+               chunk: int, subchunk: int) -> StepShape:
+    """The shape of the step that carries ``chunk`` prompt tokens beside
+    the decode rows.  A chunk is one sequence to the full layers and one
+    every ``subchunk`` tokens to the sliding ones, each with a window's
+    worth of pages."""
+    g = geometry
+    return StepShape(
+        tokens=g.slots + chunk, chunk=chunk, outputs=g.slots + 1,
+        full_seqs=g.slots + (1 if chunk else 0), full_pages=g.pages_per_seq,
+        window_seqs=g.slots + lm_cache.ceil_div(chunk, subchunk),
+        window_pages=g.window_table_pages(
+            min(subchunk, chunk) if chunk else 1))
+
+
+def program_name(shape: StepShape) -> str:
+    """The name its jitted program goes by in a trace."""
+    return 'run'
+
+
+def pad_rows(cfg: DecoderConfig, geometry: lm_cache.CacheGeometry,
+             views: dict) -> None:
+    """A step's inputs of this model's own with no sequence in them: the
+    ring's writes go to the spare page."""
+    g = geometry
+    views['window_rows'][:] = g.slots * g.ring_pages * g.page_size
+
+
+class StepPlan:
+    """The host's side of one step: the metadata of the two attention
+    calls, filled by the step loop a decode row at a time, then the chunk.
+    The loop itself fills what every model's step has (tokens, positions,
+    output rows, the page pool's rows and table)."""
+
+    def __init__(self, cfg: DecoderConfig, geometry: lm_cache.CacheGeometry,
+                 views: dict, subchunk: int):
+        self.g, self.subchunk = geometry, subchunk
+        self.full_lens = views['full_kv_lens']
+        self.full_cu = views['full_cu_q_lens']
+        self.full_num = views['full_num_seqs']
+        self.window_rows = views['window_rows']
+        self.window_lens = views['window_kv_lens']
+        self.window_table = views['window_page_indices']
+        self.window_cu = views['window_cu_q_lens']
+        self.window_num = views['window_num_seqs']
+        self.width = self.window_table.shape[1]
+        self.full_seqs = self.window_seqs = 0
+
+    def decode_row(self, row: int, lease: lm_cache.Lease, at: int) -> None:
+        g = self.g
+        self.full_lens[row] = at + 1
+        self.window_rows[row] = lm_cache.ring_rows(g, lease.slot, at)
+        self.window_lens[row], self.window_table[row] = \
+            lm_cache.window_view(g, lease.slot, at, 1, self.width)
+
+    def end_decode(self, n: int) -> int:
+        """The ``n`` decode rows are in.  Returns the row of the batch, and
+        of the page table, the chunk starts at: right behind them."""
+        self.full_cu[:n + 1] = np.arange(n + 1)
+        self.window_cu[:n + 1] = np.arange(n + 1)
+        self.full_seqs = self.window_seqs = n
+        return n
+
+    def chunk(self, n: int, lease: lm_cache.Lease, first: int,
+              taken: int) -> None:
+        """``taken`` prompt tokens at positions ``first ..`` of the
+        sequence that holds ``lease``, behind ``n`` decode rows."""
+        g = self.g
+        self.full_lens[n] = first + taken
+        self.full_cu[n + 1] = n + taken
+        self.full_seqs = n + 1
+        self.window_rows[n:n + taken] = lm_cache.ring_rows(
+            g, lease.slot, np.arange(first, first + taken))
+        for begin in range(0, taken, self.subchunk):
+            q_len = min(self.subchunk, taken - begin)
+            seq = self.window_seqs
+            self.window_lens[seq], self.window_table[seq] = \
+                lm_cache.window_view(g, lease.slot, first + begin, q_len,
+                                     self.width)
+            self.window_cu[seq + 1] = n + begin + q_len
+            self.window_seqs += 1
+
+    def close(self) -> dict:
+        """The step is whole.  Returns what ``log_counts`` is to know of
+        the plan (nothing here)."""
+        self.full_cu[self.full_seqs + 1:] = self.full_cu[self.full_seqs]
+        self.full_num[0] = self.full_seqs
+        self.window_cu[self.window_seqs + 1:] = \
+            self.window_cu[self.window_seqs]
+        self.window_num[0] = self.window_seqs
+        return {}
+
+
+def log_counts(counts: np.ndarray, note: dict) -> Tuple[dict, dict]:
+    """(what the step log keeps of a step's ``counts`` [layers, experts],
+    {counter: its increment})."""
+    return {'experts_touched': (counts > 0).sum(axis=1)}, {}
+
+
+def step_gauges(counts: np.ndarray) -> Dict[str, float]:
+    """{gauge: value} of a step's counts, where telemetry is on."""
+    per_layer = counts.max(axis=1) / np.maximum(counts.mean(axis=1), 1e-9)
+    return {'serving/lm_expert_load_max_over_mean': float(per_layer.mean())}

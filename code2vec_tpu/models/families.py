@@ -12,6 +12,10 @@ serving path has to know without knowing the model:
 - ``param_specs``: how its parameter pytree lies on a device mesh;
 - ``reference``: the plain float32 statement of its equations that tests
   and the benchmark's check compare with;
+- ``module``: the module that holds its equations; for a language model
+  also the seam the step loop serves it through (its configuration,
+  weights, step program and the host's side of a step:
+  ``serving/lm_scheduler.py``);
 - ``build(config)``: the model behind ``model_api.create_model``, with
   ``serving_engine()`` and ``close_stores()``.
 """
@@ -26,6 +30,7 @@ class ModelFamily(NamedTuple):
     tiers: Tuple[str, ...]
     param_specs: Callable
     reference: str
+    module: str
     build: Callable
 
 
@@ -62,6 +67,7 @@ FAMILIES = {
         tiers=('topk', 'attention', 'full', 'vectors'),
         param_specs=_code2vec_param_specs,
         reference='chipbench/reference.py',
+        module='code2vec_tpu.models.functional',
         build=_build_code2vec),
     'mellum': ModelFamily(
         name='mellum',
@@ -71,6 +77,19 @@ FAMILIES = {
         tiers=('generate',),
         param_specs=_decoder_param_specs,
         reference='chipbench/reference_mellum2.py',
+        module='code2vec_tpu.models.decoder',
+        build=_build_decoder),
+    'minicpm_sala': ModelFamily(
+        name='minicpm_sala',
+        input_layout='a prompt of token ids, max_new_tokens and optionally '
+                     'a session whose cache stays resident between turns; '
+                     'a step is a flat batch of tokens with per-sequence '
+                     'page tables and state slots '
+                     '(models/hybrid_decoder.py::batch_shapes)',
+        tiers=('generate',),
+        param_specs=_decoder_param_specs,
+        reference='chipbench/reference_minicpm_sala.py',
+        module='code2vec_tpu.models.hybrid_decoder',
         build=_build_decoder),
 }
 

@@ -919,21 +919,18 @@ class ServingEngine:
         return sum(self._pending_rows.values()) + self._reserved_rows
 
     def _admit(self, rows: int, tier: str,
-               deadline_s: Optional[float], context: int = 0) -> str:
+               deadline_s: Optional[float], generate=None) -> str:
         """Admission control for one submission: bound check, drain
         estimate vs deadline, degradation ladder. Reserves ``rows``
         against the bound (released on enqueue or failure) and returns
-        the EFFECTIVE tier to serve.  A ``generate`` request states its
-        ``context`` (positions of cache it will hold): the cache manager
-        is asked whether that can ever be resident; when it can is the
-        step loop's question (serving/lm_scheduler.py)."""
+        the EFFECTIVE tier to serve.  A ``generate`` request is booked
+        with the step loop (serving/lm_scheduler.py), which refuses a
+        context that could never be resident (for a session's turn,
+        counted from the session's end); when it can be is the loop's
+        question."""
         with self._cond:
             if self._closed:
                 raise EngineClosed('ServingEngine is closed')
-            if context and not self._lm.cache.fits_ever(context):
-                raise ValueError(
-                    'a context of %d positions can never be admitted to '
-                    'this engine\'s cache' % context)
             if faults.maybe_fire('reject_all'):
                 self._shed_locked(rows, 'reject_all drill')
             admitted = self._admitted_rows_locked()
@@ -960,6 +957,8 @@ class ServingEngine:
                 if tele_core.enabled():
                     self._mirror.counter(
                         'serving/degraded_total').inc()
+            if generate is not None:
+                self._lm.reserve_locked(generate)
             self._reserved_rows += rows
             self._peak_rows = max(self._peak_rows,
                                   self._admitted_rows_locked())
@@ -973,7 +972,8 @@ class ServingEngine:
                tier: str = 'topk',
                deadline_ms: Optional[float] = None,
                max_new_tokens: int = 1,
-               return_logits: bool = False) -> Future:
+               return_logits: bool = False,
+               session=None) -> Future:
         """Enqueue one prediction request (raw extractor/``.c2v`` context
         lines, like ``model.predict``). Returns a Future resolving to
         one ``ModelPredictionResults`` per line, in order. Requests
@@ -989,7 +989,11 @@ class ServingEngine:
                 'submit through its ServingMesh (serving/mesh.py)')
         if self._lm is not None:
             return self._submit_generate(context_lines, tier,
-                                         max_new_tokens, return_logits)
+                                         max_new_tokens, return_logits,
+                                         session)
+        if session is not None:
+            raise ValueError('sessions belong to the generate tier of a '
+                             'language model')
         if tier not in self.tiers:
             raise ValueError('tier %r is not warmed on this engine '
                              '(tiers=%s)' % (tier, list(self.tiers)))
@@ -1091,11 +1095,15 @@ class ServingEngine:
         return future
 
     def _submit_generate(self, prompt_ids, tier: str, max_new_tokens: int,
-                         return_logits: bool) -> Future:
+                         return_logits: bool, session=None) -> Future:
         """``submit`` of a language model's engine: ``prompt_ids`` (token
         ids) in, a Future of a ``GenerationResult`` out: ``max_new_tokens``
         greedy ids, always run to their end, and with ``return_logits``
-        the float32 logits each was picked from."""
+        the float32 logits each was picked from.  With ``session`` (any
+        hashable id) the request is a turn of that session: its cache stays
+        resident at delivery, and the turn starts at the session's end
+        (the token the earlier turn generated last, then ``prompt_ids``),
+        not at position 0; ``close_session`` returns the cache."""
         from code2vec_tpu.serving.lm_scheduler import GenerateRequest
         if tier not in self.tiers:
             raise ValueError('tier %r is not served by this engine '
@@ -1109,8 +1117,8 @@ class ServingEngine:
         if tele_core.enabled():
             self._mirror.counter('serving/requests_total').inc()
         request = GenerateRequest(prompt, int(max_new_tokens),
-                                  bool(return_logits))
-        self._admit(1, tier, None, context=request.context)
+                                  bool(return_logits), session)
+        self._admit(1, tier, None, generate=request)
         with self._cond:
             self._reserved_rows -= 1
             if self._closed:
@@ -1120,6 +1128,16 @@ class ServingEngine:
             self._set_queue_depth_locked()
             self._cond.notify_all()
         return request.future
+
+    def close_session(self, session) -> bool:
+        """Returns a resident session's cache (pages, pooled keys,
+        recurrent states) to the pools; False if the engine holds no such
+        session.  Raises while a turn of it is submitted and undelivered.
+        Closing a session whose turns fail with ``SessionLost`` (True)
+        acknowledges the loss: its id can be used anew."""
+        if self._lm is None:
+            raise ValueError('sessions belong to a language model\'s engine')
+        return self._lm.close_session(session)
 
     def lm_runtime(self):
         """A language model's runtime (``serving/lm_scheduler.py::
@@ -1131,6 +1149,11 @@ class ServingEngine:
         """What every finished step of a language model carried
         (``LMScheduler.step_log``); empty for code2vec."""
         return self._lm.step_log() if self._lm is not None else []
+
+    def lm_request_log(self) -> list:
+        """When every delivered request of a language model passed each
+        stage (``LMScheduler.request_log``); empty for code2vec."""
+        return self._lm.request_log() if self._lm is not None else []
 
     def predict(self, context_lines: Sequence[str], tier: str = 'topk',
                 timeout: Optional[float] = None) -> list:

@@ -42,6 +42,16 @@ class DeadlineExceeded(ServingError):
     client has already given up on."""
 
 
+class SessionLost(ServingError):
+    """A language model's resident session lost its cache: a step failed,
+    the pools were reset, and what the session's earlier turns left on the
+    device went with them.  The turns of it that stood in the queue fail
+    with this, and so does every later ``submit(session=...)`` until the
+    caller acknowledges the loss with ``close_session`` and sends the
+    history again: a turn served from position 0 would be a normal-looking
+    answer computed without the context the session promises."""
+
+
 class ReplicaDead(ServingError):
     """The mesh replica holding this request died (worker exit, wire
     corruption, or heartbeat-declared liveness failure) and the request

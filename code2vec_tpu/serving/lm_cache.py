@@ -1,6 +1,6 @@
-"""The key/value cache of a decoder with sliding-window and full layers
-mixed: two pools, kept on the host as bookkeeping and on the device as two
-arrays (``models/decoder.py::zero_cache``).
+"""The cache of a decoder whose layers keep different kinds of state: pools
+kept on the host as bookkeeping and on the device as arrays
+(``models/decoder.py::zero_cache``, ``models/hybrid_decoder.py::zero_cache``).
 
 - The **ring pool** serves the sliding layers.  A resident sequence owns
   one slot: a fixed run of ``ring_pages`` pages in every sliding layer,
@@ -12,10 +12,21 @@ arrays (``models/decoder.py::zero_cache``).
   context needs them; a page is ``page_size`` positions in every full layer
   (the same page number in each layer's slab).
 
+- The **state pool** serves linear-attention layers.  A resident sequence
+  owns the same slot there: one fixed-size float32 recurrent state in every
+  such layer, whatever the context's length.  A block-sparse layer's pooled
+  keys (one row every ``kernel_stride`` positions) live page for page beside
+  its pages and need no bookkeeping of their own.
+
 Held as one kind of cache, every layer would pay the full layers' price.
 A request is admitted only when a slot is free and the page pool can hold
 its whole context (prompt and every token it will generate), so a running
 sequence never waits for memory; both are returned at delivery.
+
+A request may name a **session**: its lease is then kept at delivery
+(``keep``) and the session's next turn is admitted by adding only the pages
+its new positions need (``extend``); ``close_session`` returns the lease.
+No sharing between sessions, no snapshots, no eviction.
 
 Thread-safety: the engine calls everything here under its own lock.
 """
@@ -73,9 +84,9 @@ class CacheGeometry:
 
 @dataclasses.dataclass
 class Lease:
-    """What one resident sequence holds of the two pools."""
-    slot: int
-    pages: np.ndarray       # int32, the full layers' pages in order
+    """What one resident sequence holds of the pools."""
+    slot: int               # its ring slot and its state slot alike
+    pages: np.ndarray       # int32, the paged layers' pages in order
 
 
 class CacheManager:
@@ -86,6 +97,7 @@ class CacheManager:
         self._free_slots: List[int] = list(range(geometry.slots))[::-1]
         self._free_pages: List[int] = list(range(geometry.pool_pages))[::-1]
         self.held_total = 0     # admissions that found no room
+        self._kept: dict = {}   # session id -> its resident lease
 
     # ------------------------------------------------------------ asking
     def pages_for(self, tokens: int) -> int:
@@ -115,6 +127,39 @@ class CacheManager:
         self._free_pages.extend(int(p) for p in lease.pages[::-1])
         lease.pages = np.zeros((0,), np.int32)
 
+    # ---------------------------------------------------------- sessions
+    def extend(self, lease: Lease, tokens: int) -> bool:
+        """Grows a kept lease to hold ``tokens`` positions in all; False
+        (counted) where the page pool lacks the pages it would add."""
+        need = self.pages_for(tokens) - int(lease.pages.shape[0])
+        if need > len(self._free_pages):
+            self.held_total += 1
+            return False
+        if need > 0:
+            more = [self._free_pages.pop() for _ in range(need)]
+            lease.pages = np.concatenate(
+                [lease.pages, np.asarray(more, np.int32)])
+        return True
+
+    def keep(self, session, lease: Lease) -> None:
+        """``lease`` outlives its request under ``session``."""
+        self._kept[session] = lease
+
+    def kept(self, session) -> Optional[Lease]:
+        return self._kept.get(session)
+
+    def close_session(self, session) -> bool:
+        """Returns a session's lease to the pools; False if it holds none."""
+        lease = self._kept.pop(session, None)
+        if lease is None:
+            return False
+        self.free(lease)
+        return True
+
+    def close_all_sessions(self) -> None:
+        for session in list(self._kept):
+            self.close_session(session)
+
     # ----------------------------------------------------------- gauges
     @property
     def slots_in_use(self) -> int:
@@ -124,8 +169,12 @@ class CacheManager:
     def pages_in_use(self) -> int:
         return self.geometry.pool_pages - len(self._free_pages)
 
+    @property
+    def sessions_kept(self) -> int:
+        return len(self._kept)
+
     def fill(self) -> Tuple[float, float]:
-        """(ring pool, page pool) shares in use."""
+        """(slots: ring and state pool alike, page pool) shares in use."""
         g = self.geometry
         return (self.slots_in_use / g.slots,
                 self.pages_in_use / max(g.pool_pages, 1))
@@ -137,6 +186,21 @@ def full_rows(g: CacheGeometry, lease: Lease, positions):
     one position) in a full layer's slab."""
     return lease.pages[positions // g.page_size] * g.page_size \
         + positions % g.page_size
+
+
+def completed_strides(g: CacheGeometry, lease: Lease, first: int, count: int,
+                      stride: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The strides of ``stride`` positions whose last position lies in
+    ``first .. first + count - 1``: (flat row in a layer's page slab where
+    each starts, flat row in its pooled slab where its mean goes).  A page
+    holds whole strides, so both follow from the stride's page."""
+    ends = np.arange((first + stride) // stride * stride - 1, first + count,
+                     stride)
+    starts = ends - (stride - 1)
+    page = lease.pages[starts // g.page_size]
+    per_page = g.page_size // stride
+    return (page * g.page_size + starts % g.page_size,
+            page * per_page + (starts % g.page_size) // stride)
 
 
 def ring_rows(g: CacheGeometry, slot: int, positions):

@@ -188,6 +188,28 @@ CATALOG: Dict[str, Dict[str, str]] = {
     'serving/lm_expert_load_max_over_mean': _m(
         GAUGE, 'ratio', 'Tokens of the busiest expert over the mean '
         'expert, in the last step, mean over the layers (1 = even).'),
+    'serving/lm_state_pool_fill': _m(
+        GAUGE, 'fraction', 'State slots in use / slots: one float32 '
+        'recurrent state a slot and linear-attention layer.'),
+    'serving/lm_sessions_resident': _m(
+        GAUGE, 'sessions', 'Sessions whose lease (slot, pages) stays '
+        'between turns.'),
+    'serving/lm_resident_positions_total': _m(
+        COUNTER, 'positions', 'Context positions a session\'s turn found '
+        'in its resident lease at admission.'),
+    'serving/lm_prefilled_positions_total': _m(
+        COUNTER, 'positions', 'Prompt positions admitted for prefill.'),
+    'serving/lm_session_wait_ms': _m(
+        TIMER, 'ms', 'How long a session\'s turn stood in the queue behind '
+        'its own session\'s earlier turn.'),
+    'serving/lm_sparse_blocks_chosen_total': _m(
+        COUNTER, 'blocks', 'Blocks the block-sparse layers\' queries '
+        'chose, over queries, key/value heads and layers.'),
+    'serving/lm_sparse_blocks_visible_total': _m(
+        COUNTER, 'blocks', 'Blocks those queries could have read.'),
+    'serving/lm_sparse_dense_branch_total': _m(
+        COUNTER, 'calls', 'Sparse-layer calls that took the dense branch '
+        '(a token within dense_len, a layer).'),
     # ---- serving resilience (admission control / rollover / breaker) ----
     'serving/shed_total': _m(COUNTER, 'requests', 'Requests rejected at '
                              'admission (queue bound, drain-estimate vs '

@@ -142,6 +142,11 @@ SPAN_CATALOG: Dict[str, str] = {
                              'set (the cache manager had a ring slot '
                              'and pages for it).  Stats: waited_ms, '
                              'prompt.',
+    'serving.lm_session_wait': 'Language model, profiler event only: a '
+                               'session\'s turn left the queue.  Stats: '
+                               'waited_ms (behind its own session\'s '
+                               'earlier turn), resident (positions found '
+                               'in the session\'s lease).',
     'serving.shed': 'Terminal: shed at admission with EngineOverloaded '
                     '(attrs carry the reason).',
     'serving.expired': 'Terminal: SLO deadline passed while queued '

@@ -75,18 +75,34 @@ def write_corpus(tmp_path, methods=16, tags=12):
     return prefix, lines
 
 
-def language_model_agrees_at_toy_width(tmp_path, name):
+def language_model_pieces(family):
+    """(the runner that names the model's config.json keys and maps the
+    program's weights to the reference's, the reference) of a language
+    model's family."""
+    if family == 'mellum':
+        from chipbench import reference_mellum2
+        from chipbench.runners import serve_lm
+        return serve_lm, reference_mellum2
+    if family == 'minicpm_sala':
+        from chipbench import reference_minicpm_sala
+        from chipbench.runners import serve_lm_sessions
+        return serve_lm_sessions, reference_minicpm_sala
+    raise AssertionError('no reference is known for family %r' % family)
+
+
+def language_model_agrees_at_toy_width(tmp_path, name, family):
     """A language model's configuration at its own ``rehearsal`` widths, in
-    float32: a prompt longer than the ring through chunked prefill and
-    decode, against ``chipbench/reference_mellum2.py``."""
-    from chipbench import reference_mellum2, run
-    from chipbench.runners import serve_lm
+    float32: a prompt longer than a ring and than ``dense_len``, through
+    chunked prefill and decode (every layer kind the widths hold, the
+    sparse branch where there is one), against the family's reference."""
+    from chipbench import run
     from code2vec_tpu import model_api
+    runner, reference = language_model_pieces(family)
     with open(os.path.join(REPO, 'chipbench', 'configs',
                            name + '.json')) as f:
         spec = json.load(f)
     spec = run.merged(spec, spec['rehearsal'])
-    model_config = {k: spec[k] for k in serve_lm.MODEL_KEYS if k in spec}
+    model_config = {k: spec[k] for k in runner.MODEL_KEYS if k in spec}
     path = tmp_path / 'config.json'
     path.write_text(json.dumps(model_config))
     ctx = types.SimpleNamespace(
@@ -99,8 +115,12 @@ def language_model_agrees_at_toy_width(tmp_path, name):
     with model.serving_engine() as engine:
         result = engine.submit(prompt, tier='generate', max_new_tokens=6,
                                return_logits=True).result(timeout=300)
-    wanted = np.asarray(reference_mellum2.forward(
-        model_config, serve_lm.reference_weights(model.params, model_config),
+        if family == 'minicpm_sala':
+            lm = engine.stats()['lm']
+            assert lm['sparse_blocks_chosen_total'] > 0   # the sparse branch
+            assert lm['sparse_dense_branch_total'] > 0    # and the dense one
+    wanted = np.asarray(reference.forward(
+        model_config, runner.reference_weights(model.params, model_config),
         np.concatenate([prompt, result.token_ids[:-1]]),
         first_logit=len(prompt) - 1))
     np.testing.assert_allclose(
@@ -110,9 +130,9 @@ def language_model_agrees_at_toy_width(tmp_path, name):
 
 @pytest.mark.parametrize('name', CONFIG_NAMES)
 def test_program_agrees_with_reference_at_toy_width(tmp_path, name):
-    if context_for(name).settings.get('MODEL_FAMILY',
-                                      'code2vec') != 'code2vec':
-        return language_model_agrees_at_toy_width(tmp_path, name)
+    family = context_for(name).settings.get('MODEL_FAMILY', 'code2vec')
+    if family != 'code2vec':
+        return language_model_agrees_at_toy_width(tmp_path, name, family)
     prefix, lines = write_corpus(tmp_path)
     ctx = context_for(name, **TOY)
     devices = (ctx.settings['MESH_DATA_AXIS_SIZE']

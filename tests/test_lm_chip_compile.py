@@ -106,13 +106,13 @@ def test_a_period_of_the_decode_step_compiles_and_updates_in_place(
     g = lm_cache.CacheGeometry.make(page_size=128, window=1024, slots=16,
                                     pool_pages=1706, max_context=24832,
                                     max_chunk=2048)
-    shape = decoder_lib.StepShape(
+    shape = decoder_lib.step_shape(cfg, g, 0, 512)
+    assert shape == decoder_lib.StepShape(
         tokens=16, chunk=0, outputs=17, full_seqs=16,
         full_pages=g.pages_per_seq, window_seqs=16,
         window_pages=g.window_table_pages(1))
-    step = decoder_lib.make_step(cfg, shape, g.ring_layer_pages,
-                                 g.pool_layer_pages)
-    layout = lm_scheduler.pack_layout(shape)
+    step = decoder_lib.make_step(cfg, shape, g)
+    layout = lm_scheduler.pack_layout(decoder_lib.batch_shapes(shape))
 
     def run(params, cache, prev_ids, packed):
         return step(params, cache, prev_ids,
@@ -121,8 +121,7 @@ def test_a_period_of_the_decode_step_compiles_and_updates_in_place(
         lambda s: shaped(one_chip, s.shape, s.dtype),
         decoder_lib.param_shapes(cfg))
     cache = {name: shaped(one_chip, dims, jnp.bfloat16)
-             for name, dims in decoder_lib.cache_shapes(
-                 cfg, g.ring_layer_pages, g.pool_layer_pages, 128).items()}
+             for name, dims in decoder_lib.cache_shapes(cfg, g).items()}
     compiled = jax.jit(run, donate_argnums=(1,)).lower(
         params, cache, shaped(one_chip, (17,), jnp.int32),
         shaped(one_chip, (layout[''][0],), jnp.int32)).compile()
@@ -131,3 +130,139 @@ def test_a_period_of_the_decode_step_compiles_and_updates_in_place(
     assert memory.alias_size_in_bytes >= pools
     assert memory.temp_size_in_bytes < 256 * 2 ** 20
     assert compiled.as_text().count('tpu_custom_call') == 3 * 4
+
+
+# ---------------------------------------------------------------------------
+# the linear-attention and block-sparse layers (models/hybrid_decoder.py)
+from code2vec_tpu.models import hybrid_decoder as hybrid_lib  # noqa: E402
+from code2vec_tpu.ops import linear_attention, sparse_attention  # noqa: E402
+
+SALA = {
+    'attention_bias': False, 'attn_use_rope': False, 'head_dim': 128,
+    'hidden_act': 'silu', 'hidden_size': 4096, 'intermediate_size': 16384,
+    'lightning_head_dim': 128, 'lightning_nh': 32, 'lightning_nkv': 32,
+    'lightning_scale': '1/sqrt(d)', 'lightning_use_rope': True,
+    'mixer_types': (['minicpm4'] + ['lightning-attn'] * 8 + ['minicpm4']
+                    + ['lightning-attn'] * 6 + ['minicpm4'] * 2
+                    + ['lightning-attn'] * 4 + ['minicpm4']
+                    + ['lightning-attn'] * 6 + ['minicpm4'] * 3),
+    'num_attention_heads': 32, 'num_key_value_heads': 2, 'qk_norm': True,
+    'rms_norm_eps': 1e-6, 'vocab_size': 73448, 'rope_theta': 10000,
+    'scale_emb': 12, 'scale_depth': 1.4, 'dim_model_base': 256,
+    'tie_word_embeddings': False, 'use_output_gate': True,
+    'use_output_norm': True, 'attn_use_output_gate': True,
+    # one period: a sparse layer and the three lightning layers after it
+    'first_hidden_layer': 9, 'num_hidden_layers': 4}
+SALA_GEO = sparse_attention.SparseGeometry(**hybrid_lib.SPARSE_DEFAULTS)
+#: the cell's pools: 8 sessions, 5,600 pages of 128, contexts up to 152K
+SALA_SLOTS, SALA_POOL_PAGES, SALA_SEQ_PAGES = 8, 5600, 1216
+
+
+def test_the_published_depth_counts_its_parameters():
+    cfg = hybrid_lib.HybridConfig.from_dict(
+        dict(SALA, first_hidden_layer=0, num_hidden_layers=32))
+    assert cfg.mixer_types.count('lightning-attn') == 24
+    assert 9.47e9 < cfg.parameters() < 9.49e9
+    cut = hybrid_lib.HybridConfig.from_dict(
+        dict(SALA, first_hidden_layer=9, num_hidden_layers=16))
+    assert cut.mixer_types.count('minicpm4') == 4
+    assert 5.038e9 < cut.parameters() < 5.041e9
+
+
+@pytest.mark.parametrize('tokens', [2048, 256], ids=['chunk', 'short-chunk'])
+def test_chunked_scan_compiles_at_published_widths(one_chip, tokens):
+    compiled = jax.jit(linear_attention.chunk_scan).lower(
+        shaped(one_chip, (tokens, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (tokens, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (tokens, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (32,), jnp.float32),
+        shaped(one_chip, (tokens,), jnp.int32),
+        shaped(one_chip, (32, 128, 128), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_decode_update_compiles_at_published_widths(one_chip):
+    compiled = jax.jit(linear_attention.decode_update).lower(
+        shaped(one_chip, (8, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (8, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (8, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (32,), jnp.float32),
+        shaped(one_chip, (8,), jnp.int32),
+        shaped(one_chip, (8, 32, 128, 128), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_selection_compiles_at_published_widths(one_chip):
+    """Stage 1 and the exact choice for a tile of 32 queries against the
+    stride rows of a 152K context."""
+    def select(q, positions, means):
+        scores = sparse_attention.block_scores(q, positions, means,
+                                               SALA_GEO)
+        return sparse_attention.choose(scores, SALA_GEO.topk)
+    compiled = jax.jit(select).lower(
+        shaped(one_chip, (32, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (32,), jnp.int32),
+        shaped(one_chip, (SALA_SEQ_PAGES * 8, 2, 128),
+               jnp.bfloat16)).compile()
+    assert 'sort(' not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize('tokens', [2048, 1], ids=['chunk', 'decode-row'])
+def test_block_sparse_attention_compiles_at_published_widths(one_chip,
+                                                             tokens):
+    """Both stages over the cell's pool; the pool is read where it lies:
+    no copy of it in another layout."""
+    pool = (2, (SALA_POOL_PAGES + 1) * 2, 2, 64, 128)
+
+    def attend(q, positions, live, means, table, pages):
+        return sparse_attention.sparse_attention_chunk(
+            q, positions, live, means, table, pages, SALA_GEO, 128)
+    compiled = jax.jit(attend).lower(
+        shaped(one_chip, (tokens, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (tokens,), jnp.int32),
+        shaped(one_chip, (tokens,), jnp.int32),
+        shaped(one_chip, (SALA_SEQ_PAGES * 8, 2, 128), jnp.bfloat16),
+        shaped(one_chip, (SALA_SEQ_PAGES,), jnp.int32),
+        shaped(one_chip, pool, jnp.bfloat16)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < int(np.prod(pool)) * 2 // 2
+
+
+@pytest.mark.parametrize('chunk', [2048, 0], ids=['chunk', 'decode-only'])
+def test_a_period_of_the_hybrid_step_compiles_and_fits(one_chip, chunk):
+    """A sparse layer and three lightning layers, a 2,048 chunk beside 8
+    decode rows (or the rows alone) at the cell's pool sizes: every pool is
+    donated and aliased, no step copies or re-lays one, and the
+    temporaries leave the chip room for sixteen layers' weights."""
+    cfg = hybrid_lib.HybridConfig.from_dict(SALA)
+    g = lm_cache.CacheGeometry.make(
+        page_size=128, window=0, slots=SALA_SLOTS,
+        pool_pages=SALA_POOL_PAGES, max_context=SALA_SEQ_PAGES * 128,
+        max_chunk=2048)
+    shape = hybrid_lib.step_shape(cfg, g, chunk, 512)
+    assert shape == hybrid_lib.StepShape(
+        tokens=SALA_SLOTS + chunk, chunk=chunk, outputs=SALA_SLOTS + 1,
+        slots=SALA_SLOTS, full_seqs=SALA_SLOTS + (1 if chunk else 0),
+        full_pages=g.pages_per_seq,
+        strides=SALA_SLOTS + (chunk // 16 + 1 if chunk else 0))
+    step = hybrid_lib.make_step(cfg, shape, g)
+    layout = lm_scheduler.pack_layout(hybrid_lib.batch_shapes(shape))
+
+    def run(params, cache, prev_ids, packed):
+        return step(params, cache, prev_ids,
+                    lm_scheduler.unpack_batch(packed, layout))
+    params = jax.tree_util.tree_map(
+        lambda s: shaped(one_chip, s.shape, s.dtype),
+        hybrid_lib.param_shapes(cfg))
+    dtypes = hybrid_lib.cache_dtypes(jnp.bfloat16)
+    cache = {name: shaped(one_chip, dims, dtypes[name])
+             for name, dims in hybrid_lib.cache_shapes(cfg, g).items()}
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, shaped(one_chip, (SALA_SLOTS + 1,), jnp.int32),
+        shaped(one_chip, (layout[''][0],), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    pools = sum(int(np.prod(c.shape)) * np.dtype(c.dtype).itemsize
+                for c in cache.values())
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.temp_size_in_bytes < (640 if chunk else 64) * 2 ** 20

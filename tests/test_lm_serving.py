@@ -208,6 +208,36 @@ def test_neighbours_joining_and_leaving_change_nothing(exact):
                                program_logits(beside), atol=1e-5)
 
 
+def test_a_session_keeps_ring_and_pages_between_turns(exact):
+    """A request may name a session on this model too: the second turn
+    starts at the first's end (ring slot and pages kept), feeds the token
+    the first generated last, and equals one request over the joined
+    history; closing the session returns both pools."""
+    model, engine, config = exact
+    rng = np.random.default_rng(21)
+    first, second = rng.integers(0, 64, 23), rng.integers(0, 64, 10)
+    one = engine.submit(first, tier='generate', max_new_tokens=5,
+                        return_logits=True, session='s').result(timeout=120)
+    two = engine.submit(second, tier='generate', max_new_tokens=6,
+                        return_logits=True, session='s').result(timeout=120)
+    lm = engine.stats()['lm']
+    assert lm['ring_pool_fill'] > 0 and lm['sessions_resident'] == 1
+    history = np.concatenate([first, one.token_ids, second])
+    weights = serve_lm.reference_weights(model.params, config)
+    want = np.asarray(ref.forward(
+        config, weights, np.concatenate([history, two.token_ids[:-1]]),
+        first_logit=len(history) - 1))
+    np.testing.assert_allclose(program_logits(two), want, atol=1e-4)
+    assert engine.close_session('s')
+    whole = engine.submit(history, tier='generate', max_new_tokens=6,
+                          return_logits=True).result(timeout=120)
+    np.testing.assert_allclose(program_logits(whole), program_logits(two),
+                               atol=1e-5)
+    lm = engine.stats()['lm']
+    assert lm['ring_pool_fill'] == 0.0 and lm['page_pool_fill'] == 0.0
+    assert lm['state_pool_fill'] == 0.0
+
+
 def test_bfloat16_holds_the_written_two_part_tolerance(tmp_path_factory):
     """The mode the chip runs: products in bfloat16.  Most positions are
     within the bound; a position where a near-tie picked another expert
