@@ -17,28 +17,35 @@ contiguous stream of ``(source, path, target)`` int32 triples:
   label   (B,) int32
   weight  (B,) float32
 
-A TRAINING stream (a packer built with ``table_rows``) also names, per
-data shard, the embedding rows the shard's slots touch, so the step can
-build and reduce the two tables' gradients over those rows and not over
-the tables (ops/pallas_ragged.py ``_rows_table_grad``), on one shard as
-on several:
+A TRAINING stream (a packer built with ``table_rows``) also names the
+embedding rows the STEP's slots touch, ONE set a table whatever the number
+of data shards, so the step can build and reduce the two tables' gradients
+over those rows and not over the tables (ops/pallas_ragged.py
+``_rows_table_grad``): every shard sums its slots into the same row space,
+the shards' sums are added, and one scatter writes the table's gradient:
 
-  tok_rows  (data_shards, U_tok)  int32 — the shard's distinct token rows
-            (source and target slots together), ascending, its PAD row
-            among them
-  path_rows (data_shards, U_path) int32 — the same for the path rows
+  tok_rows  (data_shards, U_tok / data_shards) int32 — the distinct token
+            rows of all shards together (source and target slots),
+            ascending, the PAD row among them, cut into ``data_shards``
+            equal runs so that the set ships over ``data`` like every
+            other array: ``tok_rows.reshape(-1)`` is the set
+  path_rows (data_shards, U_path / data_shards) int32 — the same for the
+            path rows
   inv       (data_shards, capacity, 3) int32 — for every slot of ``ctx``
-            the position of its row in ``tok_rows[s]`` (columns 0, 2) or
-            ``path_rows[s]`` (column 1): ``rows[s][inv[s]] == ctx[s]`` on
-            every slot, the tail padding included
+            the position of its row in the token set (columns 0, 2) or
+            the path set (column 1): ``rows.reshape(-1)[inv[s]] ==
+            ctx[s]`` on every slot of every shard, the tail padding
+            included
 
-Past a shard's distinct rows each row array goes on with distinct
-ascending ids BEYOND the table's last row (``rows_in_table + k``), so the
-whole array is sorted and unique and a ``mode='drop'`` scatter discards
-the padding. ``U_tok``/``U_path`` are sticky and bucketed like
-``capacity``. Every other stream (eval, predict, serving, bulk) ships
-the four arrays alone, and so does a one-shard training stream from the
-batch on whose capacity passes ``ONE_SHARD_ROWS_MAX_CAPACITY``.
+Past the step's distinct rows each set goes on with distinct ascending ids
+BEYOND the table's last row (``rows_in_table + k``), so the whole set is
+sorted and unique and a ``mode='drop'`` scatter discards the padding.
+``U_tok``/``U_path`` are sticky and bucketed like ``capacity`` (and
+multiples of ``data_shards``). One shard is the same wire with one run:
+``(1, U)`` rows, ``inv`` into them. Every other stream (eval, predict,
+serving, bulk) ships the four arrays alone, and so does a one-shard
+training stream from the batch on whose capacity passes
+``ONE_SHARD_ROWS_MAX_CAPACITY``.
 
 12 bytes per RETAINED slot + 12 bytes per example. Keeping everything up
 to the last valid slot (not only the mask-valid slots) is what makes the
@@ -103,9 +110,10 @@ class PackedBatch(NamedTuple):
     weight: np.ndarray               # (B,) float32 — example validity
     label_strings: Optional[np.ndarray] = None     # (B,) object
     context_lines: Optional[np.ndarray] = None     # (B,) object
-    # per-shard touched rows, training streams only
-    tok_rows: Optional[np.ndarray] = None          # (D, U_tok) int32
-    path_rows: Optional[np.ndarray] = None         # (D, U_path) int32
+    # the step's touched rows, training streams only: one ascending set a
+    # table in D equal runs, and every slot's position in its set
+    tok_rows: Optional[np.ndarray] = None          # (D, U_tok / D) int32
+    path_rows: Optional[np.ndarray] = None         # (D, U_path / D) int32
     inv: Optional[np.ndarray] = None               # (D, cap, 3) int32
 
     @property
@@ -212,17 +220,17 @@ def distinct_rows(ids: np.ndarray, pad_row: int, lut: np.ndarray
     return rows, lut[ids]
 
 
-def pad_rows(rows, capacity: int, rows_in_table: int) -> np.ndarray:
-    """Per-shard ascending row sets -> the rectangular (D, capacity) int32
-    array, each shard continued with ``rows_in_table + k``: still
-    ascending and unique, and out of the table's bounds."""
-    out = np.empty((len(rows), capacity), np.int32)
-    for shard, own in enumerate(rows):
-        n = own.shape[0]
-        out[shard, :n] = own
-        out[shard, n:] = rows_in_table + np.arange(capacity - n,
-                                                   dtype=np.int32)
-    return out
+def pad_rows(rows: np.ndarray, capacity: int, rows_in_table: int,
+             data_shards: int) -> np.ndarray:
+    """An ascending row set -> ``capacity`` int32 rows, continued with
+    ``rows_in_table + k`` (still ascending and unique, and out of the
+    table's bounds), as the (data_shards, capacity / data_shards) array
+    the wire ships."""
+    out = np.empty((capacity,), np.int32)
+    n = rows.shape[0]
+    out[:n] = rows
+    out[n:] = rows_in_table + np.arange(capacity - n, dtype=np.int32)
+    return out.reshape(data_shards, capacity // data_shards)
 
 
 def shard_totals(count: np.ndarray, data_shards: int) -> np.ndarray:
@@ -308,7 +316,7 @@ class StickyPacker:
     data source (reader / cache), living across epochs.
 
     ``table_rows`` = (token table rows, path table rows) marks a TRAINING
-    stream: every batch then also carries its shards' touched rows
+    stream: every batch then also carries the step's touched rows
     (module docstring), under sticky capacities of their own, whatever
     the number of data shards; one shard stops once its sticky capacity
     passes ``ONE_SHARD_ROWS_MAX_CAPACITY``, for good.
@@ -318,8 +326,9 @@ class StickyPacker:
     thread packs), the packed fill rate (retained slots / wire
     capacity — the padding waste the capacity buckets trade for fewer
     jit specializations) and, where rows ship, ``input/unique_row_share``
-    (distinct rows / retained index slots) and
-    ``input/row_capacity_fill`` (distinct rows / row capacity)."""
+    (the step's distinct rows / retained index slots) and
+    ``input/row_capacity_fill`` (the step's distinct rows / ``U_tok +
+    U_path``)."""
 
     def __init__(self, token_pad: int, path_pad: int, data_shards: int = 1,
                  minimum: int = MIN_CAPACITY,
@@ -335,35 +344,34 @@ class StickyPacker:
             self._path_lut = np.empty((table_rows[1],), np.int32)
 
     def _touched_rows(self, ctx: np.ndarray):
-        """(tok_rows, path_rows, inv, distinct) of one packed ``ctx``."""
+        """(tok_rows, path_rows, inv, distinct) of one packed ``ctx``:
+        one row set a table over all of its shards."""
         shards, cap, _ = ctx.shape
         inv = np.empty_like(ctx)
-        tok, pth = [], []
-        for shard in range(shards):
-            rows, pos = distinct_rows(
-                np.concatenate([ctx[shard, :, 0], ctx[shard, :, 2]]),
-                self.token_pad, self._tok_lut)
-            inv[shard, :, 0] = pos[:cap]
-            inv[shard, :, 2] = pos[cap:]
-            tok.append(rows)
-            rows, pos = distinct_rows(np.ascontiguousarray(ctx[shard, :, 1]),
-                                      self.path_pad, self._path_lut)
-            inv[shard, :, 1] = pos
-            pth.append(rows)
-        grown = (row_capacity(max(r.shape[0] for r in tok),
-                              self.tok_capacity, self.minimum),
-                 row_capacity(max(r.shape[0] for r in pth),
-                              self.path_capacity, self.minimum))
+        tok, pos = distinct_rows(
+            np.concatenate([ctx[..., 0].ravel(), ctx[..., 2].ravel()]),
+            self.token_pad, self._tok_lut)
+        inv[..., 0] = pos[:shards * cap].reshape(shards, cap)
+        inv[..., 2] = pos[shards * cap:].reshape(shards, cap)
+        pth, pos = distinct_rows(ctx[..., 1].ravel(), self.path_pad,
+                                 self._path_lut)
+        inv[..., 1] = pos.reshape(shards, cap)
+        # a multiple of the shards, so that the set ships in equal runs
+        grown = tuple(
+            -(-row_capacity(rows.shape[0], current, self.minimum)
+              // shards) * shards
+            for rows, current in ((tok, self.tok_capacity),
+                                  (pth, self.path_capacity)))
         if grown != (self.tok_capacity, self.path_capacity):
             logger.info('packed touched-row capacities: token %d -> %d, '
                         'path %d -> %d (one more train-step program)',
                         self.tok_capacity, grown[0], self.path_capacity,
                         grown[1])
             self.tok_capacity, self.path_capacity = grown
-        distinct = sum(r.shape[0] for r in tok) + sum(r.shape[0] for r in pth)
-        return (pad_rows(tok, self.tok_capacity, self.table_rows[0]),
-                pad_rows(pth, self.path_capacity, self.table_rows[1]),
-                inv, distinct)
+        return (pad_rows(tok, self.tok_capacity, self.table_rows[0], shards),
+                pad_rows(pth, self.path_capacity, self.table_rows[1],
+                         shards),
+                inv, tok.shape[0] + pth.shape[0])
 
     def _finish(self, packed: PackedBatch, t0: float) -> PackedBatch:
         """Attach the touched rows where this stream ships them, and
@@ -393,8 +401,7 @@ class StickyPacker:
                 reg.gauge('input/unique_row_share').set(
                     distinct / max(3 * retained, 1))
                 reg.gauge('input/row_capacity_fill').set(
-                    distinct / (self.data_shards * (self.tok_capacity
-                                                    + self.path_capacity)))
+                    distinct / (self.tok_capacity + self.path_capacity))
         return packed
 
     def pack_batch(self, batch) -> PackedBatch:

@@ -716,42 +716,40 @@ def _grads_jnp(src_e, pth_e, tgt_e, seg, slot_valid, w_src, w_path, w_tgt,
 def _rows_table_grad(table_rows: int, rows, inv, cot, mesh):
     """One embedding table's gradient, reduced over the rows the step
     touched and not over the table (data/packed.py has the wire):
-    ``rows`` (D, U) int32 each shard's distinct rows, ascending, padded
-    past the table's end; ``inv`` (D, S) each slot's position in its
-    shard's rows; ``cot`` (D, S, d) the slots' cotangents.
+    ``rows`` (D, U / D) int32 the step's distinct rows, ascending, padded
+    past the table's end, in D equal runs; ``inv`` (D, S) each slot's
+    position in that set; ``cot`` (D, S, d) the slots' cotangents.
 
-    Each shard sums its slots into a (U, d) buffer of its own: the same
-    row-updates as the dense scatter-add, into a destination that stays
-    on its chip. Buffers and rows are then made whole everywhere (the
-    one collective: an all-gather of D x U rows, where the dense form
-    all-reduces the table), and every device adds the D buffers into the
-    dense gradient in turn. A buffer's rows are unique and sorted, which
-    the scatter is told; rows that shards share are summed by the
-    sequence; the padding is out of bounds and dropped. Same sums as the
-    dense form, reassociated, in ``cot``'s dtype throughout.
+    Each shard sums its slots into a (U, d) buffer in the step's row
+    space: the same row-updates as the dense scatter-add, into a
+    destination that stays on its chip. The D buffers are summed across
+    ``data`` (the one collective: an all-reduce of U rows, where the
+    dense form all-reduces the table), and ONE scatter writes the sums
+    into zeros. The set's rows are unique and sorted, which the scatter
+    is told, and its destination is known to be zeros: the form whose
+    zero-fill the TPU compiler fuses into the scatter and runs at the
+    gathers' rate (a scatter into a live table reads, adds and writes
+    every row at four times that, PERF.md). The padding is out of bounds
+    and dropped. Same sums as the dense form, reassociated, in ``cot``'s
+    dtype throughout.
 
-    One shard (D = 1) is the cheap case and has no collective: one
-    compact scatter-add and one unique sorted scatter into zeros, which
-    the TPU compiler fuses with the zero-fill and runs at the gathers'
-    rate (a later shard's scatter meets a live table and does not,
-    PERF.md). The ``vmap`` over one shard lowers to the plain scatter."""
-    shards, capacity = rows.shape
+    One shard (D = 1) is the same code with nothing to sum and no
+    collective: the ``vmap`` and the sum over one shard lower to the
+    plain scatters."""
+    def held(x, spec):
+        """``x`` under a sharding constraint, where there is a mesh."""
+        return x if mesh is None else jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, spec))
+
     dim = cot.shape[-1]
-    compact = jax.vmap(
+    rows = held(rows, P()).reshape(-1)      # the set made whole: U ids
+    capacity = rows.shape[0]
+    compact = held(jax.vmap(
         lambda i, c: jnp.zeros((capacity, dim), cot.dtype).at[i].add(c))(
-            inv, cot)                                       # (D, U, d)
-    if mesh is not None:
-        compact = jax.lax.with_sharding_constraint(
-            compact, NamedSharding(mesh, P(DATA_AXIS)))
-        whole = NamedSharding(mesh, P())
-        compact = jax.lax.with_sharding_constraint(compact, whole)
-        rows = jax.lax.with_sharding_constraint(rows, whole)
-    grad = jnp.zeros((table_rows, dim), cot.dtype)
-    for shard in range(shards):
-        grad = grad.at[rows[shard]].add(
-            compact[shard], unique_indices=True, indices_are_sorted=True,
-            mode='drop')
-    return grad
+            inv, cot), P(DATA_AXIS))                        # (D, U, d)
+    sums = held(compact.sum(axis=0), P())                   # (U, d)
+    return jnp.zeros((table_rows, dim), cot.dtype).at[rows].add(
+        sums, unique_indices=True, indices_are_sorted=True, mode='drop')
 
 
 # ------------------------------------------------- custom-VJP train path

@@ -109,15 +109,17 @@ CATALOG: Dict[str, Dict[str, str]] = {
                                  '/ packed wire capacity of the last packed '
                                  'batch (padding waste = 1 - this).'),
     'input/unique_row_share': _m(GAUGE, 'fraction', 'Distinct embedding '
-                                 'rows the last batch\'s shards named / its '
-                                 'retained index slots, tokens and paths '
-                                 'together (a training stream on the '
-                                 'packed wire: the rows the table gradients '
-                                 'are built and reduced over).'),
+                                 'rows the last batch named, all of its '
+                                 'data shards together / its retained '
+                                 'index slots, tokens and paths together '
+                                 '(a training stream on the packed wire: '
+                                 'the rows the table gradients are built '
+                                 'and reduced over).'),
     'input/row_capacity_fill': _m(GAUGE, 'fraction', 'Distinct embedding '
-                                  'rows the last batch\'s shards named / '
-                                  'the touched-row capacity they ship '
-                                  'under.'),
+                                  'rows the last batch named / the '
+                                  'touched-row capacities it ships under '
+                                  '(U_tok + U_path: one set a table a '
+                                  'step).'),
     # ---- serving engine (code2vec_tpu/serving/, SERVING.md) ----
     'serving/requests_total': _m(COUNTER, 'requests', 'Prediction requests '
                                  'submitted to the serving engine.'),

@@ -928,8 +928,8 @@ class Trainer:
                         # each NEW packed capacity = one more jit
                         # specialization of the whole step program
                         # (so is each new touched-row capacity)
-                        shapes = tuple(int(a.shape[1])
-                                       for a in arrays[:1] + arrays[4:6])
+                        shapes = (int(arrays[0].shape[1]),) + tuple(
+                            int(a.size) for a in arrays[4:6])
                         shape_key = 'packed:' + ':'.join(map(str, shapes))
                         tele.capacity.observe(shapes[0], batch_num,
                                               rows=shapes[1:])

@@ -1,9 +1,10 @@
 """Touched rows on the packed wire (data/packed.py) and the gradient
 reduction that uses them (ops/pallas_ragged.py ``_rows_table_grad``): a
-training batch names, per data shard, the embedding rows its slots touch;
-the step sums its slots into those rows and scatters them, unique and
-sorted, into the dense gradient, and on a data-parallel mesh gathers the
-rows' sums where the dense form all-reduces the tables.
+training batch names the embedding rows its slots touch, ONE ascending set
+a table for all of its data shards; every shard sums its slots into that
+row space, a data-parallel mesh adds the shards' sums (an all-reduce of
+the set's rows where the dense form all-reduces the tables), and one
+scatter, unique and sorted, writes them into zeros.
 
 CPU, up to four of the eight virtual devices: what the packer emits, that
 the train step's table gradients are the dense form's on one shard and on
@@ -12,6 +13,7 @@ packed without ``table_rows`` (eval, predict) sees none of it."""
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,6 +21,7 @@ from code2vec_tpu.data import packed as packed_lib
 from code2vec_tpu.data.reader import (Batch, EstimatorAction,
                                       PathContextReader, context_valid_mask)
 from code2vec_tpu.models.backends import create_backend
+from code2vec_tpu.ops import pallas_ragged
 from code2vec_tpu.parallel import mesh as mesh_lib
 from code2vec_tpu.training.trainer import Trainer
 from code2vec_tpu.vocab import SizeOnlyVocabs
@@ -67,10 +70,11 @@ def plane_batch(rng, paths=PATHS):
 
 
 def corner_batch(shards, fill_paths):
-    """One batch with every corner the issue lists: rows shared between
-    shards (every shard draws from the same few), an empty method, an
-    interior all-PAD context, and a first shard whose methods name
-    exactly ``fill_paths`` distinct path rows, PAD among them."""
+    """One batch with every corner the issues list: rows that every shard
+    names (all draw their tokens from the same few), rows that one shard
+    alone names (the first shard's paths past 3), an empty method, an
+    interior all-PAD context, and a step whose methods name exactly
+    ``fill_paths`` distinct path rows, PAD among them."""
     rng = np.random.default_rng(11)
     source = rng.integers(1, 9, (BATCH, CONTEXTS)).astype(np.int32)
     target = rng.integers(1, 9, (BATCH, CONTEXTS)).astype(np.int32)
@@ -119,33 +123,37 @@ def packed_pair(request):
 
 def test_rows_ascending_unique_and_padded_past_the_table(packed_pair):
     packed, _plain, tables = packed_pair
-    for rows, in_table in ((packed.tok_rows, tables[0]),
-                           (packed.path_rows, tables[1])):
+    for rows, in_table, columns in ((packed.tok_rows, tables[0], (0, 2)),
+                                    (packed.path_rows, tables[1], (1,))):
+        # one set a step, shipped in four equal runs
         assert rows.dtype == np.int32 and rows.shape[0] == 4
-        assert (np.diff(rows.astype(np.int64), axis=1) > 0).all()
-        for shard in rows:
-            own = shard[shard < in_table]
-            # past a shard's own rows: rows_in_table + k, k = 0, 1, ...
-            np.testing.assert_array_equal(
-                shard[own.size:],
-                in_table + np.arange(shard.size - own.size))
+        whole = rows.reshape(-1)
+        assert (np.diff(whole.astype(np.int64)) > 0).all()
+        own = whole[whole < in_table]
+        np.testing.assert_array_equal(
+            own, np.union1d(packed.ctx[..., columns].ravel(), [0]))
+        # past the step's own rows: rows_in_table + k, k = 0, 1, ...
+        np.testing.assert_array_equal(
+            whole[own.size:], in_table + np.arange(whole.size - own.size))
 
 
-def test_pad_row_is_among_every_shards_rows(packed_pair):
+def test_pad_row_is_among_the_steps_rows(packed_pair):
     packed, _plain, _tables = packed_pair
-    assert (packed.tok_rows[:, 0] == 0).all()
-    assert (packed.path_rows[:, 0] == 0).all()
+    assert packed.tok_rows[0, 0] == 0 and packed.path_rows[0, 0] == 0
+    # and in the set once: a later run does not begin with it again
+    assert (packed.tok_rows[1:] > 0).all()
+    assert (packed.path_rows[1:] > 0).all()
 
 
 def test_inv_finds_every_slots_row(packed_pair):
     packed, _plain, _tables = packed_pair
-    shard = np.arange(4)[:, None]
-    np.testing.assert_array_equal(
-        packed.tok_rows[shard, packed.inv[..., 0]], packed.ctx[..., 0])
-    np.testing.assert_array_equal(
-        packed.path_rows[shard, packed.inv[..., 1]], packed.ctx[..., 1])
-    np.testing.assert_array_equal(
-        packed.tok_rows[shard, packed.inv[..., 2]], packed.ctx[..., 2])
+    tok, path = packed.tok_rows.reshape(-1), packed.path_rows.reshape(-1)
+    np.testing.assert_array_equal(tok[packed.inv[..., 0]],
+                                  packed.ctx[..., 0])
+    np.testing.assert_array_equal(path[packed.inv[..., 1]],
+                                  packed.ctx[..., 1])
+    np.testing.assert_array_equal(tok[packed.inv[..., 2]],
+                                  packed.ctx[..., 2])
 
 
 def test_rows_follow_the_four_wire_arrays(packed_pair):
@@ -171,10 +179,26 @@ def test_row_capacities_are_sticky_and_never_shrink():
     seen = []
     for batch in (narrow, wide, narrow, wide):
         packed = packer.pack_batch(batch)
-        seen.append((packed.tok_rows.shape[1], packed.path_rows.shape[1]))
+        assert packed.tok_rows.shape[0] == packed.path_rows.shape[0] == 2
+        seen.append((packed.tok_rows.size, packed.path_rows.size))
         assert seen[-1] == (packer.tok_capacity, packer.path_capacity)
     assert seen[1][0] > seen[0][0] and seen[1][1] > seen[0][1]
     assert seen[2] == seen[1] == seen[3]
+
+
+@pytest.mark.parametrize('shards', [1, 3, 4])
+def test_row_capacities_are_multiples_of_the_shards(shards):
+    # the set ships in equal runs over ``data``, whatever the bucket
+    rng = np.random.default_rng(5)
+    batch = random_plane_batch(rng, 12, CONTEXTS, pad_row_rate=0.1)
+    packer = packed_lib.StickyPacker(0, 0, data_shards=shards, minimum=1,
+                                     table_rows=(128, 128))
+    packed = packer.pack_batch(batch)
+    for rows, capacity in ((packed.tok_rows, packer.tok_capacity),
+                           (packed.path_rows, packer.path_capacity)):
+        assert rows.shape == (shards, capacity // shards)
+        assert capacity % shards == 0
+        assert (np.diff(rows.reshape(-1).astype(np.int64)) > 0).all()
 
 
 def test_row_capacity_leaves_head_room_and_holds_what_fits():
@@ -188,6 +212,16 @@ def test_row_capacity_leaves_head_room_and_holds_what_fits():
     for distinct in (16400, 16716, 17600):
         assert packed_lib.row_capacity(distinct, current=64) == 20480
     assert packed_lib.row_capacity(33000, current=36864) == 36864
+    # the four shards of its data=4 step name 95.2K and 52.1K together:
+    # the token set lies by a bucket's edge (94,663), so a first batch
+    # gives it one of two capacities, and either holds every later batch
+    for distinct, capacity in ((93000, 106496), (94663, 106496),
+                               (94664, 114688), (95200, 114688),
+                               (97500, 114688)):
+        assert packed_lib.row_capacity(distinct, current=64) == capacity
+    assert packed_lib.row_capacity(99000, current=106496) == 106496
+    for distinct in (51000, 52100, 53500):
+        assert packed_lib.row_capacity(distinct, current=64) == 61440
     for distinct in (100, 16385, 30959, 81920):
         capacity = packed_lib.row_capacity(distinct, current=64)
         assert distinct * 9 // 8 <= capacity <= distinct * 5 // 4 + 64
@@ -293,18 +327,18 @@ def assert_corner_step_equals_the_dense_form(trainer):
     # the first batch sets the row capacities; the second's first shard
     # then names exactly as many path rows as there is room for
     first = with_rows.pack_batch(plane_batch(rng, paths=9))
-    assert first.path_rows.shape[1] == PATHS
+    assert first.path_rows.size == PATHS
     corner = corner_batch(data, fill_paths=PATHS)
     packed = with_rows.pack_batch(corner)
-    assert packed.path_rows.shape == first.path_rows.shape
-    paths_in_table = table_rows(trainer.config)[1]
-    assert (packed.path_rows[0] < paths_in_table).all()        # U, exactly
+    assert packed.path_rows.shape == first.path_rows.shape == (
+        data, PATHS // data)
+    tokens_in_table, paths_in_table = table_rows(trainer.config)
+    assert (packed.path_rows < paths_in_table).all()           # U, exactly
+    assert (packed.tok_rows >= tokens_in_table).any()          # and padding
+    named = [set(packed.ctx[shard, :, 1]) for shard in range(data)]
     if data > 1:
-        assert (packed.path_rows[1:] >= paths_in_table).any()
-        shared = set(packed.tok_rows[0]) & set(packed.tok_rows[1])
-        assert len(shared) > 2                                 # shared rows
-    else:
-        assert (packed.tok_rows[0] >= table_rows(trainer.config)[0]).any()
+        assert len(set.intersection(*named)) > 2        # rows of every shard
+        assert named[0] - set.union(*named[1:])         # rows of one alone
     assert packed.count[-1] == 0 and packed.count[1] == CONTEXTS
 
     state = trainer.init_state(seed=0)
@@ -372,12 +406,20 @@ def compiled_text(trainer, packed):
     return lowered(trainer, packed).compile().as_text()
 
 
+def narrow_batch(seed):
+    """A batch whose step names fewer rows than either table holds, so
+    that a set's row count cannot be mistaken for a table's."""
+    batch = plane_batch(np.random.default_rng(seed), paths=8)
+    return batch._replace(source=np.minimum(batch.source, 19),
+                          target=np.minimum(batch.target, 19))
+
+
 def test_no_collective_of_the_step_carries_a_table():
     # tables of their own size, so a row count cannot be mistaken
     trainer = make_trainer(4, 1, PARAM_ROW_ALIGNMENT=8,
                            MAX_TOKEN_VOCAB_SIZE=TOKENS)
-    with_rows, plain = packers(trainer)
-    batch = plane_batch(np.random.default_rng(19))
+    with_rows, plain = packers(trainer, minimum=4)
+    batch = narrow_batch(19)
     tokens_in_table, paths_in_table = table_rows(trainer.config)
     assert (tokens_in_table, paths_in_table) == (40, 16)
     carries_table = re.compile(r'\[(%d|%d),8\]' % (tokens_in_table,
@@ -385,13 +427,27 @@ def test_no_collective_of_the_step_carries_a_table():
     dense = collectives(compiled_text(trainer, plain.pack_batch(batch)))
     assert {kind for kind, shape in dense
             if carries_table.search(shape)} == {'all-reduce'}, dense
-    by_rows = collectives(compiled_text(trainer,
-                                        with_rows.pack_batch(batch)))
+    packed = with_rows.pack_batch(batch)
+    sets = (packed.tok_rows.size, packed.path_rows.size)
+    assert sets == (24, 12)
+    by_rows = collectives(compiled_text(trainer, packed))
     assert by_rows and not [
         (kind, shape) for kind, shape in by_rows
         if carries_table.search(shape)], by_rows
-    gathered = [shape for kind, shape in by_rows if kind == 'all-gather']
-    assert any(shape.startswith('f32[') for shape in gathered), by_rows
+    # the shards' row sums are ADDED across ``data``, (U_step, d) a table:
+    # an all-reduce, or a reduce-scatter with the all-gather that follows
+    for rows in sets:
+        summed = [kind for kind, shape in by_rows
+                  if 'f32[%d,8]' % rows in shape
+                  and kind in ('all-reduce', 'all-gather')]
+        assert summed, (rows, by_rows)
+        assert 'all-reduce' in summed or 'f32[%d,8]' % (rows // 4) in ''.join(
+            shape for kind, shape in by_rows if kind == 'reduce-scatter')
+    # and no shard's buffer is gathered: D x U float32 rows
+    assert not [shape for kind, shape in by_rows if kind == 'all-gather'
+                and re.search(r'f32\[(4,(%d|%d)|%d|%d),8\]'
+                              % (*sets, 4 * sets[0], 4 * sets[1]), shape)
+                ], by_rows
 
 
 def test_a_packer_without_table_rows_lowers_the_one_device_step_as_before():
@@ -410,19 +466,42 @@ def test_a_packer_without_table_rows_lowers_the_one_device_step_as_before():
 
 
 SCATTER = re.compile(
-    r'"stablehlo\.scatter"\(.*?unique_indices = (true|false)\}>.*?'
-    r'\}\) : \([^\n]*\) -> tensor<([0-9x]+)xf32>', re.S)
+    r'(%\w+) = "stablehlo\.scatter"\((%\w+), [^\n]*?'
+    r'unique_indices = (true|false)\}>.*?'
+    r'\}\) : \(tensor<[0-9x]+xf32>, tensor<[0-9x]+xi32>, '
+    r'tensor<([0-9x]+)xf32>\) -> tensor<([0-9x]+)xf32>', re.S)
+
+
+def scatters_into(text, tables):
+    """(unique_indices, update shape, destination known to be zeros) of
+    every scatter of a lowered text whose result is one of ``tables``
+    (shapes as '40x8'), in program order."""
+    out = []
+    for _name, operand, unique, updates, result in SCATTER.findall(text):
+        if result not in tables:
+            continue
+        made = re.search(r'%s = stablehlo\.broadcast_in_dim (%%\w+), dims = '
+                         r'\[\]' % re.escape(operand), text)
+        zeros = bool(made and re.search(
+            r'%s = stablehlo\.constant dense<0\.0+e\+00>'
+            % re.escape(made.group(1)), text))
+        out.append((unique, updates, zeros))
+    return out
+
+
+def small_tables_trainer(data):
+    # tables of their own size, so a row count cannot be mistaken
+    trainer = make_trainer(data, 1, PARAM_ROW_ALIGNMENT=8,
+                           MAX_TOKEN_VOCAB_SIZE=TOKENS)
+    tokens_in_table, paths_in_table = table_rows(trainer.config)
+    assert (tokens_in_table, paths_in_table) == (40, 16)
+    return trainer, ('%dx8' % tokens_in_table, '%dx8' % paths_in_table)
 
 
 def test_one_device_step_scatters_unique_rows_into_the_tables():
-    # tables of their own size, so a row count cannot be mistaken
-    trainer = make_trainer(1, 1, PARAM_ROW_ALIGNMENT=8,
-                           MAX_TOKEN_VOCAB_SIZE=TOKENS)
+    trainer, tables = small_tables_trainer(1)
     with_rows, plain = packers(trainer)
     batch = plane_batch(np.random.default_rng(23))
-    tokens_in_table, paths_in_table = table_rows(trainer.config)
-    assert (tokens_in_table, paths_in_table) == (40, 16)
-    tables = {'%dx8' % tokens_in_table, '%dx8' % paths_in_table}
 
     def into_tables(wire):
         """``unique_indices`` of every scatter whose result is a table's
@@ -430,8 +509,8 @@ def test_one_device_step_scatters_unique_rows_into_the_tables():
         text = lowered(trainer, wire).as_text()
         assert not re.search(r'stablehlo\.(all_|reduce_scatter|collective_)'
                              r'|@Sharding', text)
-        return [unique for unique, shape in SCATTER.findall(text)
-                if shape in tables]
+        return [unique for unique, _updates, _zeros
+                in scatters_into(text, tables)]
 
     # the dense form: a scatter-add of every slot, then the PAD row's term
     assert into_tables(plain.pack_batch(batch)) == [
@@ -442,6 +521,87 @@ def test_one_device_step_scatters_unique_rows_into_the_tables():
     assert len(packed.device_arrays()) == 7
     assert into_tables(packed) == ['true'] * 4
     assert not collectives(compiled_text(trainer, packed))
+
+
+@pytest.mark.parametrize('data', [4, 2, 1])
+def test_step_scatters_one_row_set_a_table_into_zeros(data):
+    # whatever the number of shards: per table ONE scatter of the step's
+    # set, unique and sorted, into a destination the compiler is shown to
+    # be zeros, then the PAD row's one-row term; no shard's sums meet a
+    # table that already holds another's
+    trainer, tables = small_tables_trainer(data)
+    with_rows, _plain = packers(trainer, minimum=4)
+    packed = with_rows.pack_batch(narrow_batch(29))
+    sets = (packed.tok_rows.size, packed.path_rows.size)
+    assert sets == (24, 12)
+    found = scatters_into(lowered(trainer, packed).as_text(), tables)
+    assert found == [('true', '%dx8' % sets[0], True), ('true', '8', False),
+                     ('true', '%dx8' % sets[1], True), ('true', '8', False)]
+
+
+def parent_rows_table_grad(table_rows, rows, inv, cot, mesh):
+    """``_rows_table_grad`` as PR 34 left it (its one-device path, no
+    mesh): the reference the one-shard step must still compile to."""
+    assert mesh is None
+    shards, capacity = rows.shape
+    dim = cot.shape[-1]
+    compact = jax.vmap(
+        lambda i, c: jnp.zeros((capacity, dim), cot.dtype).at[i].add(c))(
+            inv, cot)
+    grad = jnp.zeros((table_rows, dim), cot.dtype)
+    for shard in range(shards):
+        grad = grad.at[rows[shard]].add(
+            compact[shard], unique_indices=True, indices_are_sorted=True,
+            mode='drop')
+    return grad
+
+
+def test_one_shard_step_compiles_to_the_program_it_was(monkeypatch):
+    # one shard is the degenerate case of the same code: the run of one
+    # and the sum over one shard leave nothing in the compiled program
+    trainer, _tables = small_tables_trainer(1)
+    with_rows, _plain = packers(trainer)
+    packed = with_rows.pack_batch(plane_batch(np.random.default_rng(23)))
+    assert packed.tok_rows.shape[0] == packed.path_rows.shape[0] == 1
+
+    def program():
+        """The compiled step's instructions, names and source lines
+        apart, as a sorted list."""
+        text = re.sub(r', metadata=\{[^}]*\}', '',
+                      compiled_text(trainer, packed))
+        text = re.sub(r'%[\w.-]+',
+                      lambda m: re.sub(r'[._]?\d+', '', m.group(0)), text)
+        return sorted(line.strip() for line in text.splitlines()
+                      if ' = ' in line)
+
+    ours = program()
+    monkeypatch.setattr(pallas_ragged, '_rows_table_grad',
+                        parent_rows_table_grad)
+    jax.clear_caches()
+    trainer, _tables = small_tables_trainer(1)
+    theirs = program()
+    assert [line for line in ours if 'scatter(' in line]
+    assert ours == theirs
+    assert not collectives('\n'.join(ours))
+
+
+@pytest.mark.parametrize('shards', [1, 2, 4])
+def test_rows_table_grad_is_the_dense_scatter_add(shards):
+    # the function alone, no mesh: every shard's slots, rows shared
+    # between shards and padding past the table included
+    rng = np.random.default_rng(31)
+    in_table, slots, dim = 24, 40, 8
+    ids = rng.integers(0, 12, (shards, slots)).astype(np.int32)
+    cot = rng.standard_normal((shards, slots, dim)).astype(np.float32)
+    lut = np.empty((in_table,), np.int32)
+    own, inv = packed_lib.distinct_rows(ids.ravel(), 0, lut)
+    rows = packed_lib.pad_rows(own, 16, in_table, shards)
+    got = pallas_ragged._rows_table_grad(
+        in_table, jnp.asarray(rows), jnp.asarray(inv.reshape(shards, slots)),
+        jnp.asarray(cot), None)
+    want = np.zeros((in_table, dim), np.float32)
+    np.add.at(want, ids.ravel(), cot.reshape(-1, dim))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
 
 
 def test_capacity_tracker_counts_a_new_row_capacity_once():
@@ -465,14 +625,16 @@ def test_packer_reports_the_row_gauges(packed_pair):
         packer = packed_lib.StickyPacker(0, 0, data_shards=4, minimum=8,
                                          table_rows=tables)
         packed = packer.pack_batch(plane_batch(rng))
-        distinct = int((packed.tok_rows < tables[0]).sum()
-                       + (packed.path_rows < tables[1]).sum())
+        # the step's distinct rows, not the sum of its shards' own
+        distinct = (np.union1d(packed.ctx[..., (0, 2)].ravel(), [0]).size
+                    + np.union1d(packed.ctx[..., 1].ravel(), [0]).size)
+        assert distinct == int((packed.tok_rows < tables[0]).sum()
+                               + (packed.path_rows < tables[1]).sum())
         reg = core.registry()
         assert reg.gauge('input/unique_row_share').value == pytest.approx(
             distinct / (3 * int(packed.count.sum())))
         assert reg.gauge('input/row_capacity_fill').value == pytest.approx(
-            distinct / (4 * (packed.tok_rows.shape[1]
-                             + packed.path_rows.shape[1])))
+            distinct / (packed.tok_rows.size + packed.path_rows.size))
     finally:
         if not was:
             core.disable()
