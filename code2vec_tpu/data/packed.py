@@ -72,6 +72,7 @@ so the data layer stays importable without it.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import time as _time
 from typing import NamedTuple, Optional, Tuple
@@ -322,7 +323,9 @@ class StickyPacker:
     passes ``ONE_SHARD_ROWS_MAX_CAPACITY``, for good.
 
     Instrumented (telemetry enabled only — one bool read otherwise):
-    pack time (``step/pack_ms``, recorded from whichever reader/prefetch
+    pack time (``step/pack_ms`` and, from the same opening, a
+    ``host/pack`` profiler event, so that a capture shows the packing on
+    the device's clock; recorded from whichever reader/prefetch
     thread packs), the packed fill rate (retained slots / wire
     capacity — the padding waste the capacity buckets trade for fewer
     jit specializations) and, where rows ship, ``input/unique_row_share``
@@ -373,9 +376,28 @@ class StickyPacker:
                          shards),
                 inv, tok.shape[0] + pth.shape[0])
 
-    def _finish(self, packed: PackedBatch, t0: float) -> PackedBatch:
+    @contextlib.contextmanager
+    def _timed(self):
+        """One batch's packing, opened once for both sinks where
+        telemetry is on: the ``step/pack_ms`` timer and a ``host/pack``
+        profiler event whose ``stats`` are the sticky capacities the
+        batch met (known when the event opens; they grow in warm-up)."""
+        from code2vec_tpu.telemetry import core
+        if not core.enabled():
+            yield
+            return
+        from jax.profiler import TraceAnnotation
+        t0 = _time.perf_counter()
+        with TraceAnnotation('host/pack', capacity=self.capacity,
+                             tok_rows=self.tok_capacity,
+                             path_rows=self.path_capacity):
+            yield
+        core.registry().timer('step/pack_ms').record(
+            _time.perf_counter() - t0)
+
+    def _finish(self, packed: PackedBatch) -> PackedBatch:
         """Attach the touched rows where this stream ships them, and
-        record the batch's instruments."""
+        record the batch's gauges."""
         from code2vec_tpu.telemetry import core
         distinct = None
         capacity = packed.ctx.shape[1]
@@ -393,7 +415,6 @@ class StickyPacker:
                                      inv=inv)
         if core.enabled():
             reg = core.registry()
-            reg.timer('step/pack_ms').record(_time.perf_counter() - t0)
             retained = int(packed.count.sum())
             slots = int(packed.ctx.shape[0]) * int(capacity)
             reg.gauge('input/packed_fill_rate').set(retained / max(slots, 1))
@@ -405,23 +426,22 @@ class StickyPacker:
         return packed
 
     def pack_batch(self, batch) -> PackedBatch:
-        from code2vec_tpu.telemetry import core
-        t0 = _time.perf_counter() if core.enabled() else 0.0
-        packed = pack_batch(batch, self.token_pad, self.path_pad,
-                            data_shards=self.data_shards,
-                            capacity_minimum=self.capacity)
-        self.capacity = max(self.capacity, packed.ctx.shape[1])
-        return self._finish(packed, t0)
+        with self._timed():
+            packed = pack_batch(batch, self.token_pad, self.path_pad,
+                                data_shards=self.data_shards,
+                                capacity_minimum=self.capacity)
+            self.capacity = max(self.capacity, packed.ctx.shape[1])
+            return self._finish(packed)
 
     def pack_ragged(self, ctx_rows: np.ndarray, count: np.ndarray,
                     label: np.ndarray, weight: np.ndarray) -> PackedBatch:
-        from code2vec_tpu.telemetry import core
-        t0 = _time.perf_counter() if core.enabled() else 0.0
-        ctx = pack_ragged(ctx_rows, count, self.token_pad, self.path_pad,
-                          self.data_shards, capacity_minimum=self.capacity)
-        self.capacity = max(self.capacity, ctx.shape[1])
-        return self._finish(PackedBatch(ctx=ctx, count=count, label=label,
-                                        weight=weight), t0)
+        with self._timed():
+            ctx = pack_ragged(ctx_rows, count, self.token_pad,
+                              self.path_pad, self.data_shards,
+                              capacity_minimum=self.capacity)
+            self.capacity = max(self.capacity, ctx.shape[1])
+            return self._finish(PackedBatch(ctx=ctx, count=count,
+                                            label=label, weight=weight))
 
 
 def unpack_ragged_np(ctx_rows: np.ndarray, count: np.ndarray,

@@ -34,6 +34,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from code2vec_tpu.scopes import scoped
+
 # Floor for the additive log-mask so fully-masked rows stay finite (vs the
 # reference's log(0) = -inf which NaNs an all-invalid row,
 # tensorflow_model.py:257). Must be a NORMAL fp32 (XLA flushes denormals to
@@ -108,6 +110,7 @@ def dropout_keep_mask(dropout_rng: jax.Array, keep_rate: float, shape,
     return jax.random.bernoulli(dropout_rng, keep_rate, shape)
 
 
+@scoped('c2v_encode')
 def encode(params: Code2VecParams, source: jax.Array, path: jax.Array,
            target: jax.Array, mask: jax.Array, *,
            dropout_rng: Optional[jax.Array] = None,
@@ -184,6 +187,7 @@ def encode(params: Code2VecParams, source: jax.Array, path: jax.Array,
     return code_vectors, attention_weights
 
 
+@scoped('c2v_encode')
 def encode_packed(params: Code2VecParams, ctx: jax.Array, count: jax.Array,
                   *, max_contexts: int, token_pad: int, path_pad: int,
                   dropout_rng: Optional[jax.Array] = None,
@@ -216,6 +220,7 @@ def encode_packed(params: Code2VecParams, ctx: jax.Array, count: jax.Array,
         use_kernel=use_kernel, interpret=interpret, mesh=mesh)
 
 
+@scoped('c2v_logits')
 def compute_logits(params: Code2VecParams, code_vectors: jax.Array,
                    dtype: jnp.dtype = jnp.float32,
                    num_valid_targets: Optional[int] = None) -> jax.Array:
@@ -297,6 +302,7 @@ def loss_and_aux(params: Code2VecParams, source: jax.Array, path: jax.Array,
                            num_valid_targets, use_fused_ce, fused_ce_mesh)
 
 
+@scoped('c2v_ce')
 def _loss_from_code(params, code_vectors, label, weight, dtype,
                     num_valid_targets, use_fused_ce, fused_ce_mesh):
     """The loss tail shared by the plane and packed wires: code vectors

@@ -103,6 +103,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from code2vec_tpu.ops._pallas_common import resolve_interpret
 from code2vec_tpu.parallel.mesh import DATA_AXIS
+from code2vec_tpu.scopes import scoped
 
 SLOT_TILE = 512     # packed slots per grid step; capacity pads to a multiple
 _NEG = -1e30        # finite -inf stand-in (denormal-safe, like pallas_ce)
@@ -803,6 +804,7 @@ def ragged_encode_code(token_embedding: jax.Array,
 
     precision = _precision(dtype)
 
+    @scoped('c2v_encode')
     def _fwd_compute(tok_t, path_t, trans, attn, ctx_, count_, rng_):
         count2, seg, _pos, slot_valid, src, pth, tgt = _segment_inputs(
             ctx_, count_, token_pad, path_pad)
@@ -835,6 +837,7 @@ def ragged_encode_code(token_embedding: jax.Array,
         code = _code_from_stats(z, acc, count2, x_pad)
         return code.reshape(count_.shape[0], -1), m, z
 
+    @scoped('c2v_encode')   # autodiff names no custom VJP's backward
     def _bwd_compute(tok_t, path_t, trans, attn, ctx_, count_, rng_,
                      rows_, m, z, code, g):
         count2, seg, _pos, slot_valid, src, pth, tgt = _segment_inputs(
@@ -900,35 +903,36 @@ def ragged_encode_code(token_embedding: jax.Array,
         # rows, reduced over those and not over the tables. Token table,
         # its PAD term, then the path table: the order the dense form's
         # operands have always been built in (its lowered text is pinned)
-        if rows_:
-            tok_rows, path_rows, inv = rows_
-            d_tok = _rows_table_grad(
-                tok_t.shape[0], tok_rows,
-                jnp.concatenate([inv[..., 0], inv[..., 2]], axis=1),
-                jnp.concatenate([de_src, de_tgt],
-                                axis=1).astype(tok_t.dtype), mesh)
-        else:
-            tok_idx = jnp.concatenate([src.reshape(-1), tgt.reshape(-1)])
-            tok_cot = jnp.concatenate([de_src.reshape(-1, token_dim),
-                                       de_tgt.reshape(-1, token_dim)])
-            d_tok = jnp.zeros((tok_t.shape[0], token_dim),
-                              tok_t.dtype).at[tok_idx].add(
-                                  tok_cot.astype(tok_t.dtype))
-        d_tok = d_tok.at[token_pad].add(
-            (de_pad[:token_dim]
-             + de_pad[token_dim + path_dim:]).astype(tok_t.dtype))
-        if rows_:
-            d_path = _rows_table_grad(
-                path_t.shape[0], path_rows, inv[..., 1],
-                de_pth.astype(path_t.dtype), mesh)
-        else:
-            pth_cot = de_pth.reshape(-1, path_dim)
-            pth_idx = pth.reshape(-1)
-            d_path = jnp.zeros((path_t.shape[0], path_dim),
-                               path_t.dtype).at[pth_idx].add(
-                                   pth_cot.astype(path_t.dtype))
-        d_path = d_path.at[path_pad].add(
-            de_pad[token_dim:token_dim + path_dim].astype(path_t.dtype))
+        with jax.named_scope('c2v_table_grad'):
+            if rows_:
+                tok_rows, path_rows, inv = rows_
+                d_tok = _rows_table_grad(
+                    tok_t.shape[0], tok_rows,
+                    jnp.concatenate([inv[..., 0], inv[..., 2]], axis=1),
+                    jnp.concatenate([de_src, de_tgt],
+                                    axis=1).astype(tok_t.dtype), mesh)
+            else:
+                tok_idx = jnp.concatenate([src.reshape(-1), tgt.reshape(-1)])
+                tok_cot = jnp.concatenate([de_src.reshape(-1, token_dim),
+                                           de_tgt.reshape(-1, token_dim)])
+                d_tok = jnp.zeros((tok_t.shape[0], token_dim),
+                                  tok_t.dtype).at[tok_idx].add(
+                                      tok_cot.astype(tok_t.dtype))
+            d_tok = d_tok.at[token_pad].add(
+                (de_pad[:token_dim]
+                 + de_pad[token_dim + path_dim:]).astype(tok_t.dtype))
+            if rows_:
+                d_path = _rows_table_grad(
+                    path_t.shape[0], path_rows, inv[..., 1],
+                    de_pth.astype(path_t.dtype), mesh)
+            else:
+                pth_cot = de_pth.reshape(-1, path_dim)
+                pth_idx = pth.reshape(-1)
+                d_path = jnp.zeros((path_t.shape[0], path_dim),
+                                   path_t.dtype).at[pth_idx].add(
+                                       pth_cot.astype(path_t.dtype))
+            d_path = d_path.at[path_pad].add(
+                de_pad[token_dim:token_dim + path_dim].astype(path_t.dtype))
         return d_tok, d_path, d_trans, d_attn.astype(attn.dtype)
 
     @jax.custom_vjp

@@ -37,6 +37,7 @@ from code2vec_tpu.models import functional
 from code2vec_tpu.ops.topk import sharded_top_k
 from code2vec_tpu.parallel import mesh as mesh_lib
 from code2vec_tpu.resilience import faults
+from code2vec_tpu.scopes import scoped
 from code2vec_tpu.telemetry import goodput as goodput_lib
 
 # package logger: 'code2vec_tpu.training.trainer' — propagates to the
@@ -130,14 +131,25 @@ class Trainer:
         # Telemetry (OBSERVABILITY.md): None when disabled — every
         # instrumented site below is then a single `is None` check.
         self._telemetry = None
-        # dispatch shapes whose AOT step cost (FLOPs/bytes for train/mfu)
-        # has been captured — first sight only, telemetry path only
-        self._cost_keys = set()
+        # Legend of the step programs for profiler captures
+        # (telemetry/trace.py::ProgramLegend): None unless a capture can
+        # happen (PROFILE_DIR's fixed window, telemetry's on-demand one)
+        self._legend = None
+        # dispatch shapes already lowered for their AOT step cost
+        # (FLOPs/bytes for train/mfu) and the legend's text — first sight
+        # only, telemetry and PROFILE_DIR paths only
+        self._seen_keys = set()
         if getattr(config, 'TELEMETRY', False):
             from code2vec_tpu.telemetry import StepTelemetry
             self._telemetry = StepTelemetry(
                 config, log=config.log,
                 process_index=jax.process_index())
+        if self._telemetry is not None or config.PROFILE_DIR:
+            from code2vec_tpu.telemetry.trace import ProgramLegend
+            self._legend = ProgramLegend(dict(self.mesh.shape),
+                                         log=config.log)
+            if self._telemetry is not None:
+                self._telemetry.trace.legend = self._legend
         # Device-memory ledger (telemetry/memory.py, OBSERVABILITY.md):
         # this trainer's state registers under a per-instance key, so
         # restores replace (never double-count) and a garbage-collected
@@ -175,6 +187,7 @@ class Trainer:
         # before any moment math.
         grads_bf16 = self.config.GRADS_DTYPE == 'bfloat16'
 
+        @scoped('c2v_adam')    # the casts are the walk's
         def cast_for_grads(params):
             return jax.tree_util.tree_map(
                 lambda p: p.astype(jnp.bfloat16)
@@ -214,9 +227,13 @@ class Trainer:
                 diff_params = (cast_for_grads(state.params) if grads_bf16
                                else state.params)
                 loss, grads = jax.value_and_grad(loss_fn)(diff_params)
-                updates, new_opt_state = optimizer.update(
-                    grads, state.opt_state, state.params)
-                new_params = optax.apply_updates(state.params, updates)
+                # the walk over the tables and the dense parameters; the
+                # loss's parts are named where they are written
+                # (models/functional.py, ops/pallas_ragged.py)
+                with jax.named_scope('c2v_adam'):
+                    updates, new_opt_state = optimizer.update(
+                        grads, state.opt_state, state.params)
+                    new_params = optax.apply_updates(state.params, updates)
                 new_state = TrainerState(params=new_params,
                                          opt_state=new_opt_state,
                                          step=state.step + 1, rng=state.rng)
@@ -233,6 +250,7 @@ class Trainer:
         # None keeps single-device tracing mesh-free, like loss_mesh
         fwd_mesh = self.mesh if self.mesh.size > 1 else None
 
+        @scoped('c2v_topk')
         def take_top_k(logits):
             # cross-shard merge on model-parallel meshes, plain lax.top_k
             # otherwise — the dispatch lives in sharded_top_k
@@ -629,11 +647,17 @@ class Trainer:
         """One jitted program's AOT cost record: logical FLOPs + bytes
         accessed from ``Lowered.cost_analysis()`` — analysis of the
         lowered (pre-partitioning) module, so it costs one trace +
-        lowering but NO extra backend compile (a telemetry run keeps
-        zero post-warmup compiles).  None where the version/backend has
-        no cost analysis."""
+        lowering but NO extra backend compile.  None where the
+        version/backend has no cost analysis."""
         try:
-            cost = fn.lower(*args).cost_analysis()
+            return Trainer._lowered_cost(fn.lower(*args))
+        except Exception:
+            return None
+
+    @staticmethod
+    def _lowered_cost(lowered) -> Optional[dict]:
+        try:
+            cost = lowered.cost_analysis()
             if isinstance(cost, (list, tuple)):
                 cost = cost[0]
             flops = float(cost.get('flops', 0.0))
@@ -644,26 +668,40 @@ class Trainer:
         except Exception:
             return None
 
-    def train_program_cost(self, state: TrainerState, arrays
-                           ) -> Optional[dict]:
-        """AOT FLOPs/bytes of the train-step program for the shapes of
-        ``arrays`` (either wire) — the MFU/roofline numerator
-        (telemetry/goodput.py, OBSERVABILITY.md "Training goodput")."""
+    @staticmethod
+    def _shape_key(arrays) -> Tuple[str, tuple]:
+        """(the dispatch shape's key, its sizes): each NEW packed capacity
+        is one more jit specialization of the whole step program, and so
+        is each new touched-row capacity."""
+        if _packed(arrays):
+            shapes = (int(arrays[0].shape[1]),) + tuple(
+                int(a.size) for a in arrays[4:6])
+            return 'packed:' + ':'.join(map(str, shapes)), shapes
+        return 'planes:%d' % int(arrays[0].shape[0]), ()
+
+    def _first_sight(self, shape_key: str, state, arrays) -> None:
+        """First sight of a dispatch shape, on the telemetry and
+        PROFILE_DIR paths only: ONE lowering of its train-step program
+        gives the AOT FLOPs/bytes for the goodput ledger (the
+        MFU/roofline numerator, OBSERVABILITY.md "Training goodput";
+        telemetry) and the compiled text for the captures' legend. The
+        legend's compile is the build the first dispatch would have made,
+        or its cache hit: the dispatch that follows shares this lowering
+        and finds the executable on it, so a run counts the programs it
+        counted (56 of 56 on the chip), here in warm-up: no capture and
+        no steady window holds a compile."""
+        if shape_key in self._seen_keys:
+            return
+        self._seen_keys.add(shape_key)
         fn = (self._train_step_packed if _packed(arrays)
               else self._train_step)
-        return self._program_cost(fn, state, arrays)
-
-    def _maybe_record_step_cost(self, shape_key: str, state, arrays) -> None:
-        """First sight of a dispatch shape: capture its AOT step cost
-        into the goodput ledger (telemetry path; rides the same
-        first-sight cadence as the capacity tracker)."""
-        if shape_key in self._cost_keys:
-            return
-        self._cost_keys.add(shape_key)
-        cost = self.train_program_cost(state, arrays)
-        if cost is not None:
-            self._telemetry.goodput.set_step_cost(
-                shape_key, cost['flops'], cost['bytes_accessed'])
+        lowered = fn.lower(state, arrays)
+        if self._telemetry is not None:
+            cost = self._lowered_cost(lowered)
+            if cost is not None:
+                self._telemetry.goodput.set_step_cost(
+                    shape_key, cost['flops'], cost['bytes_accessed'])
+        self._legend.add(shape_key, lowered, state)
 
     def train_program_memory(self, state: TrainerState, arrays
                              ) -> Optional[dict]:
@@ -778,6 +816,7 @@ class Trainer:
                 watchdog.shutdown()
             if getattr(self, '_profiling', False):
                 jax.profiler.stop_trace()
+                self._legend.write(config.PROFILE_DIR)
                 self._profiling = False
             if self._telemetry is not None:
                 # final flush + stop any live on-demand capture, so a
@@ -913,10 +952,18 @@ class Trainer:
                     elif batch_num >= profile_stop_step and self._profiling:
                         jax.block_until_ready(state.params)
                         jax.profiler.stop_trace()
+                        self._legend.write(config.PROFILE_DIR)
                         self._profiling = False
                         profile_done = True
                         config.log('Profiler trace written to `%s`.'
                                    % config.PROFILE_DIR)
+                    if tele is None:
+                        # the legend needs the program with PROFILE_DIR
+                        # alone too (under telemetry: below)
+                        shape_key, _ = self._shape_key(arrays)
+                        self._first_sight(shape_key, state, arrays)
+                        if self._profiling:
+                            self._legend.ran(shape_key)
                 if tele is not None:
                     if not self._profiling:
                         # on-demand capture (TELEMETRY_TRACE_AT_STEP /
@@ -924,21 +971,16 @@ class Trainer:
                         # window holds the profiler
                         tele.trace.maybe_update(batch_num,
                                                 sync_tree=state.params)
-                    if _packed(arrays):
-                        # each NEW packed capacity = one more jit
-                        # specialization of the whole step program
-                        # (so is each new touched-row capacity)
-                        shapes = (int(arrays[0].shape[1]),) + tuple(
-                            int(a.size) for a in arrays[4:6])
-                        shape_key = 'packed:' + ':'.join(map(str, shapes))
+                    shape_key, shapes = self._shape_key(arrays)
+                    if shapes:
                         tele.capacity.observe(shapes[0], batch_num,
                                               rows=shapes[1:])
-                    else:
-                        shape_key = 'planes:%d' % int(arrays[0].shape[0])
                     # first sight of a dispatch shape: AOT step FLOPs/
-                    # bytes for the MFU gauges (lowering only — no
-                    # extra backend compile)
-                    self._maybe_record_step_cost(shape_key, state, arrays)
+                    # bytes for the MFU gauges and the text for the
+                    # captures' legend, from one lowering
+                    self._first_sight(shape_key, state, arrays)
+                    if self._profiling or tele.trace.active:
+                        self._legend.ran(shape_key)
                     with jax.profiler.StepTraceAnnotation(
                             'train', step_num=batch_num), \
                             tele.dispatch.time():

@@ -204,6 +204,16 @@ def test_profile_trace_capture_smoke(tmp_path):
     trainer.fit(state, lambda epoch: iter(batches), start_epoch=0)
     trace_files = list((tmp_path / 'trace').rglob('*'))
     assert any(f.is_file() for f in trace_files), 'no trace artifacts'
+    assert any(f.name.endswith('.xplane.pb') for f in trace_files)
+    # the legend beside it: the text of the one program the window ran
+    # (telemetry off, planes wire), whose op_name gives each part
+    programs = sorted(f.name for f in (tmp_path / 'trace'
+                                       / 'programs').iterdir())
+    assert programs == ['jit_train_step.planes-16.hlo.txt',
+                        'jit_train_step.planes-16.json']
+    text = (tmp_path / 'trace' / 'programs' / programs[0]).read_text()
+    for scope in ('c2v_encode', 'c2v_logits', 'c2v_ce', 'c2v_adam'):
+        assert scope in text, scope
 
 
 def test_checkpoint_metadata_mismatch_is_clear_error(tmp_path):
