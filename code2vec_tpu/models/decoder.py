@@ -432,6 +432,13 @@ COUNTERS: Tuple[str, ...] = ()
 COUNTS_STAT = 'expert_tokens'
 
 
+def step_kernels(platform: str) -> dict:
+    """The names of the model's own kernels ``make_step`` takes: none (the
+    attention and expert kernels are the library's, and
+    ``ops/lm_attention.py`` and ``ops/grouped_experts.py`` choose them)."""
+    return {}
+
+
 def ring_window(cfg: DecoderConfig) -> int:
     """Positions a slot's ring has to keep behind a query."""
     return cfg.sliding_window

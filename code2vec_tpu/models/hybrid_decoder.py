@@ -44,6 +44,7 @@ layer).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, List, Tuple
 
@@ -53,7 +54,8 @@ import numpy as np
 
 from code2vec_tpu.models.decoder import (_matmul, _rms_norm, _rotate,
                                          rope_inv_freq, take_row)
-from code2vec_tpu.ops import linear_attention, sparse_attention
+from code2vec_tpu.ops import linear_attention, pallas_sparse, \
+    sparse_attention
 from code2vec_tpu.ops.sparse_attention import SparseGeometry
 from code2vec_tpu.serving import lm_cache
 
@@ -287,15 +289,19 @@ def layer_pool_index(cfg: HybridConfig) -> List[int]:
 
 
 def make_step(cfg: HybridConfig, shape: StepShape,
-              geometry: lm_cache.CacheGeometry, dtype=jnp.bfloat16):
+              geometry: lm_cache.CacheGeometry, dtype=jnp.bfloat16,
+              sparse_stage2: str = 'jnp'):
     """The step function for one shape (jit it with ``cache`` donated).
 
     ``step(params, cache, prev_ids, batch)`` -> ``(cache, next_ids
-    [outputs], logits [outputs, vocab] float32, counts [sparse layers, 2])``
+    [outputs], logits [outputs, vocab] float32, counts [sparse layers, 3])``
     with ``counts`` the blocks chosen and the blocks visible, summed over
-    the step's sparse-branch queries and key/value heads.
+    the step's sparse-branch queries and key/value heads, and those
+    queries again where their stage 2 ran in a kernel.  ``sparse_stage2``
+    names stage 2's form (``STAGE2``, as ``step_kernels`` chooses it).
     """
     geo = cfg.sparse
+    stage2 = STAGE2[sparse_stage2]
     # the pages ONE sparse layer owns (its last takes the padding rows'
     # writes), as in models/decoder.py
     pool_pages, page_size = geometry.pool_layer_pages, geometry.page_size
@@ -380,7 +386,7 @@ def make_step(cfg: HybridConfig, shape: StepShape,
             """``work()`` where any token needs it, else zeros."""
             def nothing():
                 return (jnp.zeros(shape, jnp.float32),
-                        jnp.zeros((2,), jnp.int32))
+                        jnp.zeros((3,), jnp.int32))
             with jax.named_scope(prefix + name):
                 return jax.lax.cond(jnp.any(needed), work, nothing)
 
@@ -390,17 +396,13 @@ def make_step(cfg: HybridConfig, shape: StepShape,
                                qr[None], at[None], pages_of_row, pages, geo,
                                page_size)[0])(
                 q[:slots], positions[:slots], table[:slots])
-            return out, jnp.zeros((2,), jnp.int32)
+            return out, jnp.zeros((3,), jnp.int32)
 
         def sparse_rows():
-            def one(qr, at, lr, pages_of_row):
-                out, counted = sparse_attention.sparse_attention(
-                    qr[None], at[None], lr[None], row_means(pages_of_row),
-                    pages_of_row, pages, geo, page_size)
-                return out[0], counted
-            out, counted = jax.vmap(one)(q[:slots], positions[:slots],
-                                         live[:slots], table[:slots])
-            return out, counted.sum(0)
+            return sparse_attention.sparse_attention_rows(
+                q[:slots], positions[:slots], live[:slots],
+                jax.vmap(row_means)(table[:slots]), table[:slots], pages,
+                geo, page_size, kernel=stage2)
         rows = (slots,) + q.shape[1:]
         direct, _ = branch('dense_attention', plain[:slots], dense_rows,
                            rows)
@@ -412,13 +414,13 @@ def make_step(cfg: HybridConfig, shape: StepShape,
             def dense_chunk():
                 return (sparse_attention.dense_attention(
                     q[slots:], positions[slots:], pages_of_chunk, pages,
-                    geo, page_size), jnp.zeros((2,), jnp.int32))
+                    geo, page_size), jnp.zeros((3,), jnp.int32))
 
             def sparse_chunk():
                 return sparse_attention.sparse_attention_chunk(
                     q[slots:], positions[slots:], live[slots:],
                     row_means(pages_of_chunk), pages_of_chunk, pages, geo,
-                    page_size)
+                    page_size, kernel=stage2)
             rows = (chunk,) + q.shape[1:]
             more, _ = branch('dense_attention', plain[slots:], dense_chunk,
                              rows)
@@ -465,7 +467,7 @@ def make_step(cfg: HybridConfig, shape: StepShape,
         logits = _matmul(last, params['head'], dtype) * cfg.logit_scale
         next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         counts = (jnp.stack(counts) if counts
-                  else jnp.zeros((0, 2), jnp.int32))
+                  else jnp.zeros((0, 3), jnp.int32))
         return ({'pages': pages, 'pooled': pooled, 'states': states},
                 next_ids, logits, counts)
 
@@ -533,9 +535,25 @@ SLOT_GAUGE = 'serving/lm_state_pool_fill'
 #: the counters of the model's own that ``log_counts`` feeds
 COUNTERS = ('serving/lm_sparse_blocks_chosen_total',
             'serving/lm_sparse_blocks_visible_total',
-            'serving/lm_sparse_dense_branch_total')
+            'serving/lm_sparse_dense_branch_total',
+            'serving/lm_sparse_kernel_queries_total')
 #: the name ``stats()`` gives the sum of every step's counts
 COUNTS_STAT = 'sparse_blocks'
+
+
+#: stage 2 of the sparse layers by name: the ``jax.numpy`` form, the
+#: Pallas kernel, and the kernel in Pallas's TPU interpreter (tests)
+STAGE2 = {'jnp': None, 'pallas': pallas_sparse.attend_planned,
+          'interpret': functools.partial(pallas_sparse.attend_planned,
+                                         interpret=True)}
+
+
+def step_kernels(platform: str) -> dict:
+    """The names of the model's own kernels ``make_step`` takes, for step
+    programs that run on ``platform`` (``stats()['lm']`` reports them):
+    stage 2 of the sparse layers is the Pallas kernel on a TPU and the
+    ``jax.numpy`` form elsewhere."""
+    return {'sparse_stage2': 'pallas' if platform == 'tpu' else 'jnp'}
 
 
 def ring_window(cfg: HybridConfig) -> int:
@@ -550,7 +568,7 @@ def check_geometry(cfg: HybridConfig,
 
 
 def counts_shape(cfg: HybridConfig) -> Tuple[int, int]:
-    return cfg.mixer_types.count(SPARSE), 2
+    return cfg.mixer_types.count(SPARSE), 3
 
 
 def step_shape(cfg: HybridConfig, geometry: lm_cache.CacheGeometry,
@@ -650,13 +668,14 @@ class StepPlan:
 
 def log_counts(counts: np.ndarray, note: dict) -> Tuple[dict, dict]:
     """(what the step log keeps of a step's ``counts`` [sparse layers,
-    (chosen, visible)], {counter: its increment})."""
-    chosen, visible = (int(x) for x in counts.sum(axis=0))
+    (chosen, visible, kernel queries)], {counter: its increment})."""
+    chosen, visible, kernel = (int(x) for x in counts.sum(axis=0))
     dense_tokens = note['dense_tokens']
     return ({'blocks_chosen': chosen, 'blocks_visible': visible,
              'dense_tokens': dense_tokens},
             {'serving/lm_sparse_blocks_chosen_total': chosen,
              'serving/lm_sparse_blocks_visible_total': visible,
+             'serving/lm_sparse_kernel_queries_total': kernel,
              # a sparse layer took its dense branch for these tokens
              'serving/lm_sparse_dense_branch_total':
                  dense_tokens * counts.shape[0]})

@@ -51,14 +51,19 @@ initial block and the scored ones) are gathered query by query (``far``).
 The two parts share one softmax.
 
 Plain ``jax.numpy`` and ``lax`` gathers: the same code runs in the CPU tests
-and compiles for the chip.  The scores of stage 1 accumulate in float32
-from the operands as cached, so that the choice of blocks follows the cache
-and not a rounding of the scores.
+and compiles for the chip.  On a TPU stage 2 is instead the Pallas kernel
+of ``ops/pallas_sparse.py``, handed in as ``kernel``: it reads the same
+near range and far lists (``plan_blocks``) straight from the pool, with no
+gathered copy; ``attend_blocks`` stays its reference.  The scores of stage
+1 accumulate in float32 from the operands as cached, so that the choice of
+blocks follows the cache and not a rounding of the scores.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -262,6 +267,67 @@ def _shared_softmax(parts, values, specs):
     return out / jnp.where(total > 0, total, 1.0)[..., None]
 
 
+def _split(positions, live, chosen, geo: SparseGeometry):
+    """How stage 2 reads a tile's chosen blocks: the ``span`` blocks from
+    ``low`` (from the first live query's window to the last query) are
+    read once for the tile, as ``near`` [span] with each query's choice of
+    them ``picked`` [tokens, kv_heads, span]; what a query chose outside
+    them (before them: see ``plan_blocks``), at most ``slots``, is read for
+    it alone, ``far`` [tokens, kv_heads, slots] with ``held`` which slots
+    hold one."""
+    tokens, _, blocks = chosen.shape
+    block = geo.block_size
+    at = positions.astype(jnp.int32)
+    span = min(blocks, (geo.window_size + tokens) // block + 2)
+    alive = live > 0
+    first = jnp.min(jnp.where(alive, at, jnp.iinfo(jnp.int32).max))
+    first = jnp.where(jnp.any(alive), first, 0)
+    low = jnp.clip((first - geo.window_size + 1) // block, 0, blocks - span)
+    near = low + jnp.arange(span, dtype=jnp.int32)
+    picked = jax.lax.dynamic_slice_in_dim(chosen, low, span, axis=2)
+    inside = (jnp.arange(blocks, dtype=jnp.int32) >= low) \
+        & (jnp.arange(blocks, dtype=jnp.int32) < low + span)
+    slots = max(1, min(geo.topk, blocks) - geo.window_size // block)
+    far, held = listed(chosen & ~inside, slots)
+    return near, picked, far, held
+
+
+class BlockPlan(NamedTuple):
+    """What stage 2 reads for a tile, in pool blocks (``plan_blocks``)."""
+    live: jax.Array         # [] any query of the tile is live
+    near: jax.Array         # [span] the near range's pool blocks
+    near_mask: jax.Array    # [kv_heads, tokens, span x block] int8: the key
+    #                         is chosen, visible and its query live
+    far: jax.Array          # [tokens, kv_heads, slots] far pool blocks
+    far_count: jax.Array    # [tokens, kv_heads] slots held (0: not live)
+
+
+def plan_blocks(positions, live, chosen, table, geo: SparseGeometry,
+                page_size: int) -> BlockPlan:
+    """``attend_blocks``' reading of ``chosen`` for the kernel of
+    ``ops/pallas_sparse.py``: the same near range and far lists, as pool
+    blocks.  A far block lies before the near range, which begins at or
+    before every live query's window, so each of its keys is visible to
+    every live query: its mask is which slots are held."""
+    tokens = chosen.shape[0]
+    block = geo.block_size
+    per_page = page_size // block
+    at = positions.astype(jnp.int32)
+    alive = live > 0
+    near, picked, far, held = _split(positions, live, chosen, geo)
+    key_at = near[:, None] * block + jnp.arange(block, dtype=jnp.int32)
+    mask = (picked[..., None] & alive[:, None, None, None]
+            & (key_at[None, None] <= at[:, None, None, None]))
+    return BlockPlan(
+        live=jnp.any(alive),
+        near=pool_blocks_of(table, near, per_page),
+        near_mask=mask.transpose(1, 0, 2, 3).reshape(
+            chosen.shape[1], tokens, -1).astype(jnp.int8),
+        far=pool_blocks_of(table, far, per_page),
+        far_count=jnp.where(alive[:, None],
+                            jnp.sum(held, axis=-1), 0).astype(jnp.int32))
+
+
 def attend_blocks(q, positions, live, chosen, table, pool,
                   geo: SparseGeometry, page_size: int):
     """Stage 2 for a tile of consecutive queries.  ``chosen`` [tokens,
@@ -269,33 +335,22 @@ def attend_blocks(q, positions, live, chosen, table, pool,
     pages (already offset to its layer's slab).  Returns [tokens, q_heads,
     d] float32."""
     tokens, q_heads, d = q.shape
-    kv_heads, blocks = chosen.shape[1], chosen.shape[2]
+    kv_heads = chosen.shape[1]
     group = q_heads // kv_heads
     block = geo.block_size
     per_page = page_size // block
     at = positions.astype(jnp.int32)
     scale = 1.0 / math.sqrt(d)
     qg = q.reshape(tokens, kv_heads, group, d)
-    # local: the blocks from the first live query's window to the last
-    # live query, read once for the tile
-    span = min(blocks, (geo.window_size + tokens) // block + 2)
-    alive = live > 0
-    first = jnp.min(jnp.where(alive, at, jnp.iinfo(jnp.int32).max))
-    first = jnp.where(jnp.any(alive), first, 0)
-    low = jnp.clip((first - geo.window_size + 1) // block, 0, blocks - span)
-    near = low + jnp.arange(span, dtype=jnp.int32)
+    near, picked, far, held = _split(positions, live, chosen, geo)
+    # local: the near range read once for the tile
     where = pool_blocks_of(table, near, per_page)
     k_near, v_near = pool[0, where], pool[1, where]     # [span, kv, b, d]
     key_at = near[:, None] * block + jnp.arange(block, dtype=jnp.int32)
-    picked = jax.lax.dynamic_slice_in_dim(chosen, low, span, axis=2)
     near_mask = (picked[..., None]
                  & (key_at[None, None] <= at[:, None, None, None]))
     near_scores = _mixed('tkgd,nkbd->tkgnb', qg, k_near) * scale
     # far: what a query chose outside that range, gathered for it alone
-    inside = (jnp.arange(blocks, dtype=jnp.int32) >= low) \
-        & (jnp.arange(blocks, dtype=jnp.int32) < low + span)
-    slots = max(1, min(geo.topk, blocks) - geo.window_size // block)
-    far, held = listed(chosen & ~inside, slots)         # [t, kv, slots]
     where = pool_blocks_of(table, far, per_page)
     key_at = far[..., None] * block + jnp.arange(block, dtype=jnp.int32)
     far_mask = held[..., None] & (key_at <= at[:, None, None, None])
@@ -349,6 +404,20 @@ def dense_attention(q, positions, table, pool, geo: SparseGeometry,
     return out.reshape(-1, q_heads, d)[:tokens]
 
 
+def _select(q, positions, live, means, geo: SparseGeometry):
+    """Stage 1 for a tile: (chosen [tokens, kv_heads, blocks], (blocks
+    chosen, blocks visible) summed over the live queries and key/value
+    heads)."""
+    with jax.named_scope('sparse_select'):
+        chosen = choose(block_scores(q, positions, means, geo), geo.topk)
+    alive = (live > 0)
+    picked = jnp.sum(jnp.where(alive[:, None, None], chosen, False))
+    visible = jnp.sum(jnp.where(
+        alive, positions.astype(jnp.int32) // geo.block_size + 1, 0)) \
+        * chosen.shape[1]
+    return chosen, jnp.stack([picked, visible]).astype(jnp.int32)
+
+
 def sparse_attention(q, positions, live, means, table, pool,
                      geo: SparseGeometry, page_size: int):
     """Both stages for ``q`` [tokens, q_heads, d], consecutive queries of
@@ -356,24 +425,39 @@ def sparse_attention(q, positions, live, means, table, pool,
     (the others' outputs are unspecified but finite).  Returns the outputs
     float32 and (blocks chosen, blocks visible) summed over the live
     queries and key/value heads."""
-    with jax.named_scope('sparse_select'):
-        chosen = choose(block_scores(q, positions, means, geo), geo.topk)
+    chosen, counts = _select(q, positions, live, means, geo)
     with jax.named_scope('sparse_attention'):
         out = attend_blocks(q, positions, live, chosen, table, pool, geo,
                             page_size)
-    alive = (live > 0)
-    picked = jnp.sum(jnp.where(alive[:, None, None], chosen, False))
-    visible = jnp.sum(jnp.where(
-        alive, positions.astype(jnp.int32) // geo.block_size + 1, 0)) \
-        * chosen.shape[1]
-    return out, jnp.stack([picked, visible]).astype(jnp.int32)
+    return out, counts
+
+
+def planned(q, positions, live, means, table, geo: SparseGeometry,
+            page_size: int):
+    """``sparse_attention`` up to what its stage 2 reads: (``BlockPlan``,
+    counts) for the kernel of ``ops/pallas_sparse.py``."""
+    chosen, counts = _select(q, positions, live, means, geo)
+    with jax.named_scope('sparse_attention'):
+        plan = plan_blocks(positions, live, chosen, table, geo, page_size)
+    return plan, counts
+
+
+def _with_kernel_queries(counts, live, kernel):
+    """(blocks chosen, blocks visible, queries whose stage 2 ran in the
+    kernel)."""
+    queries = jnp.sum(live > 0) if kernel is not None else 0
+    return jnp.concatenate([counts, jnp.asarray(queries, jnp.int32)[None]])
 
 
 def sparse_attention_chunk(q, positions, live, means, table, pool,
                            geo: SparseGeometry, page_size: int,
-                           tile: int = QUERY_TILE):
+                           tile: int = QUERY_TILE, kernel=None):
     """``sparse_attention`` for a prefill chunk, ``tile`` queries at a
-    time; a tile with no live query is skipped."""
+    time; a tile with no live query is skipped.  ``kernel`` is stage 2 as
+    ``ops/pallas_sparse.py::attend_planned`` (None: ``attend_blocks``):
+    stage 1 still runs a tile at a time, stage 2 of every tile is one call
+    of it.  Returns the outputs and (blocks chosen, blocks visible, queries
+    whose stage 2 ran in the kernel)."""
     tokens, q_heads, d = q.shape
     tiles = -(-tokens // tile)
     pad = tiles * tile - tokens
@@ -381,20 +465,48 @@ def sparse_attention_chunk(q, positions, live, means, table, pool,
         q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
         positions = jnp.pad(positions, (0, pad))
         live = jnp.pad(live, (0, pad))
+    tiled = (q.reshape(tiles, tile, q_heads, d),
+             positions.reshape(tiles, tile), live.reshape(tiles, tile))
+    both = planned if kernel is not None else functools.partial(
+        sparse_attention, pool=pool)
 
     def one(xs):
         qt, pt, lt = xs
 
         def work():
-            return sparse_attention(qt, pt, lt, means, table, pool, geo,
-                                    page_size)
+            return both(qt, pt, lt, means, table, geo=geo,
+                        page_size=page_size)
 
         def skip():
-            return (jnp.zeros((tile, q_heads, d), jnp.float32),
-                    jnp.zeros((2,), jnp.int32))
+            return jax.tree_util.tree_map(
+                lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(work))
         return jax.lax.cond(jnp.any(lt > 0), work, skip)
+    done, counts = jax.lax.map(one, tiled)
+    if kernel is not None:
+        with jax.named_scope('sparse_attention'):
+            done = kernel(tiled[0], done, pool)
+    return (done.reshape(tiles * tile, q_heads, d)[:tokens],
+            _with_kernel_queries(counts.sum(0), live, kernel))
 
-    out, counts = jax.lax.map(one, (q.reshape(tiles, tile, q_heads, d),
-                                    positions.reshape(tiles, tile),
-                                    live.reshape(tiles, tile)))
-    return out.reshape(tiles * tile, q_heads, d)[:tokens], counts.sum(0)
+
+def sparse_attention_rows(q, positions, live, means, tables, pool,
+                          geo: SparseGeometry, page_size: int,
+                          kernel=None):
+    """Both stages for decode rows ``q`` [rows, q_heads, d], each of its
+    own sequence (``means`` [rows, strides, kv_heads, d], ``tables`` [rows,
+    pages]).  With a ``kernel`` stage 2 of every row is one call of it, and
+    a row that is not live copies nothing.  Returns what
+    ``sparse_attention_chunk`` does."""
+    def one(qr, at, lr, means_of_row, table_of_row):
+        if kernel is not None:
+            return planned(qr[None], at[None], lr[None], means_of_row,
+                           table_of_row, geo, page_size)
+        out, counted = sparse_attention(qr[None], at[None], lr[None],
+                                        means_of_row, table_of_row, pool,
+                                        geo, page_size)
+        return out[0], counted
+    done, counts = jax.vmap(one)(q, positions, live, means, tables)
+    if kernel is not None:
+        with jax.named_scope('sparse_attention'):
+            done = kernel(q[:, None], done, pool)[:, 0]
+    return done, _with_kernel_queries(counts.sum(0), live, kernel)

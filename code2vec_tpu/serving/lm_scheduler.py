@@ -33,8 +33,10 @@ the queue, without holding back the requests behind it.
 The loop knows no model.  It is handed the model's module
 (``LMRuntime.lib``, named by the family's entry in ``models/families.py``)
 and asks it for everything that is the model's own: the step's shape and
-program, the cache's arrays, the inputs a plan fills beside the common ones
-(``lib.StepPlan``) and what a step's counts mean (``lib.log_counts``).
+program (with the kernels of its own, ``lib.step_kernels``, chosen once by
+the platform), the cache's arrays, the inputs a plan fills beside the
+common ones (``lib.StepPlan``) and what a step's counts mean
+(``lib.log_counts``).
 ``models/decoder.py`` (sliding and full attention, experts: ring slots and
 pages) and ``models/hybrid_decoder.py`` (linear and block-sparse attention:
 state slots, pages and pooled keys) are such modules.
@@ -192,12 +194,18 @@ class LMRuntime:
     the step programs, one a shape.  ``lib`` is the model's module: the
     seam (its last section says what is asked of it)."""
 
-    def __init__(self, config, cfg, params, lib):
+    def __init__(self, config, cfg, params, lib, step_kernels=None):
         import jax
         import jax.numpy as jnp
+        from code2vec_tpu.parallel.mesh import mesh_platform
         self.cfg = cfg
         self.params = params
         self.lib = lib
+        # the names of the model's own kernels or their jax.numpy forms,
+        # decided once from the platform the step programs run on (a test
+        # hands them)
+        self.step_kernels = (lib.step_kernels(mesh_platform())
+                             if step_kernels is None else step_kernels)
         # float32 is the CPU tests' exact mode; the chip's kernels take
         # bfloat16
         self.dtype = (jnp.float32 if config.COMPUTE_DTYPE == 'float32'
@@ -232,7 +240,8 @@ class LMRuntime:
 
     def _program(self, shape, layout: dict):
         import jax
-        step = self.lib.make_step(self.cfg, shape, self.geometry, self.dtype)
+        step = self.lib.make_step(self.cfg, shape, self.geometry, self.dtype,
+                                  **self.step_kernels)
 
         def run(params, cache, prev_ids, packed):
             return step(params, cache, prev_ids,
@@ -845,6 +854,8 @@ class LMScheduler:
         return {
             # what the model's steps counted and its own counters
             self.runtime.lib.COUNTS_STAT: counts_total, **model,
+            # which of its own kernels the step programs were built with
+            'step_kernels': dict(self.runtime.step_kernels),
             'steps_total': self.steps_total.snapshot(),
             'tokens_total': self.tokens_total.snapshot(),
             'generated_tokens_total': self.generated_total.snapshot(),

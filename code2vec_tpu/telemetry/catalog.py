@@ -212,6 +212,10 @@ CATALOG: Dict[str, Dict[str, str]] = {
     'serving/lm_sparse_dense_branch_total': _m(
         COUNTER, 'calls', 'Sparse-layer calls that took the dense branch '
         '(a token within dense_len, a layer).'),
+    'serving/lm_sparse_kernel_queries_total': _m(
+        COUNTER, 'queries', 'Live sparse-layer queries whose stage 2 ran in '
+        'the Pallas kernel (ops/pallas_sparse.py), over layers; 0 where '
+        'the step programs run the jax.numpy form.'),
     # ---- serving resilience (admission control / rollover / breaker) ----
     'serving/shed_total': _m(COUNTER, 'requests', 'Requests rejected at '
                              'admission (queue bound, drain-estimate vs '

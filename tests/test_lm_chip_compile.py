@@ -10,6 +10,7 @@ All in this one file, the topology described inside a fixture (never while
 a module is imported), so that one worker loads the TPU's library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -135,7 +136,9 @@ def test_a_period_of_the_decode_step_compiles_and_updates_in_place(
 # ---------------------------------------------------------------------------
 # the linear-attention and block-sparse layers (models/hybrid_decoder.py)
 from code2vec_tpu.models import hybrid_decoder as hybrid_lib  # noqa: E402
-from code2vec_tpu.ops import linear_attention, sparse_attention  # noqa: E402
+from chipbench.layer_metrics import lmhybridkernels  # noqa: E402
+from code2vec_tpu.ops import linear_attention, pallas_sparse  # noqa: E402
+from code2vec_tpu.ops import sparse_attention  # noqa: E402
 
 SALA = {
     'attention_bias': False, 'attn_use_rope': False, 'head_dim': 128,
@@ -229,12 +232,60 @@ def test_block_sparse_attention_compiles_at_published_widths(one_chip,
         < int(np.prod(pool)) * 2 // 2
 
 
+@pytest.fixture
+def kernels_on_the_chip(monkeypatch):
+    """The kernel asks the platform of ``jax.devices()``, here the CPU's,
+    whether it may run compiled: steer it in the test."""
+    monkeypatch.setattr(pallas_sparse, 'resolve_interpret',
+                        lambda interpret, what, mesh=None: False)
+
+
+def kernel_scopes(text: str) -> set:
+    """What the benchmark's reader makes of the Pallas calls of a compiled
+    program: the kernel each custom call's instruction is counted under."""
+    scopes = lmhybridkernels.scopes_of(text)
+    calls = [re.match(r'\s*(?:ROOT\s+)?%?([\w.\-]+) = ', line)
+             for line in text.splitlines() if 'tpu_custom_call' in line]
+    assert calls and all(calls)
+    return {scopes.get(call.group(1)) for call in calls}
+
+
+@pytest.mark.parametrize('rows,tokens', [(32, 32), (8, 1)],
+                         ids=['chunk-1024', 'decode-rows-8'])
+def test_stage_2_kernel_compiles_at_published_widths(
+        one_chip, kernels_on_the_chip, rows, tokens):
+    """The kernel alone over the cell's pool: a 1,024 chunk's 32 tiles of
+    32 queries, or eight decode rows; the benchmark's reader counts its
+    instruction under ``sparse_attention``."""
+    pool = (2, (SALA_POOL_PAGES + 1) * 2, 2, 64, 128)
+    span = (SALA_GEO.window_size + tokens) // 64 + 2
+    slots = SALA_GEO.topk - SALA_GEO.window_size // 64
+
+    def attend(q, live, near, mask, far, count, pages):
+        plan = sparse_attention.BlockPlan(live, near, mask, far, count)
+        with jax.named_scope('sparse_attention'):
+            return pallas_sparse.attend_planned(q, plan, pages)
+    compiled = jax.jit(attend).lower(
+        shaped(one_chip, (rows, tokens, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (rows,), jnp.bool_),
+        shaped(one_chip, (rows, span), jnp.int32),
+        shaped(one_chip, (rows, 2, tokens, span * 64), jnp.int8),
+        shaped(one_chip, (rows, tokens, 2, slots), jnp.int32),
+        shaped(one_chip, (rows, tokens, 2), jnp.int32),
+        shaped(one_chip, pool, jnp.bfloat16)).compile()
+    assert kernel_scopes(compiled.as_text()) == {'sparse_attention'}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize('chunk', [2048, 0], ids=['chunk', 'decode-only'])
-def test_a_period_of_the_hybrid_step_compiles_and_fits(one_chip, chunk):
+def test_a_period_of_the_hybrid_step_compiles_and_fits(
+        one_chip, kernels_on_the_chip, chunk):
     """A sparse layer and three lightning layers, a 2,048 chunk beside 8
-    decode rows (or the rows alone) at the cell's pool sizes: every pool is
-    donated and aliased, no step copies or re-lays one, and the
-    temporaries leave the chip room for sixteen layers' weights."""
+    decode rows (or the rows alone) at the cell's pool sizes, with the
+    kernels a TPU's step programs take: every pool is donated and aliased,
+    no step copies or re-lays one, the temporaries leave the chip room for
+    sixteen layers' weights, and stage 2 is the kernel, counted under
+    ``sparse_attention``."""
     cfg = hybrid_lib.HybridConfig.from_dict(SALA)
     g = lm_cache.CacheGeometry.make(
         page_size=128, window=0, slots=SALA_SLOTS,
@@ -246,7 +297,8 @@ def test_a_period_of_the_hybrid_step_compiles_and_fits(one_chip, chunk):
         slots=SALA_SLOTS, full_seqs=SALA_SLOTS + (1 if chunk else 0),
         full_pages=g.pages_per_seq,
         strides=SALA_SLOTS + (chunk // 16 + 1 if chunk else 0))
-    step = hybrid_lib.make_step(cfg, shape, g)
+    step = hybrid_lib.make_step(cfg, shape, g,
+                                **hybrid_lib.step_kernels('tpu'))
     layout = lm_scheduler.pack_layout(hybrid_lib.batch_shapes(shape))
 
     def run(params, cache, prev_ids, packed):
@@ -266,3 +318,4 @@ def test_a_period_of_the_hybrid_step_compiles_and_fits(one_chip, chunk):
                 for c in cache.values())
     assert memory.alias_size_in_bytes >= pools
     assert memory.temp_size_in_bytes < (640 if chunk else 64) * 2 ** 20
+    assert kernel_scopes(compiled.as_text()) == {'sparse_attention'}

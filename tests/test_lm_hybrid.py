@@ -705,6 +705,54 @@ def test_step_log_says_what_each_step_carried(exact):
     assert all(s['blocks_chosen'] == 2 * 4 for s in decodes)
 
 
+def test_the_kernel_counter_reads_the_queries_stage_2_ran_there(
+        tmp_path_factory):
+    """``serving/lm_sparse_kernel_queries_total`` is cataloged and in
+    ``stats()['lm']``: 0 where the step programs run the ``jax.numpy``
+    stage 2 (what the CPU's platform chooses), and the live sparse queries
+    times the sparse layers where the test hands the runtime the kernel in
+    the interpreter; both paths choose the same blocks and give the same
+    logits."""
+    from code2vec_tpu.serving.engine import ServingEngine
+    from code2vec_tpu.serving.lm_scheduler import LMRuntime
+    from code2vec_tpu.telemetry import catalog
+    name = 'serving/lm_sparse_kernel_queries_total'
+    assert name in catalog.CATALOG and name in hybrid_lib.COUNTERS
+    config = tiny_config(mixers=(S, L, S, L))
+    model, plain = build(tmp_path_factory, config, LM_CHUNK_BUCKETS='8')
+    runtime = LMRuntime(model.config, model.decoder_config, model.params,
+                        model.lib,
+                        step_kernels={'sparse_stage2': 'interpret'})
+    kernel = ServingEngine(model.config, None, model.params, None,
+                           decode_table=None, lm_runtime=runtime,
+                           log=model.log)
+    try:
+        prompt = np.random.default_rng(12).integers(0, 64, 30)
+        before = {e: e.stats()['lm'] for e in (plain, kernel)}
+        results = {e: generate(e, prompt, 4) for e in (plain, kernel)}
+        after = {e: e.stats()['lm'] for e in (plain, kernel)}
+
+        def grew(engine, key):
+            return after[engine][key] - before[engine][key]
+        assert after[plain]['step_kernels'] == {'sparse_stage2': 'jnp'}
+        assert after[kernel]['step_kernels'] == {
+            'sparse_stage2': 'interpret'}
+        assert after[plain]['sparse_kernel_queries_total'] == 0
+        # prompt positions 24..29 and decoded 30..32 are past dense_len 24,
+        # in two sparse layers
+        assert grew(kernel, 'sparse_kernel_queries_total') == (6 + 3) * 2
+        assert grew(kernel, 'sparse_blocks_chosen_total') \
+            == grew(plain, 'sparse_blocks_chosen_total') > 0
+        np.testing.assert_array_equal(results[kernel].token_ids,
+                                      results[plain].token_ids)
+        np.testing.assert_allclose(program_logits(results[kernel]),
+                                   program_logits(results[plain]),
+                                   atol=TOLERANCE)
+    finally:
+        kernel.close()
+        plain.close()
+
+
 # ------------------------------------------------------------ the traffic
 MIX = {'rate_per_s': 3.0, 'lead_in_s': 6.0,
        'sessions': {'count': 8, 'median': 65536, 'sigma': 0.5, 'min': 32768,
