@@ -376,7 +376,9 @@ class Config:
     # 'mellum' (a decoder-only token language model served through the
     # same ServingEngine, tier 'generate'; models/decoder.py) or
     # 'minicpm_sala' (linear-attention and block-sparse layers mixed, the
-    # same tier and loop; models/hybrid_decoder.py).
+    # same tier and loop; models/hybrid_decoder.py) or 'mistral4' (latent
+    # attention over latent pages, a share of the routed experts and a
+    # shared expert; models/latent_decoder.py).
     MODEL_FAMILY: str = 'code2vec'
     # The decoder's published config.json (hidden sizes, layer_types,
     # rope_parameters ...). Weights are the program's own seeded init
@@ -389,6 +391,13 @@ class Config:
     # middle stage; models/hybrid_decoder.py reads it, a mellum holds the
     # first layers).
     first_hidden_layer: int = 0
+    # ... and, where the stack's layers are divided over chips, the routed
+    # experts of each layer and the rows of the vocabulary this process
+    # holds, named as config.json names them (models/latent_decoder.py
+    # reads them: the experts from config.json's first_held_expert, the
+    # vocabulary's first rows). 0 = all of them.
+    n_routed_experts: int = 0
+    vocab_size: int = 0
     LM_PARAM_SEED: int = 0
     # Sequences resident at once: decode rows of a step and slots of the
     # sliding layers' ring pool (serving/lm_cache.py).
@@ -1435,9 +1444,11 @@ class Config:
         if self.HANG_WATCHDOG_SECS < 0:
             raise ValueError('config.HANG_WATCHDOG_SECS must be >= 0 '
                              '(0 disables the watchdog).')
-        if self.MODEL_FAMILY not in {'code2vec', 'mellum', 'minicpm_sala'}:
+        if self.MODEL_FAMILY not in {'code2vec', 'mellum', 'minicpm_sala',
+                                     'mistral4'}:
             raise ValueError("config.MODEL_FAMILY must be in "
-                             "{'code2vec', 'mellum', 'minicpm_sala'}.")
+                             "{'code2vec', 'mellum', 'minicpm_sala', "
+                             "'mistral4'}.")
         self.lm_chunk_buckets  # raises on malformed bucket specs
         if min(self.LM_MAX_SEQS, self.LM_PAGE_SIZE, self.LM_PAGE_POOL_PAGES,
                self.LM_MAX_CONTEXT, self.LM_WINDOW_SUBCHUNK) < 1:

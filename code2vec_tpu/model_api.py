@@ -943,10 +943,11 @@ class DecoderLMModel:
                              % config.MODEL_FAMILY)
         with open(config.LM_CONFIG_PATH, 'r') as f:
             published = json.load(f)
-        if config.num_hidden_layers:
-            published['num_hidden_layers'] = config.num_hidden_layers
-        if config.first_hidden_layer:
-            published['first_hidden_layer'] = config.first_hidden_layer
+        # what of the published stack this process holds
+        for key in ('num_hidden_layers', 'first_hidden_layer',
+                    'n_routed_experts', 'vocab_size'):
+            if getattr(config, key):
+                published[key] = getattr(config, key)
         # the family's module: its configuration, weights and step program
         from code2vec_tpu.models.families import family_of
         lib = importlib.import_module(family_of(config).module)

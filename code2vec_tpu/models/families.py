@@ -45,8 +45,10 @@ def _build_code2vec(config):
 
 
 def _decoder_param_specs(params):
-    """Every array whole on every device: the decoder serves from one chip
-    (its expert layer is not yet told which experts it holds)."""
+    """Every array whole on every device: a decoder serves from one chip.
+    What that chip holds of a layer is the model's configuration to say (a
+    share of the experts: ``n_routed_experts`` from ``first_held_expert``,
+    ``ops/grouped_experts.py``), not a sharding of the arrays here."""
     import jax
     from jax.sharding import PartitionSpec
     return jax.tree_util.tree_map(lambda _: PartitionSpec(), params)
@@ -69,6 +71,8 @@ FAMILIES = {
         reference='chipbench/reference.py',
         module='code2vec_tpu.models.functional',
         build=_build_code2vec),
+    # every expert of each layer held on one chip: the expert layer can hold
+    # a share (the mistral4 family's), this configuration holds them all
     'mellum': ModelFamily(
         name='mellum',
         input_layout='a prompt of token ids and max_new_tokens; a step is '
@@ -90,6 +94,18 @@ FAMILIES = {
         param_specs=_decoder_param_specs,
         reference='chipbench/reference_minicpm_sala.py',
         module='code2vec_tpu.models.hybrid_decoder',
+        build=_build_decoder),
+    'mistral4': ModelFamily(
+        name='mistral4',
+        input_layout='a prompt of token ids (no image inputs), '
+                     'max_new_tokens and optionally a session whose cache '
+                     'stays resident between turns; a step is a flat batch '
+                     'of tokens with per-sequence page tables of latent '
+                     'pages (models/latent_decoder.py::batch_shapes)',
+        tiers=('generate',),
+        param_specs=_decoder_param_specs,
+        reference='chipbench/reference_mistral4.py',
+        module='code2vec_tpu.models.latent_decoder',
         build=_build_decoder),
 }
 

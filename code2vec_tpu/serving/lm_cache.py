@@ -17,6 +17,12 @@ kept on the host as bookkeeping and on the device as arrays
   such layer, whatever the context's length.  A block-sparse layer's pooled
   keys (one row every ``kernel_stride`` positions) live page for page beside
   its pages and need no bookkeeping of their own.
+- **Latent pages** serve multi-head latent attention
+  (``models/latent_decoder.py::zero_cache``): a page holds its positions'
+  ``[c | rotated kr]`` latents, ``[kv_lora + rope, page_size]`` a layer,
+  in place of every head's keys and values.  They are the page pool's
+  pages, leased and kept under sessions as the others are; a slot is then
+  a decode row and holds nothing of its own.
 
 Held as one kind of cache, every layer would pay the full layers' price.
 A request is admitted only when a slot is free and the page pool can hold

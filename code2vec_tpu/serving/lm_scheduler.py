@@ -38,8 +38,10 @@ the platform), the cache's arrays, the inputs a plan fills beside the
 common ones (``lib.StepPlan``) and what a step's counts mean
 (``lib.log_counts``).
 ``models/decoder.py`` (sliding and full attention, experts: ring slots and
-pages) and ``models/hybrid_decoder.py`` (linear and block-sparse attention:
-state slots, pages and pooled keys) are such modules.
+pages), ``models/hybrid_decoder.py`` (linear and block-sparse attention:
+state slots, pages and pooled keys) and ``models/latent_decoder.py``
+(latent attention, a share of the experts: latent pages alone) are such
+modules.
 """
 from __future__ import annotations
 
@@ -350,8 +352,11 @@ class LMScheduler:
             'serving/lm_prefilled_positions_total')
         self.session_wait_timer = Timer('serving/lm_session_wait_ms')
         # a slot is what the model keeps in it: its gauge is the model's
+        # (a model that keeps nothing a slot counts the slots alone)
+        self.bare_slot_fill = Gauge('serving/lm_slot_fill')
         self.slot_fill = {gauge.name: gauge for gauge in
-                          (self.ring_fill, self.state_fill)}[lib.SLOT_GAUGE]
+                          (self.ring_fill, self.state_fill,
+                           self.bare_slot_fill)}[lib.SLOT_GAUGE]
         # the model's own counters, fed by its reading of a step's counts
         self.model_counters = {name: Counter(name) for name in lib.COUNTERS}
 
@@ -831,7 +836,8 @@ class LMScheduler:
         ``log_counts`` keeps of the step's counts (``experts_touched``
         [layers] of ``models/decoder.py``; ``blocks_chosen``,
         ``blocks_visible`` and ``dense_tokens`` of
-        ``models/hybrid_decoder.py``)."""
+        ``models/hybrid_decoder.py``; ``experts_touched`` and
+        ``held_choices`` of ``models/latent_decoder.py``)."""
         with self._log_lock:
             return list(self._step_log)
 
@@ -869,6 +875,7 @@ class LMScheduler:
             'tokens_per_step': self.tokens_per_step.snapshot(),
             'running': self.running_gauge.snapshot(),
             'state_pool_fill': self.state_fill.snapshot(),
+            'slot_fill': self.bare_slot_fill.snapshot(),
             'sessions_resident': self.sessions_gauge.snapshot(),
             'resident_positions_total':
                 self.resident_positions_total.snapshot(),

@@ -216,6 +216,24 @@ CATALOG: Dict[str, Dict[str, str]] = {
         COUNTER, 'queries', 'Live sparse-layer queries whose stage 2 ran in '
         'the Pallas kernel (ops/pallas_sparse.py), over layers; 0 where '
         'the step programs run the jax.numpy form.'),
+    'serving/lm_slot_fill': _m(
+        GAUGE, 'fraction', 'Decode slots in use / slots, for a model that '
+        'keeps nothing of its own a slot (latent attention: its cache is '
+        'pages alone).'),
+    'serving/lm_routing_choices_total': _m(
+        COUNTER, 'choices', 'Routing choices of the expert layers: valid '
+        'tokens x experts a token x layers, wherever the chosen experts '
+        'are held.'),
+    'serving/lm_held_choices_total': _m(
+        COUNTER, 'choices', 'Those choices that fell on an expert this '
+        'chip holds (the rest are other chips\' share), counted on the '
+        'device.'),
+    'serving/lm_latent_positions_read_total': _m(
+        COUNTER, 'positions', 'Latent positions the decode rows\' absorbed '
+        'attention read, over layers.'),
+    'serving/lm_latent_positions_upprojected_total': _m(
+        COUNTER, 'positions', 'History positions the prompt chunks\' '
+        'expanded attention up-projected, over layers.'),
     # ---- serving resilience (admission control / rollover / breaker) ----
     'serving/shed_total': _m(COUNTER, 'requests', 'Requests rejected at '
                              'admission (queue bound, drain-estimate vs '

@@ -87,6 +87,10 @@ def language_model_pieces(family):
         from chipbench import reference_minicpm_sala
         from chipbench.runners import serve_lm_sessions
         return serve_lm_sessions, reference_minicpm_sala
+    if family == 'mistral4':
+        from chipbench import reference_mistral4
+        from chipbench.runners import serve_lm_latent
+        return serve_lm_latent, reference_mistral4
     raise AssertionError('no reference is known for family %r' % family)
 
 
@@ -94,7 +98,8 @@ def language_model_agrees_at_toy_width(tmp_path, name, family):
     """A language model's configuration at its own ``rehearsal`` widths, in
     float32: a prompt longer than a ring and than ``dense_len``, through
     chunked prefill and decode (every layer kind the widths hold, the
-    sparse branch where there is one), against the family's reference."""
+    sparse branch where there is one, latent pages and a share of the
+    experts where the model has them), against the family's reference."""
     from chipbench import run
     from code2vec_tpu import model_api
     runner, reference = language_model_pieces(family)
@@ -119,6 +124,14 @@ def language_model_agrees_at_toy_width(tmp_path, name, family):
             lm = engine.stats()['lm']
             assert lm['sparse_blocks_chosen_total'] > 0   # the sparse branch
             assert lm['sparse_dense_branch_total'] > 0    # and the dense one
+        if family == 'mistral4':
+            lm = engine.stats()['lm']
+            # decode rows read latent pages, chunks up-projected them, and
+            # some of the routing choices fell on the experts held
+            assert lm['latent_positions_read_total'] > 0
+            assert lm['latent_positions_upprojected_total'] > 0
+            assert 0 < lm['held_choices_total'] < \
+                lm['routing_choices_total']
     wanted = np.asarray(reference.forward(
         model_config, runner.reference_weights(model.params, model_config),
         np.concatenate([prompt, result.token_ids[:-1]]),

@@ -319,3 +319,132 @@ def test_a_period_of_the_hybrid_step_compiles_and_fits(
     assert memory.alias_size_in_bytes >= pools
     assert memory.temp_size_in_bytes < (640 if chunk else 64) * 2 ** 20
     assert kernel_scopes(compiled.as_text()) == {'sparse_attention'}
+
+
+# ---------------------------------------------------------------------------
+# latent attention and a share of the experts (models/latent_decoder.py)
+from chipbench.layer_metrics import lmlatentkernels  # noqa: E402
+from code2vec_tpu.models import latent_decoder as latent_lib  # noqa: E402
+from code2vec_tpu.ops import pallas_latent  # noqa: E402
+
+MISTRAL4 = {
+    'attention_bias': False, 'first_k_dense_replace': 0, 'hidden_act': 'silu',
+    'hidden_size': 4096, 'kv_lora_rank': 256, 'mlp_bias': False,
+    'moe_intermediate_size': 2048, 'n_group': 1, 'n_routed_experts': 32,
+    'n_routed_experts_published': 128, 'first_held_expert': 0,
+    'n_shared_experts': 1, 'norm_topk_prob': True, 'num_attention_heads': 32,
+    'num_experts_per_tok': 4, 'q_lora_rank': 1024, 'qk_nope_head_dim': 64,
+    'qk_rope_head_dim': 64, 'v_head_dim': 128, 'rms_norm_eps': 1e-6,
+    'rope_interleave': True, 'routed_scaling_factor': 1,
+    'tie_word_embeddings': False, 'topk_group': 1, 'vocab_size': 32768,
+    'rope_parameters': {
+        'beta_fast': 32, 'beta_slow': 1, 'factor': 128,
+        'llama_4_scaling_beta': 0.1, 'mscale': 1, 'mscale_all_dim': 1,
+        'original_max_position_embeddings': 8192, 'rope_theta': 10000,
+        'rope_type': 'yarn'},
+    # one layer: every layer is the same
+    'num_hidden_layers': 1}
+#: the cell's pool: 16 decode rows, 8,000 pages of 128, contexts to 144K
+MISTRAL4_POOL_PAGES, MISTRAL4_SEQ_PAGES = 8000, 1152
+
+
+@pytest.fixture
+def latent_kernel_on_the_chip(monkeypatch):
+    monkeypatch.setattr(pallas_latent, 'resolve_interpret',
+                        lambda interpret, what, mesh=None: False)
+    monkeypatch.setattr(grouped_experts, 'on_tpu', lambda: True)
+
+
+def test_latent_decode_kernel_compiles_at_published_widths(
+        one_chip, latent_kernel_on_the_chip):
+    """The absorbed kernel alone over six layers' pool: sixteen decode
+    rows, the pool read where it lies, no copy of it."""
+    def decode(q, pool, lengths, tables):
+        with jax.named_scope('lm/latent_decode'):
+            return pallas_latent.absorbed_decode(q, pool, lengths, tables,
+                                                 kv_lora=256, scale=0.19)
+    compiled = jax.jit(decode).lower(
+        shaped(one_chip, (16, 32, 320), jnp.bfloat16),
+        shaped(one_chip, (6 * (MISTRAL4_POOL_PAGES + 1), 320, 128),
+               jnp.bfloat16),
+        shaped(one_chip, (16,), jnp.int32),
+        shaped(one_chip, (16, MISTRAL4_SEQ_PAGES), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    calls = [re.match(r'\s*(?:ROOT\s+)?%?([\w.\-]+) = ', line).group(1)
+             for line in text.splitlines() if 'tpu_custom_call' in line]
+    scopes = lmlatentkernels.scopes_of(text)
+    assert {scopes.get(call) for call in calls} == {'latent_decode'}
+
+
+@pytest.mark.parametrize('tokens', [2048, 1536, 384, 256],
+                         ids=['chunk-2048', 'chunk-1536', 'chunk-384',
+                              'chunk-256'])
+def test_latent_prefill_kernel_compiles_at_published_widths(
+        one_chip, latent_kernel_on_the_chip, tokens):
+    """The expanded kernel alone over a layer's pool and the longest page
+    table: its scores stay in fast memory (no temporaries but the inputs'
+    head-major copies)."""
+    def prefill(q_nope, q_rope, first, table, kv_len, pool, w_kvb):
+        with jax.named_scope('lm/latent_prefill'):
+            return pallas_latent.expanded_prefill(
+                q_nope, q_rope, first, table, kv_len, pool, w_kvb,
+                kv_lora=256, scale=0.19)
+    compiled = jax.jit(prefill).lower(
+        shaped(one_chip, (tokens, 32, 64), jnp.bfloat16),
+        shaped(one_chip, (tokens, 32, 64), jnp.bfloat16),
+        shaped(one_chip, (), jnp.int32),
+        shaped(one_chip, (MISTRAL4_SEQ_PAGES,), jnp.int32),
+        shaped(one_chip, (), jnp.int32),
+        shaped(one_chip, (MISTRAL4_POOL_PAGES + 1, 320, 128), jnp.bfloat16),
+        shaped(one_chip, (256, 32, 192), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    calls = [re.match(r'\s*(?:ROOT\s+)?%?([\w.\-]+) = ', line).group(1)
+             for line in text.splitlines() if 'tpu_custom_call' in line]
+    scopes = lmlatentkernels.scopes_of(text)
+    assert {scopes.get(call) for call in calls} == {'latent_prefill'}
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize('chunk', [2048, 768, 0],
+                         ids=['chunk', 'chunk-768', 'decode-only'])
+def test_a_layer_of_the_latent_step_compiles_and_fits(
+        one_chip, latent_kernel_on_the_chip, chunk):
+    """One layer (every layer is the same) of the step at the cell's pool
+    and page table, a 2,048 chunk beside 16 decode rows or the rows alone:
+    the latent pool is donated and aliased, no step copies or re-lays it
+    (a position's column written alone made the compiler lay the whole
+    pool out position-major, padded, and copy it every step), and the
+    benchmark's reader counts the two latent kernels and the held experts'
+    grouped products under their scopes."""
+    cfg = latent_lib.LatentConfig.from_dict(MISTRAL4)
+    g = lm_cache.CacheGeometry.make(
+        page_size=128, window=0, slots=16, pool_pages=MISTRAL4_POOL_PAGES,
+        max_context=MISTRAL4_SEQ_PAGES * 128, max_chunk=2048)
+    shape = latent_lib.step_shape(cfg, g, chunk, 512)
+    step = latent_lib.make_step(cfg, shape, g,
+                                **latent_lib.step_kernels('tpu'))
+    layout = lm_scheduler.pack_layout(latent_lib.batch_shapes(shape))
+
+    def run(params, cache, prev_ids, packed):
+        return step(params, cache, prev_ids,
+                    lm_scheduler.unpack_batch(packed, layout))
+    params = jax.tree_util.tree_map(
+        lambda s: shaped(one_chip, s.shape, s.dtype),
+        latent_lib.param_shapes(cfg))
+    cache = {name: shaped(one_chip, dims, jnp.bfloat16)
+             for name, dims in latent_lib.cache_shapes(cfg, g).items()}
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, shaped(one_chip, (17,), jnp.int32),
+        shaped(one_chip, (layout[''][0],), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    pool = int(np.prod(cache['latents'].shape)) * 2
+    assert memory.alias_size_in_bytes >= pool
+    assert memory.temp_size_in_bytes < (320 if chunk else 32) * 2 ** 20
+    text = compiled.as_text()
+    scopes = lmlatentkernels.scopes_of(text)
+    calls = [re.match(r'\s*(?:ROOT\s+)?%?([\w.\-]+) = ', line).group(1)
+             for line in text.splitlines() if 'tpu_custom_call' in line]
+    assert {scopes.get(call) for call in calls} == {
+        'latent_decode', 'experts'} | ({'latent_prefill'} if chunk else set())
