@@ -294,11 +294,12 @@ def make_step(cfg: HybridConfig, shape: StepShape,
     """The step function for one shape (jit it with ``cache`` donated).
 
     ``step(params, cache, prev_ids, batch)`` -> ``(cache, next_ids
-    [outputs], logits [outputs, vocab] float32, counts [sparse layers, 3])``
+    [outputs], logits [outputs, vocab] float32, counts [sparse layers, 4])``
     with ``counts`` the blocks chosen and the blocks visible, summed over
-    the step's sparse-branch queries and key/value heads, and those
-    queries again where their stage 2 ran in a kernel.  ``sparse_stage2``
-    names stage 2's form (``STAGE2``, as ``step_kernels`` chooses it).
+    the step's sparse-branch queries and key/value heads, those queries
+    again where their stage 2 ran in a kernel, and the stride rows the
+    decode rows' stage 1 scored.  ``sparse_stage2`` names stage 2's form
+    (``STAGE2``, as ``step_kernels`` chooses it).
     """
     geo = cfg.sparse
     stage2 = STAGE2[sparse_stage2]
@@ -386,7 +387,7 @@ def make_step(cfg: HybridConfig, shape: StepShape,
             """``work()`` where any token needs it, else zeros."""
             def nothing():
                 return (jnp.zeros(shape, jnp.float32),
-                        jnp.zeros((3,), jnp.int32))
+                        jnp.zeros((4,), jnp.int32))
             with jax.named_scope(prefix + name):
                 return jax.lax.cond(jnp.any(needed), work, nothing)
 
@@ -396,13 +397,12 @@ def make_step(cfg: HybridConfig, shape: StepShape,
                                qr[None], at[None], pages_of_row, pages, geo,
                                page_size)[0])(
                 q[:slots], positions[:slots], table[:slots])
-            return out, jnp.zeros((3,), jnp.int32)
+            return out, jnp.zeros((4,), jnp.int32)
 
         def sparse_rows():
             return sparse_attention.sparse_attention_rows(
-                q[:slots], positions[:slots], live[:slots],
-                jax.vmap(row_means)(table[:slots]), table[:slots], pages,
-                geo, page_size, kernel=stage2)
+                q[:slots], positions[:slots], live[:slots], pooled,
+                table[:slots], pages, geo, page_size, kernel=stage2)
         rows = (slots,) + q.shape[1:]
         direct, _ = branch('dense_attention', plain[:slots], dense_rows,
                            rows)
@@ -414,13 +414,16 @@ def make_step(cfg: HybridConfig, shape: StepShape,
             def dense_chunk():
                 return (sparse_attention.dense_attention(
                     q[slots:], positions[slots:], pages_of_chunk, pages,
-                    geo, page_size), jnp.zeros((3,), jnp.int32))
+                    geo, page_size), jnp.zeros((4,), jnp.int32))
 
             def sparse_chunk():
-                return sparse_attention.sparse_attention_chunk(
+                out, counts = sparse_attention.sparse_attention_chunk(
                     q[slots:], positions[slots:], live[slots:],
                     row_means(pages_of_chunk), pages_of_chunk, pages, geo,
                     page_size, kernel=stage2)
+                # no decode row's stride rows
+                return out, jnp.concatenate(
+                    [counts, jnp.zeros((1,), jnp.int32)])
             rows = (chunk,) + q.shape[1:]
             more, _ = branch('dense_attention', plain[slots:], dense_chunk,
                              rows)
@@ -467,7 +470,7 @@ def make_step(cfg: HybridConfig, shape: StepShape,
         logits = _matmul(last, params['head'], dtype) * cfg.logit_scale
         next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         counts = (jnp.stack(counts) if counts
-                  else jnp.zeros((0, 3), jnp.int32))
+                  else jnp.zeros((0, 4), jnp.int32))
         return ({'pages': pages, 'pooled': pooled, 'states': states},
                 next_ids, logits, counts)
 
@@ -536,7 +539,8 @@ SLOT_GAUGE = 'serving/lm_state_pool_fill'
 COUNTERS = ('serving/lm_sparse_blocks_chosen_total',
             'serving/lm_sparse_blocks_visible_total',
             'serving/lm_sparse_dense_branch_total',
-            'serving/lm_sparse_kernel_queries_total')
+            'serving/lm_sparse_kernel_queries_total',
+            'serving/lm_sparse_decode_stride_rows_total')
 #: the name ``stats()`` gives the sum of every step's counts
 COUNTS_STAT = 'sparse_blocks'
 
@@ -568,7 +572,7 @@ def check_geometry(cfg: HybridConfig,
 
 
 def counts_shape(cfg: HybridConfig) -> Tuple[int, int]:
-    return cfg.mixer_types.count(SPARSE), 3
+    return cfg.mixer_types.count(SPARSE), 4
 
 
 def step_shape(cfg: HybridConfig, geometry: lm_cache.CacheGeometry,
@@ -668,14 +672,16 @@ class StepPlan:
 
 def log_counts(counts: np.ndarray, note: dict) -> Tuple[dict, dict]:
     """(what the step log keeps of a step's ``counts`` [sparse layers,
-    (chosen, visible, kernel queries)], {counter: its increment})."""
-    chosen, visible, kernel = (int(x) for x in counts.sum(axis=0))
+    (chosen, visible, kernel queries, decode stride rows)], {counter: its
+    increment})."""
+    chosen, visible, kernel, strides = (int(x) for x in counts.sum(axis=0))
     dense_tokens = note['dense_tokens']
     return ({'blocks_chosen': chosen, 'blocks_visible': visible,
              'dense_tokens': dense_tokens},
             {'serving/lm_sparse_blocks_chosen_total': chosen,
              'serving/lm_sparse_blocks_visible_total': visible,
              'serving/lm_sparse_kernel_queries_total': kernel,
+             'serving/lm_sparse_decode_stride_rows_total': strides,
              # a sparse layer took its dense branch for these tokens
              'serving/lm_sparse_dense_branch_total':
                  dense_tokens * counts.shape[0]})

@@ -50,6 +50,13 @@ blocks outside that range (at most ``topk - window / block_size``: the
 initial block and the scored ones) are gathered query by query (``far``).
 The two parts share one softmax.
 
+**Decode rows** are eight sequences with a query each, most of them idle
+between turns, and their lengths differ.  So they are not one tile:
+``sparse_attention_rows`` works the live rows one after another, and
+gathers and scores a row's stride rows a piece of its page table at a
+time, only as far as its position reaches (``ROW_PIECES``); its choice and
+plan are then a tile's of one query.
+
 Plain ``jax.numpy`` and ``lax`` gathers: the same code runs in the CPU tests
 and compiles for the chip.  On a TPU stage 2 is instead the Pallas kernel
 of ``ops/pallas_sparse.py``, handed in as ``kernel``: it reads the same
@@ -145,13 +152,24 @@ def block_scores(q, positions, means, geo: SparseGeometry):
     stride index (rows of strides not yet complete are never read).
     Returns [tokens, kv_heads, blocks] float32: ``+inf`` at a forced
     block, ``-inf`` at one the query cannot see."""
+    return scores_of_strides(stride_scores(q, means), positions, geo)
+
+
+def stride_scores(q, means):
+    """``q`` [tokens, q_heads, d] against stride rows ``means`` [strides,
+    kv_heads, d]: [tokens, kv_heads, group, strides] float32, scaled."""
     tokens, q_heads, d = q.shape
-    strides, kv_heads, _ = means.shape
-    group = q_heads // kv_heads
+    kv_heads = means.shape[1]
+    return _mixed('tkgd,mkd->tkgm',
+                  q.reshape(tokens, kv_heads, q_heads // kv_heads, d),
+                  means) * (1.0 / math.sqrt(d))
+
+
+def scores_of_strides(qs, positions, geo: SparseGeometry):
+    """``block_scores`` from the queries' ``stride_scores`` ``qs``."""
+    tokens, kv_heads, _, strides = qs.shape
     r = geo.strides_per_block
     blocks = strides // r
-    qs = _mixed('tkgd,mkd->tkgm', q.reshape(tokens, kv_heads, group, d),
-                means) * (1.0 / math.sqrt(d))
     pooled = 0.5 * (qs[..., :-1] + qs[..., 1:])          # j = 0 .. strides-2
     at = positions.astype(jnp.int32)
     # pooled key j is whole when stride j + kernel - 1 <= i
@@ -410,12 +428,18 @@ def _select(q, positions, live, means, geo: SparseGeometry):
     heads)."""
     with jax.named_scope('sparse_select'):
         chosen = choose(block_scores(q, positions, means, geo), geo.topk)
+    return chosen, _counted(chosen, positions, live, geo)
+
+
+def _counted(chosen, positions, live, geo: SparseGeometry):
+    """(blocks chosen, blocks visible) summed over the live queries and
+    key/value heads."""
     alive = (live > 0)
     picked = jnp.sum(jnp.where(alive[:, None, None], chosen, False))
     visible = jnp.sum(jnp.where(
         alive, positions.astype(jnp.int32) // geo.block_size + 1, 0)) \
         * chosen.shape[1]
-    return chosen, jnp.stack([picked, visible]).astype(jnp.int32)
+    return jnp.stack([picked, visible]).astype(jnp.int32)
 
 
 def sparse_attention(q, positions, live, means, table, pool,
@@ -489,24 +513,85 @@ def sparse_attention_chunk(q, positions, live, means, table, pool,
             _with_kernel_queries(counts.sum(0), live, kernel))
 
 
-def sparse_attention_rows(q, positions, live, means, tables, pool,
+#: the pieces a decode row's page table is cut into: a row's stride rows
+#: are gathered and scored a piece at a time, as far as its positions reach
+ROW_PIECES = 4
+
+
+def sparse_attention_rows(q, positions, live, pooled, tables, pool,
                           geo: SparseGeometry, page_size: int,
                           kernel=None):
     """Both stages for decode rows ``q`` [rows, q_heads, d], each of its
-    own sequence (``means`` [rows, strides, kv_heads, d], ``tables`` [rows,
-    pages]).  With a ``kernel`` stage 2 of every row is one call of it, and
-    a row that is not live copies nothing.  Returns what
-    ``sparse_attention_chunk`` does."""
-    def one(qr, at, lr, means_of_row, table_of_row):
-        if kernel is not None:
-            return planned(qr[None], at[None], lr[None], means_of_row,
-                           table_of_row, geo, page_size)
-        out, counted = sparse_attention(qr[None], at[None], lr[None],
-                                        means_of_row, table_of_row, pool,
-                                        geo, page_size)
-        return out[0], counted
-    done, counts = jax.vmap(one)(q, positions, live, means, tables)
+    own sequence: ``tables`` [rows, pages] its pages (offset to the layer's
+    slab), ``pooled`` [pool pages, strides a page, kv_heads, d] the stride
+    rows page for page.
+
+    A row chooses its blocks alone, for what it can see.  The live rows
+    are worked one after another, a loop as long as they are many, and a
+    row that is not live does nothing and comes back zero.  A live row's
+    stride rows are gathered and scored one of ``ROW_PIECES`` pieces of its
+    table at a time, up to the piece that holds its position; the strides
+    past it are ones it cannot see, and its choice and plan are a tile's
+    of one query over the whole table.  With a ``kernel`` stage 2 of every
+    row is one call of it, and a row that is not live copies nothing.
+    Returns the outputs and (blocks chosen, blocks visible, queries whose
+    stage 2 ran in the kernel, stride rows scored)."""
+    rows, q_heads, d = q.shape
+    pages = tables.shape[1]
+    piece = -(-pages // ROW_PIECES)
+    # whole pieces: the pages past the table are beyond every position
+    tables = jnp.pad(tables, ((0, 0), (0, piece * ROW_PIECES - pages)),
+                     mode='edge')
+    strides_per_page = pooled.shape[1]
+    kv_heads = pooled.shape[2]
+    strides = pages * strides_per_page
+    alive = live > 0
+    order = jnp.nonzero(alive, size=rows, fill_value=0)[0]
+
+    def one(row):
+        qr, at, lr = q[row][None], positions[row][None], live[row][None]
+        table = tables[row]
+
+        def scored(p, qs):
+            means = pooled[jax.lax.dynamic_slice_in_dim(
+                table, p * piece, piece)].reshape(-1, kv_heads, d)
+            with jax.named_scope('sparse_select'):
+                part = stride_scores(qr, means)
+            return jax.lax.dynamic_update_slice_in_dim(
+                qs, part, p * part.shape[-1], axis=3)
+        reach = -(-(at[0] // page_size + 1) // piece)
+        qs = jax.lax.fori_loop(
+            0, reach, scored,
+            jnp.zeros((1, kv_heads, q_heads // kv_heads,
+                       piece * ROW_PIECES * strides_per_page), jnp.float32))
+        with jax.named_scope('sparse_select'):
+            chosen = choose(scores_of_strides(qs[..., :strides], at, geo),
+                            geo.topk)
+        with jax.named_scope('sparse_attention'):
+            if kernel is not None:
+                done = plan_blocks(at, lr, chosen, table, geo, page_size)
+            else:
+                done = attend_blocks(qr, at, lr, chosen, table, pool, geo,
+                                     page_size)[0]
+        return done, jnp.concatenate(
+            [_counted(chosen, at, lr, geo),
+             (jnp.minimum(reach * piece, pages)
+              * strides_per_page)[None].astype(jnp.int32)])
+
+    def next_row(i, carry):
+        done, counts = carry
+        row = order[i]
+        got, counted = one(row)
+        done = jax.tree_util.tree_map(
+            lambda every, mine: every.at[row].set(mine), done, got)
+        return done, counts + counted
+    one_row, counted = jax.eval_shape(one, 0)
+    nothing = (jax.tree_util.tree_map(
+        lambda s: jnp.zeros((rows,) + s.shape, s.dtype), one_row),
+        jnp.zeros(counted.shape, counted.dtype))
+    done, counts = jax.lax.fori_loop(0, jnp.sum(alive), next_row, nothing)
     if kernel is not None:
         with jax.named_scope('sparse_attention'):
             done = kernel(q[:, None], done, pool)[:, 0]
-    return done, _with_kernel_queries(counts.sum(0), live, kernel)
+    return done, jnp.concatenate(
+        [_with_kernel_queries(counts[:2], live, kernel), counts[2:]])

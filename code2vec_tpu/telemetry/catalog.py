@@ -216,6 +216,11 @@ CATALOG: Dict[str, Dict[str, str]] = {
         COUNTER, 'queries', 'Live sparse-layer queries whose stage 2 ran in '
         'the Pallas kernel (ops/pallas_sparse.py), over layers; 0 where '
         'the step programs run the jax.numpy form.'),
+    'serving/lm_sparse_decode_stride_rows_total': _m(
+        COUNTER, 'rows', 'Stride rows (pooled keys\' halves) the '
+        'block-sparse layers\' stage 1 scored for decode rows: the prefix '
+        'of its table each live row was scored over, over rows and layers '
+        '(ops/sparse_attention.py::sparse_attention_rows).'),
     'serving/lm_slot_fill': _m(
         GAUGE, 'fraction', 'Decode slots in use / slots, for a model that '
         'keeps nothing of its own a slot (latent attention: its cache is '

@@ -277,6 +277,40 @@ def test_stage_2_kernel_compiles_at_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+def test_decode_rows_choose_their_blocks_in_a_loop_at_published_widths(
+        one_chip, kernels_on_the_chip):
+    """The decode rows' sparse branch at the cell's sizes (8 rows, tables
+    of 1,280 pages: 10,240 stride rows and 2,560 blocks, top-64), stage 2
+    the kernel: the live rows are one loop and a row's pieces another, not
+    unrolled, so the program holds one gather of stride rows (a piece of
+    one row's table) where the eight rows' whole tables were one gather of
+    42 MB; and the kernel is counted under ``sparse_attention``."""
+    pool = (2, (SALA_POOL_PAGES + 1) * 2, 2, 64, 128)
+    pooled = (SALA_POOL_PAGES + 1, 8, 2, 128)
+
+    def rows(q, positions, live, pooled, tables, pages):
+        return sparse_attention.sparse_attention_rows(
+            q, positions, live, pooled, tables, pages, SALA_GEO, 128,
+            kernel=pallas_sparse.attend_planned)
+    lowered = jax.jit(rows).lower(
+        shaped(one_chip, (SALA_SLOTS, 32, 128), jnp.bfloat16),
+        shaped(one_chip, (SALA_SLOTS,), jnp.int32),
+        shaped(one_chip, (SALA_SLOTS,), jnp.int32),
+        shaped(one_chip, pooled, jnp.bfloat16),
+        shaped(one_chip, (SALA_SLOTS, 1280), jnp.int32),
+        shaped(one_chip, pool, jnp.bfloat16))
+    text = lowered.as_text()
+    piece = 1280 // sparse_attention.ROW_PIECES
+    gathers = re.findall(r'slice_sizes = array<i64: 1, 8, 2, 128>', text)
+    assert len(gathers) == 1
+    assert 'tensor<%dx8x2x128xbf16>' % piece in text
+    assert len(text) < 500_000
+    compiled = lowered.compile()
+    assert kernel_scopes(compiled.as_text()) == {'sparse_attention'}
+    # one piece of one row's stride rows is 1.3 MB; eight rows' tables 42
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
 @pytest.mark.parametrize('chunk', [2048, 0], ids=['chunk', 'decode-only'])
 def test_a_period_of_the_hybrid_step_compiles_and_fits(
         one_chip, kernels_on_the_chip, chunk):

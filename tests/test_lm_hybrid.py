@@ -753,6 +753,49 @@ def test_the_kernel_counter_reads_the_queries_stage_2_ran_there(
         plain.close()
 
 
+def test_the_stride_row_counter_reads_what_decode_rows_scored(exact):
+    """``serving/lm_sparse_decode_stride_rows_total`` is cataloged and in
+    ``stats()['lm']``: over the sparse layers, the stride rows of the
+    pieces of its table each live decode row was scored over (up to the
+    one that holds it), and nothing while no decode row is past
+    ``dense_len``."""
+    from code2vec_tpu.telemetry import catalog
+    name = 'serving/lm_sparse_decode_stride_rows_total'
+    assert name in catalog.CATALOG and name in hybrid_lib.COUNTERS
+    model, engine, config = exact
+    key = name[len('serving/lm_'):]
+    g = engine.lm_runtime().geometry
+    geo = sparse_attention.SparseGeometry(**SPARSE_CONFIG)
+    piece = -(-g.pages_per_seq // sparse_attention.ROW_PIECES)
+    strides_a_page = g.page_size // geo.kernel_stride
+    sparse_layers = sum(kind == S for kind in
+                        hybrid_lib.HybridConfig.from_dict(config).mixer_types)
+
+    def run(length, new):
+        before = (engine.stats()['lm'][key], len(engine.lm_step_log()))
+        engine.submit(np.random.default_rng(length).integers(0, 64, length),
+                      tier='generate', max_new_tokens=new).result(timeout=300)
+        steps = engine.lm_step_log()[before[1]:]
+        return engine.stats()['lm'][key] - before[0], steps
+
+    # decode rows at positions 10..13: all within dense_len
+    grew, steps = run(10, 5)
+    assert grew == 0 and any(len(s['decode_positions']) for s in steps)
+    # decode rows at 27..29, past dense_len 24
+    grew, steps = run(27, 4)
+    want = 0
+    for step in steps:
+        for at in step['decode_positions']:
+            if at + 1 > geo.dense_len:
+                pages = min(-(-(at // g.page_size + 1) // piece) * piece,
+                            g.pages_per_seq)
+                assert (at // geo.kernel_stride + 1
+                        <= pages * strides_a_page
+                        <= g.pages_per_seq * strides_a_page)
+                want += pages * strides_a_page * sparse_layers
+    assert grew == want > 0
+
+
 # ------------------------------------------------------------ the traffic
 MIX = {'rate_per_s': 3.0, 'lead_in_s': 6.0,
        'sessions': {'count': 8, 'median': 65536, 'sigma': 0.5, 'min': 32768,

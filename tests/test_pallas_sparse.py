@@ -26,14 +26,15 @@ KERNEL = functools.partial(pallas_sparse.attend_planned, interpret=True)
 
 
 def pool_and_tables(rows: int, seed: int):
+    """(pool, tables [rows, pages], the stride rows page for page [pool
+    pages, strides a page, kv, d])."""
     rng = np.random.default_rng(seed)
     pool = rng.normal(size=(2, POOL_PAGES * PAGE // GEO.block_size, KV,
                             GEO.block_size, D)) * 1.75
     tables = np.stack([rng.permutation(POOL_PAGES)[:SEQ_PAGES]
                        for _ in range(rows)]).astype(np.int32)
-    means = rng.normal(size=(rows, SEQ_PAGES * PAGE // GEO.kernel_stride,
-                             KV, D))
-    return pool, tables, means
+    pooled = rng.normal(size=(POOL_PAGES, PAGE // GEO.kernel_stride, KV, D))
+    return pool, tables, pooled
 
 
 def run_both(kind, positions, live, seed, dtype):
@@ -42,7 +43,7 @@ def run_both(kind, positions, live, seed, dtype):
     positions = jnp.asarray(positions, jnp.int32)
     live = jnp.asarray(live, jnp.int32)
     rows = positions.shape[0] if kind == 'rows' else 1
-    pool, tables, means = pool_and_tables(rows, seed)
+    pool, tables, pooled = pool_and_tables(rows, seed)
     q = np.random.default_rng(seed + 1).normal(
         size=(positions.shape[0], HEADS, D)) * 1.75
     outs = []
@@ -50,16 +51,16 @@ def run_both(kind, positions, live, seed, dtype):
                              (None, jnp.float32)):
         # the float32 reference multiplies the operands as rounded
         args = [jnp.asarray(x, dtype).astype(as_dtype)
-                for x in (q, means, pool)]
+                for x in (q, pooled, pool)]
         if kind == 'rows':
             outs.append(sa.sparse_attention_rows(
                 args[0], positions, live, args[1], jnp.asarray(tables),
                 args[2], GEO, PAGE, kernel=kernel))
         else:
+            means = args[1][tables[0]].reshape(-1, KV, D)
             outs.append(sa.sparse_attention_chunk(
-                args[0], positions, live, args[1][0],
-                jnp.asarray(tables[0]), args[2], GEO, PAGE, tile=TILE,
-                kernel=kernel))
+                args[0], positions, live, means, jnp.asarray(tables[0]),
+                args[2], GEO, PAGE, tile=TILE, kernel=kernel))
     (plain, counted), (kernel, k_counted), (exact, _) = outs
     return (np.asarray(plain), np.asarray(kernel), np.asarray(exact),
             np.asarray(counted), np.asarray(k_counted))
@@ -112,14 +113,15 @@ def test_a_tile_without_live_query_copies_nothing():
     pool, and none is made; the same ids in a live tile are refused."""
     positions = jnp.arange(50, 62, dtype=jnp.int32)
     live = jnp.asarray([1, 1, 1, 1, 0, 0, 0, 0, 1, 0, 1, 1], jnp.int32)
-    pool, tables, means = pool_and_tables(1, seed=3)
+    pool, tables, pooled = pool_and_tables(1, seed=3)
+    means = pooled[tables[0]].reshape(-1, KV, D)
     q = jnp.asarray(np.random.default_rng(4).normal(size=(12, HEADS, D)),
                     jnp.float32)
     pool = jnp.asarray(pool, jnp.float32)
     tiles = (q.reshape(3, TILE, HEADS, D), positions.reshape(3, TILE),
              live.reshape(3, TILE))
     plan = jax.vmap(lambda qt, pt, lt: sa.planned(
-        qt, pt, lt, jnp.asarray(means[0], jnp.float32),
+        qt, pt, lt, jnp.asarray(means, jnp.float32),
         jnp.asarray(tables[0]), GEO, PAGE)[0])(*tiles)
     outside = pool.shape[1] + 5
 
